@@ -1,12 +1,11 @@
 """distributed_embeddings_tpu — TPU-native distributed embedding framework.
 
-A JAX/XLA/Pallas re-design of the capability surface of NVIDIA's
+A JAX/XLA re-design of the capability surface of NVIDIA's
 ``distributed-embeddings`` (reference: ``distributed_embeddings/__init__.py:17-18``,
 which exports ``embedding_lookup`` and ``__version__``): large-embedding
 recommender training with hybrid model/data parallelism over a TPU mesh.
 """
 
-from . import compat  # noqa: F401 - polyfills jax API gaps (older releases)
 from .version import __version__
 from .ops.embedding_lookup import (
     Ragged,

@@ -47,16 +47,15 @@ import json
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-import jax.core as jcore
+import jax.extend.core as jcore
 import numpy as np
 
 from ..parallel import trainer as trainer_mod
 from ..parallel.dist_embedding import DistributedEmbedding, MpInputs
 
-# primitive-name classes: legacy shard_map (jax<=0.4.x) rewrites psum to
-# psum2 under replication checking; newer jax keeps psum. all_gather has an
-# *_invariant twin on some versions.
-PSUM_PRIMS = frozenset({"psum", "psum2"})
+# primitive-name classes: under shard_map's VMA typing a psum / all_gather
+# whose result is device-invariant traces as its *_invariant twin
+PSUM_PRIMS = frozenset({"psum", "psum_invariant"})
 ALL_TO_ALL_PRIMS = frozenset({"all_to_all"})
 ALL_GATHER_PRIMS = frozenset({"all_gather", "all_gather_invariant"})
 REDUCE_SCATTER_PRIMS = frozenset({"reduce_scatter"})
@@ -179,7 +178,7 @@ def _scope_of(eqn: jcore.JaxprEqn) -> str:
         return ""
 
 
-def _aval_of(var: Any) -> Optional[jcore.AbstractValue]:
+def _aval_of(var: Any) -> Optional[jax.core.AbstractValue]:
     return getattr(var, "aval", None)
 
 

@@ -82,10 +82,11 @@ _FLOAT_DTYPES = frozenset(d for d in _DTYPE_BYTES
 # / `...S(1)}` (the required whitespace before the opcode disambiguates,
 # so `\S+` backtracks off `opcode(` correctly)
 _INST_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*"
+    r"^\s*(?:ROOT\s+)?(?P<name>%?[\w.\-]+)\s*=\s*"
     r"(?P<shape>\((?:[^()]|\([^()]*\))*\)|\S+)\s+"
     r"(?P<op>[a-z][\w\-]*)\(")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
+_OPERAND_RE = re.compile(r"%[\w.\-]+")
 # the phase-name extractor is SHARED with the scope writer (utils/obs.py
 # mints the names) and with the measured-trace parser, so the static and
 # measured attributions can never drift onto different spellings
@@ -348,11 +349,17 @@ def census_of_text(txt: str, *, label: str = "step", world: int = 1,
     phases: Dict[str, PhasePasses] = {}
     total = 0
     unattributed = 0
+    # instruction name -> its result shape tokens: this XLA prints operands
+    # as bare names (``convert(%param_0.2)``), so operand shapes resolve
+    # through their defining instruction (names are module-unique)
+    results: Dict[str, List[Tuple[str, str]]] = {}
     for line in txt.splitlines():
         m = _INST_RE.match(line)
         if m is None:
             continue
         total += 1
+        result = _SHAPE_TOKEN_RE.findall(m.group("shape"))
+        results["%" + m.group("name").lstrip("%")] = result
         op = m.group("op")
         nm = _OPNAME_RE.search(line)
         op_name = nm.group(1) if nm else ""
@@ -375,11 +382,14 @@ def census_of_text(txt: str, *, label: str = "step", world: int = 1,
         if not parts:
             unattributed += 1
         ph.counts[kind] = ph.counts.get(kind, 0) + 1
-        tokens = _SHAPE_TOKEN_RE.findall(line)
-        ph.bytes_est += sum(_token_bytes(dt, dims) for dt, dims in tokens)
-        if kind == "convert" and len(tokens) >= 2:
-            # first token is the result shape, second the operand
-            pair = f"{tokens[1][0]}->{tokens[0][0]}"
+        args = line[m.end():].split(")", 1)[0]
+        # operand shapes: printed inline by older XLA text, else resolved
+        operands = _SHAPE_TOKEN_RE.findall(args) or [
+            t for n in _OPERAND_RE.findall(args) for t in results.get(n, ())]
+        ph.bytes_est += sum(_token_bytes(dt, dims)
+                            for dt, dims in result + operands)
+        if kind == "convert" and result and operands:
+            pair = f"{operands[0][0]}->{result[0][0]}"
             ph.convert_pairs[pair] = ph.convert_pairs.get(pair, 0) + 1
     return CensusReport(
         label=label, world=world, backend=backend, phases=phases,

@@ -86,16 +86,35 @@ class ChipSpec:
     ici_eff_gbps: float
     bf16_peak_flops: float
     hbm_headroom: float = 0.90
+    #: the ``jax.Device.device_kind`` strings this generation reports
+    device_kinds: Tuple[str, ...] = ()
 
 
-#: Known chips. v5e (v5 lite): 16 GiB HBM at 819 GB/s, 197 TFLOP/s bf16
-#: peak, ~100 GB/s effective per-chip all-to-all bandwidth over ICI
-#: (2D torus, 4x 400 Gbps links; conservative effective figure — the
-#: same numbers bench.py's v5e-16 budget uses).
+#: Known chips. v5e (reports ``device_kind == "TPU v5 lite"``): 16 GiB HBM
+#: at 819 GB/s and 197 TFLOP/s bf16 peak are the published figures (Google
+#: Cloud documentation, "TPU v5e"). ``ici_eff_gbps`` is NOT a published
+#: figure: ~100 GB/s effective per-chip all-to-all bandwidth is an
+#: assumption (2D torus, 4x 400 Gbps links, conservative) that no
+#: measurement in this repo has checked.
 CHIP_SPECS: Dict[str, ChipSpec] = {
     "v5e": ChipSpec("v5e", hbm_bytes=16 * 1024**3, hbm_gbps=819.0,
-                    ici_eff_gbps=100.0, bf16_peak_flops=197e12),
+                    ici_eff_gbps=100.0, bf16_peak_flops=197e12,
+                    device_kinds=("TPU v5 lite", "TPU v5e")),
 }
+
+
+def chip_spec_for_device_kind(device_kind: str) -> ChipSpec:
+    """The :data:`CHIP_SPECS` entry for a ``jax.Device.device_kind``.
+    A device the table does not know is an error — there is no default
+    peak and no default HBM size to compute a utilisation against."""
+    for spec in CHIP_SPECS.values():
+        if device_kind in spec.device_kinds:
+            return spec
+    raise KeyError(
+        f"device_kind {device_kind!r} is not in plan_audit.CHIP_SPECS "
+        f"(known: {sorted(k for s in CHIP_SPECS.values() for k in s.device_kinds)}); "
+        "add it with its published peaks and their source")
+
 
 #: The measured apply-scatter rate cliff (docs/perf_tpu.md, VERDICT.md
 #: Weak #3): a single uncapped scatter into a 2.7 GB slab ran at 43 ms

@@ -336,9 +336,13 @@ class ScheduleGraph:
         index = {}
         for i, instr in enumerate(entry.instructions):
             res_b = _shape_bytes(instr.shape)
-            # operand bytes from the full line minus the result shape
-            # (shape tokens in the tail are the typed operand spellings)
-            op_b = max(_shape_bytes(instr.line) - res_b, 0)
+            # operand bytes: this XLA prints operands as bare names, so
+            # they resolve through the defining instruction's result
+            # shape; typed inline spellings (older text, handwritten
+            # modules with undefined operands) are the fallback
+            defs = [names[o] for o in instr.operands if o in names]
+            op_b = (sum(_shape_bytes(d.shape) for d in defs) if defs
+                    else max(_shape_bytes(instr.line) - res_b, 0))
             is_coll = instr.op in COLLECTIVE_OPS or (
                 instr.op == "custom-call" and "all_to_all" in instr.op_name)
             payload = int(op_b * off_frac) if is_coll else 0

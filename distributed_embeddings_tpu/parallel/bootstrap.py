@@ -29,8 +29,6 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
-from .. import compat
-
 logger = logging.getLogger(__name__)
 
 
@@ -62,7 +60,7 @@ def _join_runtime(coordinator_address: Optional[str],
     from ..utils import runtime
 
     runtime.fault_point("coordinator")
-    if compat.distributed_is_initialized():
+    if jax.distributed.is_initialized():
         # an earlier attempt that "failed" late (e.g. deadline fired on the
         # way out) actually completed — initialize() is not idempotent, so
         # re-invoking it would burn the whole retry budget on its
@@ -99,31 +97,31 @@ def initialize(coordinator_address: Optional[str] = None,
                retries: int = 2) -> bool:
     """Join the multi-process JAX runtime; safe to call more than once.
 
-    With no arguments, relies on ``jax.distributed.initialize``'s cluster
-    auto-detection (TPU pod metadata, Slurm, GKE). Returns True if this call
-    performed the initialization, False if it was already done or this is a
-    plain single-process run (no args, no detectable cluster).
+    With no arguments, a join is attempted only when the environment
+    announces a multi-process job (:func:`_cluster_expected`: TPU pod
+    worker hostnames, a coordinator address, Slurm/MPI task counts); it
+    then relies on ``jax.distributed.initialize``'s cluster auto-detection.
+    A plain single-process run returns False WITHOUT touching
+    ``jax.distributed`` — on a sealed single-host TPU machine the
+    auto-detection has nothing to find and may wait on a metadata service.
+    Returns True if this call performed the initialization, False if it
+    was already done or no cluster is expected.
 
     Fault tolerance (``utils.runtime``): each join attempt is bounded by
     ``timeout_s`` (best-effort ``SIGALRM`` deadline; ``None`` = no bound)
     and a failed attempt is retried up to ``retries`` times with jittered
-    backoff — a *slow* coordinator is a normal operating condition. What a
-    failure ultimately means depends on the environment:
-
-    * cluster expected (explicit coordinator args, or the environment
-      announces a multi-process job): after the retry budget the error is
-      re-raised as :class:`~..utils.runtime.CoordinatorUnreachable` — a pod
-      must never silently fall apart into independent single-host trainings
-      (each believing it is chief);
-    * no cluster detectable: the failure degrades silently into a
-      single-process run, as before.
+    backoff — a *slow* coordinator is a normal operating condition. After
+    the retry budget the error is re-raised as
+    :class:`~..utils.runtime.CoordinatorUnreachable` — a pod must never
+    silently fall apart into independent single-host trainings (each
+    believing it is chief).
     """
-    if compat.distributed_is_initialized():
+    if jax.distributed.is_initialized():
+        return False
+    if not (coordinator_address is not None or num_processes is not None
+            or _cluster_expected()):
         return False
     from ..utils import runtime
-
-    expected = (coordinator_address is not None or num_processes is not None
-                or _cluster_expected())
 
     def join_once():
         with runtime.deadline(timeout_s, label="coordinator join"):
@@ -135,14 +133,6 @@ def initialize(coordinator_address: Optional[str] = None,
     from ..utils import obs
 
     t0 = time.monotonic()
-    if not expected:
-        try:
-            join_once()
-        except Exception as e:  # noqa: BLE001 - single-host degradation
-            logger.debug("single-process run (no cluster detected): %s", e)
-            return False
-        _log_join_success(coordinator_address, time.monotonic() - t0)
-        return True
     retries_before = obs.counters().get("runtime_retries", 0)
     try:
         runtime.retry(join_once, max_attempts=retries + 1,

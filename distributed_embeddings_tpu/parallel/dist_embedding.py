@@ -72,7 +72,6 @@ import numpy as np
 from flax import struct
 from jax import lax
 
-from .. import compat
 from ..utils import obs
 from ..utils import runtime as _runtime
 from ..layers.embedding import default_embeddings_init
@@ -131,13 +130,6 @@ class MpInputs:
 
 def _wkey(width: int) -> str:
     return f"w{width}"
-
-
-def _pvary(x: jax.Array, axis_name: str) -> jax.Array:
-    """Mark a constant as device-varying over ``axis_name`` so it can join
-    varying values in collectives/switch branches under VMA typing (identity
-    on pre-VMA jax — see :mod:`..compat`)."""
-    return compat.pvary(x, axis_name)
 
 
 class DistributedEmbedding:
@@ -593,8 +585,6 @@ class DistributedEmbedding:
         the price the ``'raise'`` policy opts into (call it from the input
         pipeline, where ids are still host numpy, to pay nothing).
         """
-        import jax.core as _jcore
-
         if isinstance(inputs, MpInputs):
             # already validated id-by-id inside pack_mp_inputs (host
             # numpy); the packed block cannot be re-attributed to inputs
@@ -619,7 +609,7 @@ class DistributedEmbedding:
             else:
                 arrs = (inp,)
                 values, splits, cap = inp, None, None
-            if any(isinstance(a, _jcore.Tracer) for a in arrs):
+            if any(isinstance(a, jax.core.Tracer) for a in arrs):
                 return None
             ids = np.asarray(values)
             if isinstance(inp, SparseIds):
@@ -1129,9 +1119,12 @@ class DistributedEmbedding:
         return (lax.axis_index(self.axis_name) if self.world_size > 1 else 0)
 
     def _vary(self, x: jax.Array) -> jax.Array:
-        """VMA-mark a constant when running under shard_map; identity for
-        the single-worker (no mesh axis) path."""
-        return _pvary(x, self.axis_name) if self.world_size > 1 else x
+        """Mark a constant device-varying over the mesh axis so it can join
+        varying values in collectives/switch branches under shard_map's VMA
+        typing; identity for the single-worker (no mesh axis) path."""
+        if self.world_size == 1:
+            return x
+        return lax.pcast(x, self.axis_name, to="varying")
 
     def _plan_row(self, arr: np.ndarray, my) -> jax.Array:
         """This device's row of a ``[world, n]`` plan tensor. The tensor is a

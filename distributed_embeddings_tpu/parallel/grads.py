@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
-
 
 def _map_by_mask(fn_mp: Callable, fn_dp: Callable, mask: Any, tree: Any) -> Any:
     """Map ``fn_mp``/``fn_dp`` over ``tree`` leaves according to a boolean mask
@@ -60,13 +58,11 @@ def resolve_dp_gradient(g: jax.Array, axis_name: str) -> jax.Array:
     Requires shard_map's default replication checking (``check_vma=True``):
     under ``check_vma=False`` every value reports an empty vma set, the
     auto-psum does not happen, and this helper cannot tell the two cases
-    apart. When no vma typing is present at all, fall back to ``pmean``
-    (the pre-VMA semantics).
+    apart.
     """
-    vma = compat.vma_of(g)
-    if vma is None or axis_name in vma:
+    if axis_name in jax.typeof(g).vma:
         return lax.pmean(g, axis_name)
-    return g / compat.axis_size(axis_name)
+    return g / lax.axis_size(axis_name)
 
 
 def hybrid_gradients(grads: Any, mp_mask: Any, axis_name: str) -> Any:
@@ -76,7 +72,7 @@ def hybrid_gradients(grads: Any, mp_mask: Any, axis_name: str) -> Any:
     are averaged over the axis (see :func:`resolve_dp_gradient`); mp leaves
     are divided by the axis size.
     """
-    world = compat.axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     return _map_by_mask(
         lambda g: None if g is None else g / world,
         lambda g: None if g is None else resolve_dp_gradient(g, axis_name),

@@ -243,8 +243,23 @@ def _worker_main(spec: Dict[str, Any]) -> None:
 
 
 def _worker_body(conn, spec: Dict[str, Any]) -> None:
-    from .serving import ServingRuntime  # jax import deferred to child
+    runtime_mod.ensure_compile_cache()
+    import jax  # deferred to the child
 
+    from .serving import ServingRuntime
+
+    # first backend touch, on purpose and alone: a chip belongs to one
+    # process at a time, and the usual reason a worker cannot start is
+    # that the supervisor's process (which trains) holds the only one
+    try:
+        jax.devices()
+    except RuntimeError as e:
+        raise RuntimeError(
+            "serving worker found no free device — a chip belongs to one "
+            "process at a time and the supervisor's process holds it. Give "
+            "the worker a chip of its own, or pin it to the CPU "
+            "(SuperviseConfig(env={'JAX_PLATFORMS': 'cpu'})). Backend said: "
+            f"{e}") from e
     factory = _resolve_factory(spec["factory"])
     built = factory(**spec.get("kwargs", {}))
     rt = ServingRuntime(

@@ -16,7 +16,7 @@ from .obs import (MetricsLogger, StepTimer, counter_inc, counters,
                   scope)
 from .runtime import (BackendProbe, BackendUnavailable, CheckpointCorrupt,
                       CheckpointMismatch, CoordinatorUnreachable,
-                      DeadlineExceeded, DeviceSpec, FaultInjected,
-                      InvalidInputError, NonFiniteLossError, SectionRecorder,
-                      deadline, fault_point, preempt_step, probe_backend,
-                      require_devices, retry, run_section)
+                      DeadlineExceeded, FaultInjected, InvalidInputError,
+                      NonFiniteLossError, SectionRecorder, deadline,
+                      ensure_compile_cache, fault_point, preempt_step,
+                      probe_backend, retry, run_section)

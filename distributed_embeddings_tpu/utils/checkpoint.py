@@ -790,13 +790,15 @@ def restore_train_state(path: str, de, emb_optimizer, dense_template,
              "step": jnp.zeros((), jnp.int32)}
     with open(os.path.join(path, "dense.msgpack"), "rb") as f:
         dense = serialization.from_bytes(dense, f.read())
-    from ..parallel.trainer import HybridTrainState
+    from ..parallel.trainer import HybridTrainState, replicate_on_mesh
 
+    dense["step"] = jnp.asarray(dense["step"])
+    dense = replicate_on_mesh(dense, mesh)
     return HybridTrainState(
         emb_params=emb_params, emb_opt_state=opt_state,
         dense_params=dense["dense_params"],
         dense_opt_state=dense["dense_opt_state"],
-        step=jnp.asarray(dense["step"]))
+        step=dense["step"])
 
 
 def load_aux_state(path: str, name: str) -> Optional[Dict[str, Any]]:

@@ -2,7 +2,7 @@
 process counters, and a crash-surviving metrics sidecar.
 
 PR 1's runtime layer (:mod:`.runtime`) made failures *survivable* — a
-stalled tunnel or a killed process leaves parseable records. This module
+killed process leaves parseable records. This module
 makes runs *explainable*: when throughput drops, or ragged ids silently
 overflow their static capacity, there is something to look at. Every later
 perf PR is measured against the instrumentation here.
@@ -325,32 +325,30 @@ _compile_listener_installed = False
 # gates would flag phantom retraces
 _compile_lock = threading.Lock()
 
-# one backend compile per jitted-signature miss: cache hits do not fire it
+# fires once per jitted-signature miss, around compile-or-load: an
+# in-process jit cache hit does not fire it, a persistent-cache load does
+# (the program was still traced, lowered and handed to the backend)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# fires when that miss was answered from the persistent compilation cache
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def install_compile_listener() -> bool:
-    """Count XLA recompiles into the ``recompiles`` counter.
+    """Count program builds into the ``recompiles`` counter.
 
     Registers a ``jax.monitoring`` duration listener for the
-    backend-compile event, which fires exactly once per compiled
-    executable (jit cache hits do not emit it) — the cache-miss signal
-    that distinguishes "throughput fell because something retraces every
-    step" from a genuine regression. Idempotent; returns False when the
-    running jax has no monitoring hooks (the caller loses the counter,
-    nothing else).
+    backend-compile event, which fires exactly once per jit cache miss —
+    whether XLA then compiles the program or loads it from the persistent
+    compilation cache (``persistent_cache_hits`` counts the latter, so
+    ``recompiles - persistent_cache_hits`` is the number of real XLA
+    compiles). A zero-recompile window therefore means no retrace at all,
+    with or without a warm persistent cache. Idempotent; returns True.
     """
     global _compile_listener_installed
     with _compile_lock:
         if _compile_listener_installed:
             return True
-        try:
-            import jax.monitoring
-        except Exception:  # noqa: BLE001 - counter is best-effort
-            return False
-        if not hasattr(jax.monitoring,
-                       "register_event_duration_secs_listener"):
-            return False
+        import jax.monitoring
 
         def _on_duration(event: str, duration: float,
                          **kwargs: Any) -> None:
@@ -358,6 +356,12 @@ def install_compile_listener() -> bool:
             if event == _COMPILE_EVENT:
                 counter_inc("recompiles")
 
+        def _on_event(event: str, **kwargs: Any) -> None:
+            del kwargs
+            if event == _CACHE_HIT_EVENT:
+                counter_inc("persistent_cache_hits")
+
+        jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _compile_listener_installed = True
         return True
@@ -651,9 +655,9 @@ class StepTimer:
 
 def env_stamp() -> Dict[str, Any]:
     """Process/environment identity for stamping benchmark records:
-    backend platform + device count are NOT probed here (that is the
-    caller's time-boxed :func:`.runtime.probe_backend` verdict, passed
-    in); this returns what is knowable without touching a backend."""
+    backend platform, device kind and count are NOT read here (the caller
+    adds them from its own ``jax.devices()``); this returns what is
+    knowable without touching a backend."""
     stamp: Dict[str, Any] = {
         "unix_time": time.time(),
         "obs_enabled": metrics_enabled(),

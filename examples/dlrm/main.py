@@ -42,7 +42,7 @@ from distributed_embeddings_tpu.parallel import (
     DistributedEmbedding, SparseSGD, bootstrap, init_hybrid_state,
     make_hybrid_eval_step, make_hybrid_train_step, run_resilient)
 from distributed_embeddings_tpu.utils import (
-    RawBinaryDataset, binary_auc, obs, power_law_ids)
+    RawBinaryDataset, binary_auc, obs, power_law_ids, runtime)
 
 FLAGS = flags.FLAGS
 flags.DEFINE_string("dataset_path", None,
@@ -169,6 +169,7 @@ def synthetic_batches(cfg, num_batches, batch_size, seed=0):
 
 
 def main(_):
+    runtime.ensure_compile_cache()
     # multi-host bootstrap (the reference's hvd.init, main.py:152-157 there):
     # no-op on a single host; on a pod every host runs this same script.
     # Deadline-bounded + retried (utils.runtime): a slow coordinator gets

@@ -43,6 +43,7 @@ from flax.training import train_state
 
 from distributed_embeddings_tpu.layers import DistributedEmbeddingLayer
 from distributed_embeddings_tpu.parallel import DistributedEmbedding
+from distributed_embeddings_tpu.utils import runtime
 
 TABLE_SIZES = [1000, 5000, 20000, 800, 12000, 300, 9000, 2500]
 EMBED_DIM = 16
@@ -112,6 +113,7 @@ def sparse_optax_demo():
 
 
 def main():
+    runtime.ensure_compile_cache()
     if "--sparse" in sys.argv:
         return sparse_optax_demo()
     mesh_mode = "--mesh" in sys.argv

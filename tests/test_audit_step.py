@@ -152,7 +152,7 @@ def test_dtype_leak_flagged():
     tracing under enable_x64 with a loss that upcasts — without x64 the
     cast is a silent no-op, which is exactly why only the auditor can see
     the difference."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _, _, _, _, base_loss = build_case("dense", 1, B)
 
         def leaky_loss(dp, emb_outs, batch):

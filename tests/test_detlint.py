@@ -14,9 +14,8 @@ import pytest
 
 from distributed_embeddings_tpu.utils import envvars
 from tools import detlint
-from tools.detlint.rules import (bare_except, donated_aux, eager_backend,
-                                 env_registry, hardcoded_capacity,
-                                 host_fetch, module_scope_jax, named_scope,
+from tools.detlint.rules import (bare_except, donated_aux, env_registry,
+                                 hardcoded_capacity, host_fetch, module_scope_jax, named_scope,
                                  spawn_context, thread_shared,
                                  unsized_unique)
 
@@ -35,19 +34,6 @@ def test_bare_except_fires_and_clean():
     assert _check(bare_except, "try:\n    pass\nexcept:\n    pass\n")
     assert not _check(bare_except,
                       "try:\n    pass\nexcept Exception:\n    pass\n")
-
-
-def test_eager_backend_module_scope_vs_annotated():
-    bad = "import jax\nn = jax.device_count()\n"
-    assert _check(eager_backend, bad, path="bench.py")
-    in_fn = ("import jax\n"
-             "def f():\n"
-             "    return jax.device_count()\n")
-    assert _check(eager_backend, in_fn, path="bench.py")
-    ok = ("import jax\n"
-          "def f():\n"
-          "    return jax.device_count()  # backend-ok: probe-cleared\n")
-    assert not _check(eager_backend, ok, path="bench.py")
 
 
 def test_env_registry_literal_and_constant_resolution():
@@ -310,7 +296,7 @@ def test_thread_shared_rule():
 
 def test_discover_rules_finds_all():
     rules = detlint.discover_rules()
-    assert {"bare-except", "eager-backend", "env-registry",
+    assert {"bare-except", "env-registry",
             "hardcoded-capacity", "host-fetch", "module-scope-jax",
             "named-scope-exchange", "spawn-context", "thread-shared",
             "unsized-unique"} <= set(rules)
@@ -361,13 +347,3 @@ def test_envvars_semantics(monkeypatch):
     assert envvars.get_int("DETPU_NANGUARD_K", 3) == 3
     monkeypatch.setenv("DETPU_PROBE_TIMEOUT_S", "2.5")
     assert envvars.get_float("DETPU_PROBE_TIMEOUT_S") == 2.5
-
-
-def test_legacy_shim_still_green():
-    """tools/check_no_eager_backend.py (kept for make verify mid-
-    transition) delegates to the detlint rule and stays green."""
-    r = subprocess.run(
-        [sys.executable, "tools/check_no_eager_backend.py"],
-        cwd=detlint.REPO, capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stderr
-    assert "OK" in r.stdout

@@ -144,12 +144,10 @@ def test_mesh_training_plain_optax():
 
         loss, (gs, gw) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
             slabs, w)
-        # dp gradient for w (replicated): resolve via the library helper —
-        # it absorbs the VMA-vs-legacy autodiff difference (newer jax
-        # auto-psums the replicated-param gradient; pre-VMA jax returns the
-        # per-device contribution) — then restore the summed-gradient
-        # semantics this test's lr was tuned for. mp gradients local,
-        # 1/world scale.
+        # dp gradient for w (replicated): shard_map's autodiff already
+        # psums it, the library helper turns that into the mean — then
+        # restore the summed-gradient semantics this test's lr was tuned
+        # for. mp gradients local, 1/world scale.
         gw = resolve_dp_gradient(gw, "data") * WORLD
         gs = jax.tree.map(lambda g: g / WORLD, gs)
         updates, opt_state = tx.update(gs, opt_state, slabs)

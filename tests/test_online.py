@@ -297,7 +297,10 @@ def test_no_torn_reads_under_interleaved_publication_mesh8(mesh8):
     state_b = state._replace(
         emb_params=jax.tree.map(lambda a: a + jnp.asarray(1, a.dtype),
                                 state.emb_params),
-        step=jnp.asarray(7, jnp.int32))
+        # derived from state.step so it keeps the mesh-replicated
+        # placement a train step returns (a fresh jnp.asarray lands on
+        # one device: a different jit cache key, i.e. a retrace)
+        step=state.step + 7)
     ev = make_hybrid_eval_step(de, _pred_fn, mesh=mesh8)
     # one-time compiles (publisher cloners, the reference eval step)
     # land BEFORE the warmup baseline — the steady-state window then

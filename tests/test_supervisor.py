@@ -205,3 +205,20 @@ def test_supervised_worker_end_to_end(tmp_path):
     finally:
         s.close()
     assert not os.path.exists(str(tmp_path / "sup.blackbox.json"))
+
+
+def test_worker_without_a_free_device_says_so(tmp_path):
+    """A worker whose backend will not come up (here: a TPU asked for on a
+    machine without one; on the chip: the supervisor's process holds it)
+    fails the start at once with the reason, not after the start timeout."""
+    s = sup.Supervisor(
+        "tools.isolation_common:worker_factory", {"world": 1},
+        config=sup.SuperviseConfig(
+            blackbox_path=str(tmp_path / "sup.blackbox.json"),
+            env={"JAX_PLATFORMS": "tpu", "DETPU_FAULT": "",
+                 "DETPU_METRICS_PORT": ""}))
+    try:
+        with pytest.raises(RuntimeError, match="found no free device"):
+            s.start()
+    finally:
+        s.close()

@@ -1,8 +1,7 @@
 """detlint — the repo's pluggable AST lint framework.
 
-The repo accumulated ad-hoc static checkers (``check_no_eager_backend``,
-the AST half of ``check_obs``) that each reimplemented file walking and
-reporting. detlint replaces that with one rule framework:
+One rule framework in place of ad-hoc static checkers that each
+reimplemented file walking and reporting:
 
 * a **rule** is a module in :mod:`tools.detlint.rules` exposing ``NAME``
   (kebab-case id), ``SCOPE`` (repo-relative glob patterns of the files it

@@ -37,8 +37,7 @@ def build(world: int = 8, seed: int = 0):
         init_hybrid_state, init_streaming)
     from distributed_embeddings_tpu.parallel import serving as sv
 
-    mesh = (Mesh(np.array(jax.devices()[:world]),  # backend-ok: drill child
-                 ("data",))
+    mesh = (Mesh(np.array(jax.devices()[:world]), ("data",))
             if world > 1 else None)
     sizes = list(SIZES)
     configs = ([{"input_dim": v, "output_dim": 8} for v in sizes]

@@ -392,7 +392,7 @@ def run_demo(world: int, steps: int, batch: int,
         make_hybrid_train_step)
     from distributed_embeddings_tpu.utils import obs, power_law_ids
 
-    devs = jax.devices()  # backend-ok: _force_cpu ran before jax import
+    devs = jax.devices()
     if len(devs) < world:
         raise RuntimeError(
             f"host platform exposes {len(devs)} devices < {world}")
