@@ -1,0 +1,17 @@
+"""Published peaks of a chip, keyed by ``device_kind``. A device that is not
+in the table is an error, never a default."""
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+    # 16 GB of HBM a chip
+    "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9,
+                    "source": "Google Cloud documentation, TPU v5e"},
+}
+
+
+def of(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise SystemExit(f"no published peaks for device kind {device_kind!r}: "
+                         "add it to benchmarks/lib/peaks.py with its source")
+    return PEAKS[device_kind]

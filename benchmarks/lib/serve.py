@@ -1,0 +1,155 @@
+"""A serving cell: an open loop of ranking queries at a fixed rate through
+``ServingRuntime.submit``/``poll`` in this process, timed from when each
+request was due, and the comparison of what was served with the reference.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import time
+from typing import List
+
+import jax.numpy as jnp
+import numpy as np
+
+from . import program, reference, traffic, weights
+
+DRAIN_S = 60.0      # how long past the close an answer is waited for
+IDLE_SLEEP_S = 2e-4
+
+
+def failed(result) -> bool:
+    """Refused, lost or expired: ``Overloaded``, ``Expired``, ``Failed``,
+    ``Unavailable``. A ``Served`` is not, however late (``deadline_missed``);
+    whether it says the right thing is the comparison's to decide."""
+    return program.is_refused(result)
+
+
+def latencies_ms(due_s, t_submit_s, results: dict) -> np.ndarray:
+    """Latency of every request from its due time to its reply. ``results[i]``
+    is request ``i``'s typed result or missing; ``t_submit_s[i]`` the clock at
+    its submit, on the window's time base. A failed or unanswered request
+    counts as the longest served one."""
+    lat = np.full(len(due_s), np.nan)
+    for i, r in results.items():
+        if not failed(r):
+            lat[i] = (t_submit_s[i] - due_s[i]) * 1e3 + r.latency_ms
+    worst = np.nanmax(lat) if np.isfinite(lat).any() else float("inf")
+    return np.where(np.isnan(lat), worst, lat)
+
+
+def requests_of(schedule: traffic.ServeSchedule) -> List:
+    """Every request of the schedule as the runtime takes it."""
+    return [program.Request(cats=cats, batch=num) for cats, num in
+            (schedule.request(i) for i in range(len(schedule)))]
+
+
+def open_loop(rt, schedule: traffic.ServeSchedule, requests: List,
+              span=contextlib.nullcontext, clock=time.monotonic,
+              sleep=time.sleep):
+    """Submit each request when it is due and poll until every one has its
+    result or ``DRAIN_S`` has passed since the last was due. Returns
+    ``(results by request index, submit times, last reply time)``, times in
+    seconds since the window opened."""
+    n = len(schedule)
+    due = schedule.due_s
+    t_sub = np.zeros(n)
+    results, by_rid = {}, {}
+    t_last = 0.0
+    i = 0
+    t_open = clock()
+    while len(results) < n:
+        now = clock() - t_open
+        if i < n and due[i] <= now:
+            with span("submit"):
+                while i < n and due[i] <= now:
+                    rej = rt.submit(requests[i])
+                    t_sub[i] = requests[i].t_submit - t_open
+                    by_rid[requests[i].rid] = i
+                    if rej is not None:
+                        results[i] = rej
+                    i += 1
+                    now = clock() - t_open
+        with span("poll"):
+            done = rt.poll()
+        if done:
+            t_last = clock() - t_open
+            for r in done:
+                results[by_rid[r.rid]] = r
+        elif i == n and now > due[-1] + DRAIN_S:
+            break
+        elif not rt.queued_samples and (i == n or due[i] - now > 2 * IDLE_SLEEP_S):
+            with span("wait_arrival"):
+                sleep(IDLE_SLEEP_S)
+    return results, t_sub, t_last
+
+
+def slowest_flushes(results: dict, n: int) -> list:
+    """``(request index, result)`` of one request from each of the ``n``
+    flushes that took longest from pack to reply, for standard error."""
+    in_flush = lambda r: r.latency_ms - r.spans["queue_wait_ms"]  # noqa: E731
+    seen, out = set(), []
+    for i, r in sorted(((i, r) for i, r in results.items() if not failed(r)),
+                       key=lambda ir: -in_flush(ir[1])):
+        key = round(in_flush(r), 6)     # requests of one flush share it
+        if key not in seen:
+            seen.add(key)
+            out.append((i, r))
+        if len(out) == n:
+            break
+    return out
+
+
+def sample_to_compare(seed: int, results: dict, sizes: np.ndarray,
+                      want: int) -> List[int]:
+    """``want`` served requests drawn from the seed, the largest among them."""
+    served = sorted(i for i, r in results.items() if not failed(r))
+    if not served:
+        return []
+    rng = np.random.default_rng([int(seed), 3])
+    pick = set(rng.choice(served, size=min(want, len(served)),
+                          replace=False).tolist())
+    pick.add(max(served, key=lambda i: sizes[i]))
+    return sorted(pick)
+
+
+def reference_logits(config: dict, schedule: traffic.ServeSchedule,
+                     picked: List[int], seed: int,
+                     precision: str = "float32") -> np.ndarray:
+    """The plain reference's logits for every sample of the picked requests,
+    in their order, from weights it makes itself from the seed."""
+    sizes = [int(s) for s in config["table_sizes"]]
+    dim = int(config["embedding_dim"])
+    tdt = program._dtype(config["table_dtype"])
+    words = jnp.asarray(weights.seed_words(seed))
+    ids = [np.concatenate([schedule.request(i)[0][t] for i in picked])
+           for t in range(len(sizes))]
+    num = np.concatenate([schedule.request(i)[1] for i in picked])
+    room = (len(picked) + 1) * int(np.diff(schedule.offsets).max())
+    room += -room % 4096
+    uniq, mapped, _ = reference.compact([ids],
+                                        [min(s, room) for s in sizes])
+    tabs = weights.rows_fn(sizes, dim, tdt)(uniq, words)
+    dense = [(jnp.asarray(k), jnp.asarray(b)) for k, b in weights.dense_params(
+        seed, int(config["num_numerical"]), config["bottom_mlp"],
+        config["top_mlp"], len(sizes), dim)]
+    return reference.forward_blocks(tabs, dense, mapped[0], num,
+                                    len(config["bottom_mlp"]), precision)
+
+
+def compare(schedule: traffic.ServeSchedule, results: dict, picked: List[int],
+            want: np.ndarray) -> dict:
+    """The widest gap between a served prediction and the reference's logit
+    for the same sample, over the picked requests, and how many of them came
+    back with another number of predictions than they had samples."""
+    gap, misshapen, a = 0.0, 0, 0
+    for i in picked:
+        n = int(schedule.offsets[i + 1] - schedule.offsets[i])
+        got = np.asarray(results[i].predictions, np.float32).reshape(-1)
+        if got.shape[0] != n or not np.isfinite(got).all():
+            misshapen += 1
+        else:
+            gap = max(gap, float(np.abs(got - want[a:a + n]).max()))
+        a += n
+    return {"logit_gap": gap, "misshapen": float(misshapen),
+            "logit_scale": float(np.abs(want).max()) if len(want) else 0.0}

@@ -1,0 +1,161 @@
+"""The one general traffic generator: a traffic file's parameters and a seed
+give the staged training batches or the serving requests of a run.
+
+Every seed gets the same multiset of request sizes, arrival gaps and
+multi-hot row lengths in another order, so that the seed changes which ids
+are drawn and not how much work a run holds.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import List, Optional
+
+import numpy as np
+
+
+def power_law_ids(rng: np.random.Generator, vocab: int, shape,
+                  alpha: float) -> np.ndarray:
+    """Ids in ``[0, vocab)`` with ``p(x) ~ x**-alpha`` by the inverse CDF (the
+    draw of the program's ``utils.data.power_law_ids``, copied so that the
+    program cannot move the yardstick)."""
+    u = rng.random(size=shape)
+    exp = 1.0 - alpha
+    ids = ((vocab + 1) ** exp * u + (1 - u)) ** (1.0 / exp) - 1.0
+    return np.clip(ids.astype(np.int64), 0, vocab - 1).astype(np.int32)
+
+
+def _rng(seed: int, stream: int) -> np.random.Generator:
+    return np.random.default_rng([int(seed), stream])
+
+
+@dataclasses.dataclass
+class TrainBatch:
+    """One global batch on the host. ``splits`` is None for one-hot ids;
+    otherwise ``ids[t]`` is ``[capacity]`` and ``splits[t]`` ``[batch+1]``."""
+    ids: List[np.ndarray]
+    splits: Optional[List[np.ndarray]]
+    numerical: np.ndarray
+    labels: np.ndarray
+
+
+def row_lengths(rng, batch: int, lo: int, hi: int, capacity: int) -> np.ndarray:
+    """``batch`` row lengths, the fixed multiset ``lo..hi`` repeated evenly and
+    shuffled; trimmed from the end if the sum would pass ``capacity``."""
+    base = np.resize(np.arange(lo, hi + 1), batch)
+    hot = rng.permutation(base)
+    over = int(hot.sum()) - capacity
+    i = batch - 1
+    while over > 0:
+        cut = min(over, int(hot[i]) - 1)
+        hot[i] -= cut
+        over -= cut
+        i -= 1
+    return hot
+
+
+def train_batches(traffic: dict, table_sizes, num_numerical: int,
+                  seed: int) -> List[TrainBatch]:
+    """``distinct_batches`` global batches drawn from the seed."""
+    rng = _rng(seed, 1)
+    b = int(traffic["global_batch"])
+    alpha = float(traffic["id_alpha"])
+    hot = traffic["hotness"]
+    out = []
+    for _ in range(int(traffic["distinct_batches"])):
+        if hot["kind"] == "one":
+            ids = [power_law_ids(rng, s, (b,), alpha) for s in table_sizes]
+            splits = None
+        elif hot["kind"] == "uniform":
+            cap = int(hot["capacity"])
+            ids, splits = [], []
+            for s in table_sizes:
+                lens = row_lengths(rng, b, int(hot["min"]), int(hot["max"]),
+                                   cap)
+                sp = np.zeros(b + 1, np.int32)
+                np.cumsum(lens, out=sp[1:])
+                v = np.zeros(cap, np.int32)
+                v[:sp[-1]] = power_law_ids(rng, s, (int(sp[-1]),), alpha)
+                ids.append(v)
+                splits.append(sp)
+        else:
+            raise ValueError(f"unknown hotness kind {hot['kind']!r}")
+        out.append(TrainBatch(
+            ids=ids, splits=splits,
+            numerical=rng.normal(size=(b, num_numerical)).astype(np.float32),
+            labels=rng.integers(0, 2, size=(b, 1)).astype(np.float32)))
+    return out
+
+
+@dataclasses.dataclass
+class ServeSchedule:
+    """Every request of a window: request ``i`` is due ``due_s[i]`` seconds
+    after the window opens and holds samples ``offsets[i]:offsets[i+1]``."""
+    due_s: np.ndarray
+    offsets: np.ndarray
+    ids: List[np.ndarray]
+    numerical: np.ndarray
+
+    def __len__(self):
+        return len(self.due_s)
+
+    def request(self, i: int):
+        a, b = int(self.offsets[i]), int(self.offsets[i + 1])
+        return [c[a:b] for c in self.ids], self.numerical[a:b]
+
+
+def _quantile_grid(n: int) -> np.ndarray:
+    return (np.arange(n) + 0.5) / n
+
+
+def request_sizes(traffic: dict, n: int) -> np.ndarray:
+    """``n`` request sizes: the traffic file's piecewise-linear quantile curve
+    read on an even grid, so the multiset is the same whatever the seed."""
+    q = traffic["size_quantiles"]
+    return np.round(np.interp(_quantile_grid(n), q["p"], q["samples"])
+                    ).astype(np.int64)
+
+
+def arrival_gaps(rate_per_s: float, n: int) -> np.ndarray:
+    """``n`` exponential gaps (seconds) as the distribution's own quantiles on
+    an even grid, scaled so that they sum to ``n / rate`` exactly."""
+    g = -np.log1p(-_quantile_grid(n))
+    return g * (n / g.sum()) / rate_per_s
+
+
+def _burst_warp(due: np.ndarray, every: float, factor: float) -> np.ndarray:
+    """Arrivals at a steady rate -> the same arrivals with the first second of
+    each ``every`` seconds at ``factor`` times the rate and the rest slowed so
+    that the mean rate is unchanged."""
+    slow = (every - factor) / (every - 1.0)
+    if slow <= 0:
+        raise ValueError("burst factor leaves no load for the other seconds")
+    period = np.floor(due / every)
+    work = due - period * every
+    return period * every + np.where(work < factor, work / factor,
+                                     1.0 + (work - factor) / slow)
+
+
+def serve_schedule(traffic: dict, table_sizes, num_numerical: int, seed: int,
+                   seconds: float) -> ServeSchedule:
+    """Open-loop Poisson arrivals at the traffic file's fixed rate for
+    ``seconds`` seconds, each request one ranking query."""
+    rng = _rng(seed, 2)
+    rate = float(traffic["rate_per_s"])
+    n = max(1, int(round(rate * seconds)))
+    sizes = rng.permutation(request_sizes(traffic, n))
+    gaps = rng.permutation(arrival_gaps(rate, n))
+    due = np.cumsum(gaps) - gaps       # the last gap closes the window
+    burst = traffic.get("burst")
+    if burst:
+        due = _burst_warp(due, float(burst["every_s"]),
+                          float(burst["factor"]))
+    offsets = np.zeros(n + 1, np.int64)
+    np.cumsum(sizes, out=offsets[1:])
+    total = int(offsets[-1])
+    alpha = float(traffic["id_alpha"])
+    return ServeSchedule(
+        due_s=due, offsets=offsets,
+        ids=[power_law_ids(rng, s, (total,), alpha) for s in table_sizes],
+        numerical=rng.standard_normal(size=(total, num_numerical),
+                                      dtype=np.float32))

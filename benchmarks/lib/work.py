@@ -1,0 +1,49 @@
+"""Operations and bytes that each kernel's work needs, from the cell's shapes
+alone, so that a roofline share reads the same work whatever implements it."""
+
+from __future__ import annotations
+
+_BYTES = {"bfloat16": 2, "float32": 4}
+
+
+def dense_forward_flops_per_sample(config: dict) -> float:
+    """Matmul FLOP of one sample's forward pass: both MLPs and the Gram
+    matrix of the dot interaction (the count of the program's
+    ``bench.dense_flops_per_sample`` without its factor 3, copied)."""
+    dims = [int(config["num_numerical"])] + list(config["bottom_mlp"])
+    f = sum(2 * a * b for a, b in zip(dims, dims[1:]))
+    nf = len(config["table_sizes"]) + 1
+    dim = int(config["embedding_dim"])
+    f += 2 * nf * nf * dim
+    dims = [nf * (nf - 1) // 2 + dim] + list(config["top_mlp"])
+    return float(f + sum(2 * a * b for a, b in zip(dims, dims[1:])))
+
+
+def dense_train_flops_per_sample(config: dict) -> float:
+    """Forward, input gradient and weight gradient; nothing recomputed."""
+    return 3.0 * dense_forward_flops_per_sample(config)
+
+
+def lookup_bytes(config: dict, ids: float, outputs: float) -> float:
+    """HBM bytes of a forward lookup: ``ids`` table rows read and ``outputs``
+    combined rows written in the compute dtype."""
+    dim = int(config["embedding_dim"])
+    return dim * (ids * _BYTES[config["table_dtype"]]
+                  + outputs * _BYTES[config["compute_dtype"]])
+
+
+def apply_bytes(config: dict, ids: float, distinct_rows: float) -> float:
+    """HBM bytes of a sparse SGD apply: each distinct touched row read and
+    written once, and one gradient row streamed in for every id."""
+    dim = int(config["embedding_dim"])
+    return dim * (2 * distinct_rows * _BYTES[config["table_dtype"]]
+                  + ids * _BYTES[config["compute_dtype"]])
+
+
+def roofline_share(flops: float, nbytes: float, seconds: float,
+                   peaks: dict) -> float:
+    """The least time the chip could take for the work over the time it took,
+    in percent."""
+    least = max(flops / peaks["flops_per_s"],
+                nbytes / peaks["hbm_bytes_per_s"])
+    return 100.0 * least / seconds
