@@ -392,6 +392,7 @@ class ServingRuntime:
         self._queued_samples = 0
         self._level = 0
         self._next_rid = 0
+        self._flush_seq = 0         # flush ordinals, minted as one starts
         self._input_spec: Optional[List[tuple]] = None
         self._batch_spec: Optional[Any] = None
         self._est_s = 0.0           # EMA of flush wall seconds
@@ -761,38 +762,40 @@ class ServingRuntime:
         """Admit one request. Returns ``None`` (queued — the answer
         arrives from a later :meth:`poll`) or a typed
         :class:`Overloaded` when the admission controller sheds it."""
-        now = self._clock() if now is None else now
-        req = self._normalize(req, now)
-        q = self._queued_samples
-        shed_at = self.config.shed_frac * self.config.max_queue
-        reason = None
-        if q + req.n > self.config.max_queue:
-            reason = "queue_full"
-        elif self._stale and req.priority <= 0:
-            # freshness rung: publication fell behind the SLO — refuse
-            # low-priority load rather than serve ever-staler answers
-            # (or block training to catch up)
-            reason = "stale_snapshot"
-        elif q >= shed_at and req.priority <= 0:
-            reason = "load_shed"
-        if reason is not None:
-            self._count("shed")
-            if reason == "stale_snapshot":
-                self._count("stale_shed")
-            obs.counter_inc("serve_shed")
+        with obs.span("serve/submit") as sp:
+            now = self._clock() if now is None else now
+            req = self._normalize(req, now)
+            sp.set_metadata(rid=req.rid, n=req.n)
+            q = self._queued_samples
+            shed_at = self.config.shed_frac * self.config.max_queue
+            reason = None
+            if q + req.n > self.config.max_queue:
+                reason = "queue_full"
+            elif self._stale and req.priority <= 0:
+                # freshness rung: publication fell behind the SLO — refuse
+                # low-priority load rather than serve ever-staler answers
+                # (or block training to catch up)
+                reason = "stale_snapshot"
+            elif q >= shed_at and req.priority <= 0:
+                reason = "load_shed"
+            if reason is not None:
+                self._count("shed")
+                if reason == "stale_snapshot":
+                    self._count("stale_shed")
+                obs.counter_inc("serve_shed")
+                self._update_level()
+                spans = self._terminal_spans(req.rid, "overloaded", 0.0, now,
+                                             reason=reason, level=self._level,
+                                             queue_samples=q)
+                return Overloaded(rid=req.rid, latency_ms=0.0, reason=reason,
+                                  level=self._level, queue_samples=q,
+                                  spans=spans)
+            with self._state_lock:
+                self._queue.append(req)
+                self._queued_samples += req.n
+            self._qdepth_sketch.observe(self._queued_samples)
             self._update_level()
-            spans = self._terminal_spans(req.rid, "overloaded", 0.0, now,
-                                         reason=reason, level=self._level,
-                                         queue_samples=q)
-            return Overloaded(rid=req.rid, latency_ms=0.0, reason=reason,
-                              level=self._level, queue_samples=q,
-                              spans=spans)
-        with self._state_lock:
-            self._queue.append(req)
-            self._queued_samples += req.n
-        self._qdepth_sketch.observe(self._queued_samples)
-        self._update_level()
-        return None
+            return None
 
     @property
     def queued_samples(self) -> int:
@@ -861,69 +864,88 @@ class ServingRuntime:
                                "layout comes from the template request")
         return self._pack([], rung)
 
+    @staticmethod
+    def _h2d(buf: np.ndarray):
+        """One host-to-device transfer under its own ``serve/h2d`` span:
+        the host's time in the ``jnp.asarray`` call, not the DMA's."""
+        import jax.numpy as jnp
+
+        with obs.span("serve/h2d", bytes=buf.nbytes):
+            return jnp.asarray(buf)
+
     def _pack(self, reqs: List[Request], rung: int):
         """Coalesce ``reqs`` (total samples <= rung) into one padded
         rung-shaped input set. Padding samples are whole fake rows: id 0
         everywhere, zero dense features, zero-length ragged rows —
-        their predictions are sliced off below."""
-        import jax.numpy as jnp
-
+        their predictions are sliced off below. The numpy work runs
+        under ``serve/pack`` spans and each buffer's transfer under a
+        ``serve/h2d`` span of its own (:func:`~.obs.span`: the flush as
+        a profile shows it); a buffer is filled, then sent, so the two
+        interleave and a flush's pack + h2d spans sum to its
+        ``coalesce_ms``."""
         spec, bspec = self._input_spec, self._batch_spec
-        offsets = []
-        off = 0
-        for r in reqs:
-            offsets.append(off)
-            off += r.n
+        with obs.span("serve/pack"):
+            offsets = []
+            off = 0
+            for r in reqs:
+                offsets.append(off)
+                off += r.n
         cats_out = []
         for i, (kind, hot) in enumerate(spec):
             if kind == "d":
-                shape = (rung,) if hot == 1 else (rung, hot)
-                buf = np.zeros(shape, np.int32)
-                for r, o in zip(reqs, offsets):
-                    a = np.asarray(r.cats[i], np.int32)
-                    buf[o:o + r.n] = a if hot > 1 or a.ndim == 1 \
-                        else a.reshape(r.n)
-                cats_out.append(jnp.asarray(buf))
+                with obs.span("serve/pack"):
+                    shape = (rung,) if hot == 1 else (rung, hot)
+                    buf = np.zeros(shape, np.int32)
+                    for r, o in zip(reqs, offsets):
+                        a = np.asarray(r.cats[i], np.int32)
+                        buf[o:o + r.n] = a if hot > 1 or a.ndim == 1 \
+                            else a.reshape(r.n)
+                cats_out.append(self._h2d(buf))
             else:
                 # ragged: per-SHARD CSR segments concatenated, so the
                 # shard_map P(axis) split hands each rank a local
                 # (values[cap_local], row_splits[b_local+1]) pair
-                b_local = rung // self.world
-                cap_local = b_local * hot
-                values = np.zeros((self.world * cap_local,), np.int32)
-                splits = np.zeros((self.world * (b_local + 1),), np.int32)
-                row_lists: List[List[int]] = [[] for _ in range(rung)]
-                for r, o in zip(reqs, offsets):
-                    for j, row in enumerate(r.cats[i]):
-                        row_lists[o + j] = row
-                for s in range(self.world):
-                    base = s * cap_local
-                    pos = 0
-                    sbase = s * (b_local + 1)
-                    splits[sbase] = 0
-                    for j in range(b_local):
-                        row = row_lists[s * b_local + j]
-                        values[base + pos:base + pos + len(row)] = row
-                        pos += len(row)
-                        splits[sbase + j + 1] = pos
-                cats_out.append(Ragged(values=jnp.asarray(values),
-                                       row_splits=jnp.asarray(splits)))
+                with obs.span("serve/pack"):
+                    b_local = rung // self.world
+                    cap_local = b_local * hot
+                    values = np.zeros((self.world * cap_local,), np.int32)
+                    splits = np.zeros((self.world * (b_local + 1),),
+                                      np.int32)
+                    row_lists: List[List[int]] = [[] for _ in range(rung)]
+                    for r, o in zip(reqs, offsets):
+                        for j, row in enumerate(r.cats[i]):
+                            row_lists[o + j] = row
+                    for s in range(self.world):
+                        base = s * cap_local
+                        pos = 0
+                        sbase = s * (b_local + 1)
+                        splits[sbase] = 0
+                        for j in range(b_local):
+                            row = row_lists[s * b_local + j]
+                            values[base + pos:base + pos + len(row)] = row
+                            pos += len(row)
+                            splits[sbase + j + 1] = pos
+                cats_out.append(Ragged(values=self._h2d(values),
+                                       row_splits=self._h2d(splits)))
 
         def pack_leaf(path_spec, leaves):
             trailing, dtype = path_spec
-            buf = np.zeros((rung,) + trailing, np.dtype(dtype))
-            for r, o, leaf in zip(reqs, offsets, leaves):
-                buf[o:o + r.n] = np.asarray(leaf)
-            return jnp.asarray(buf)
+            with obs.span("serve/pack"):
+                buf = np.zeros((rung,) + trailing, np.dtype(dtype))
+                for r, o, leaf in zip(reqs, offsets, leaves):
+                    buf[o:o + r.n] = np.asarray(leaf)
+            return self._h2d(buf)
 
         if bspec is None or not jax.tree.leaves(bspec):
             batch_out = bspec if bspec is None else jax.tree.map(
                 lambda s: None, bspec)
         else:
-            req_leaves = [jax.tree.leaves(r.batch) for r in reqs] or None
-            flat_spec, tree = jax.tree_util.tree_flatten(
-                self._batch_spec, is_leaf=lambda x: isinstance(x, tuple)
-                and len(x) == 2 and isinstance(x[1], str))
+            with obs.span("serve/pack"):
+                req_leaves = ([jax.tree.leaves(r.batch) for r in reqs]
+                              or None)
+                flat_spec, tree = jax.tree_util.tree_flatten(
+                    self._batch_spec, is_leaf=lambda x: isinstance(x, tuple)
+                    and len(x) == 2 and isinstance(x[1], str))
             packed = []
             for li, s in enumerate(flat_spec):
                 leaves = ([rl[li] for rl in req_leaves]
@@ -980,6 +1002,26 @@ class ServingRuntime:
 
     def _run_flush(self, reqs: List[Request],
                    rung: int) -> List[Served]:
+        """One flush, opened for a profile by :func:`~.obs.span`: a
+        ``serve/flush`` span (args: the flush ordinal the request traces
+        carry, the rung, requests and samples) whose children on this
+        thread are ``serve/pack`` + ``serve/h2d`` (:meth:`_pack`),
+        ``serve/dispatch``, ``serve/fetch`` (device compute + the copy
+        back) and ``serve/reply``. The spans record only while a
+        profiler session runs; ``Served.spans`` and :data:`STAGES` are
+        :meth:`_flush`'s own clock reads, as ever."""
+        with self._state_lock:
+            # the flush ordinal doubles as the coalesce-span id linking
+            # the N request traces that shared this flush: minted as
+            # the flush starts, so its serve/flush span carries it too
+            self._flush_seq += 1
+            flush_id = self._flush_seq
+        with obs.span("serve/flush", flush=flush_id, rung=rung,
+                      requests=len(reqs), samples=sum(r.n for r in reqs)):
+            return self._flush(reqs, rung, flush_id)
+
+    def _flush(self, reqs: List[Request], rung: int,
+               flush_id: int) -> List[Served]:
         runtime_mod.fault_point("serve_step")
         t0 = self._clock()
         # read the published triple ONCE: the whole flush — tables,
@@ -988,83 +1030,84 @@ class ServingRuntime:
         published = self._published
         cats, batch, offsets = self._pack(reqs, rung)
         t_pack = self._clock()
-        pending = self._dispatch(cats, batch, published)
+        with obs.span("serve/dispatch"):
+            pending = self._dispatch(cats, batch, published)
         t_disp = self._clock()
-        preds = np.asarray(pending)  # device compute + host fetch
+        with obs.span("serve/fetch"):
+            preds = np.asarray(pending)  # device compute + host fetch
         t_dev = self._clock()
-        slices = [preds[o:o + r.n] for r, o in zip(reqs, offsets)]
-        t1 = self._clock()
-        with self._state_lock:
-            # flush accounting only — the device work above ran
-            # lock-free against the RCU-read published triple
-            self._est_s = (t_dev - t0 if not self._est_s
-                           else 0.7 * self._est_s + 0.3 * (t_dev - t0))
-            n = sum(r.n for r in reqs)
-            self._pad_slots += rung - n
-            self._total_slots += rung
-            self._counts["flushes"] += 1
-            self._rung_flushes[rung] = self._rung_flushes.get(rung, 0) + 1
-            # the flush ordinal doubles as the coalesce-span id linking
-            # the N request traces that shared this flush
-            flush_id = self._counts["flushes"]
-        # latency decomposition: the flush-level spans are shared by
-        # every coalesced request (they waited on the SAME pack /
-        # dispatch / device / slice work); queue wait is per request.
-        # The five spans sum to each request's latency by construction
-        coalesce_ms = (t_pack - t0) * 1e3
-        dispatch_ms = (t_disp - t_pack) * 1e3
-        device_ms = (t_dev - t_disp) * 1e3
-        reply_ms = (t1 - t_dev) * 1e3
-        # per-response freshness: how stale the answering snapshot was at
-        # flush time, in steps (vs the trainer's newest completed step)
-        # and seconds (snapshot age) — the freshness SLO's raw samples
-        meta = published[2]
-        version = -1
-        stale_steps: Optional[float] = None
-        stale_s: Optional[float] = None
-        if meta is not None:
-            version, snap_step, pub_t = meta
-            latest = (self._latest_train_step if self._latest_train_step
-                      is not None else snap_step)
-            stale_steps = float(max(0, latest - snap_step))
-            stale_s = float(max(0.0, t_dev - pub_t))
-        out = []
-        for r, pred in zip(reqs, slices):
-            lat = (t1 - r.t_submit) * 1e3
-            queue_wait_ms = (t0 - r.t_submit) * 1e3
-            missed = t1 > r.deadline
-            spans = {"queue_wait_ms": queue_wait_ms,
-                     "coalesce_ms": coalesce_ms,
-                     "dispatch_ms": dispatch_ms,
-                     "device_compute_ms": device_ms,
-                     "reply_slice_ms": reply_ms}
-            self._lat_sketch.observe(lat)
-            for stage, v in zip(STAGES, spans.values()):
-                self._stage_sketch[stage].observe(max(0.0, v))
+        with obs.span("serve/reply"):
+            slices = [preds[o:o + r.n] for r, o in zip(reqs, offsets)]
+            t1 = self._clock()
+            with self._state_lock:
+                # flush accounting only — the device work above ran
+                # lock-free against the RCU-read published triple
+                self._est_s = (t_dev - t0 if not self._est_s
+                               else 0.7 * self._est_s + 0.3 * (t_dev - t0))
+                n = sum(r.n for r in reqs)
+                self._pad_slots += rung - n
+                self._total_slots += rung
+                self._counts["flushes"] += 1
+                self._rung_flushes[rung] = \
+                    self._rung_flushes.get(rung, 0) + 1
+            # latency decomposition: the flush-level spans are shared by
+            # every coalesced request (they waited on the SAME pack /
+            # dispatch / device / slice work); queue wait is per request.
+            # The five spans sum to each request's latency by construction
+            coalesce_ms = (t_pack - t0) * 1e3
+            dispatch_ms = (t_disp - t_pack) * 1e3
+            device_ms = (t_dev - t_disp) * 1e3
+            reply_ms = (t1 - t_dev) * 1e3
+            # per-response freshness: how stale the answering snapshot was at
+            # flush time, in steps (vs the trainer's newest completed step)
+            # and seconds (snapshot age) — the freshness SLO's raw samples
+            meta = published[2]
+            version = -1
+            stale_steps: Optional[float] = None
+            stale_s: Optional[float] = None
             if meta is not None:
-                self._fresh_steps_sketch.observe(stale_steps)
-                self._fresh_s_sketch.observe(stale_s)
-            self._count("served")
-            self._count("served_samples", r.n)
-            if missed:
-                self._count("deadline_missed")
-                obs.counter_inc("serve_deadline_missed")
-            obs.counter_inc("serve_served")
-            # the trace's stage partition is exactly the spans dict
-            # (bare stage names): sum == latency_ms by the telescoping
-            # construction above — the 1e-6 invariant check-tracing
-            # asserts on every retained trace
-            self.traces.finish(r.rid, "served", lat, t1,
-                               dict(zip(STAGES, spans.values())),
-                               flush=flush_id, coalesced=len(reqs),
-                               rung=rung, flush_t0=t0, version=version,
-                               deadline_missed=missed)
-            out.append(Served(rid=r.rid, latency_ms=lat,
-                              predictions=pred, rung=rung,
-                              deadline_missed=missed, version=version,
-                              staleness_steps=stale_steps,
-                              staleness_s=stale_s, spans=spans))
-        return out
+                version, snap_step, pub_t = meta
+                latest = (self._latest_train_step if self._latest_train_step
+                          is not None else snap_step)
+                stale_steps = float(max(0, latest - snap_step))
+                stale_s = float(max(0.0, t_dev - pub_t))
+            out = []
+            for r, pred in zip(reqs, slices):
+                lat = (t1 - r.t_submit) * 1e3
+                queue_wait_ms = (t0 - r.t_submit) * 1e3
+                missed = t1 > r.deadline
+                spans = {"queue_wait_ms": queue_wait_ms,
+                         "coalesce_ms": coalesce_ms,
+                         "dispatch_ms": dispatch_ms,
+                         "device_compute_ms": device_ms,
+                         "reply_slice_ms": reply_ms}
+                self._lat_sketch.observe(lat)
+                for stage, v in zip(STAGES, spans.values()):
+                    self._stage_sketch[stage].observe(max(0.0, v))
+                if meta is not None:
+                    self._fresh_steps_sketch.observe(stale_steps)
+                    self._fresh_s_sketch.observe(stale_s)
+                self._count("served")
+                self._count("served_samples", r.n)
+                if missed:
+                    self._count("deadline_missed")
+                    obs.counter_inc("serve_deadline_missed")
+                obs.counter_inc("serve_served")
+                # the trace's stage partition is exactly the spans dict
+                # (bare stage names): sum == latency_ms by the telescoping
+                # construction above — the 1e-6 invariant check-tracing
+                # asserts on every retained trace
+                self.traces.finish(r.rid, "served", lat, t1,
+                                   dict(zip(STAGES, spans.values())),
+                                   flush=flush_id, coalesced=len(reqs),
+                                   rung=rung, flush_t0=t0, version=version,
+                                   deadline_missed=missed)
+                out.append(Served(rid=r.rid, latency_ms=lat,
+                                  predictions=pred, rung=rung,
+                                  deadline_missed=missed, version=version,
+                                  staleness_steps=stale_steps,
+                                  staleness_s=stale_s, spans=spans))
+            return out
 
     def poll(self, now: Optional[float] = None) -> List[ServeResult]:
         """Run the scheduler once: expire dead requests, flush every due

@@ -905,18 +905,24 @@ def make_hybrid_eval_step(de: DistributedEmbedding,
     world = de.world_size
     dyn_cfg = streaming_mod.resolve_config(dynamic)
 
+    # the train step's phase names, so a serve profile attributes its
+    # device time the same way (dense_forward: there is no backward)
     if dyn_cfg is None:
         def local_eval(state: HybridTrainState, cat_inputs, batch):
-            outs = de(state.emb_params, cat_inputs)
-            return pred_fn(state.dense_params, outs, batch)
+            with obs.scope("embedding_forward"):
+                outs = de(state.emb_params, cat_inputs)
+            with obs.scope("dense_forward"):
+                return pred_fn(state.dense_params, outs, batch)
     else:
         def local_eval(state: HybridTrainState, cat_inputs, batch,
                        stream):
-            outs, _ = de.forward_with_residuals(
-                state.emb_params, cat_inputs,
-                streaming=(dyn_cfg, streaming_mod.local_state(stream),
-                           False))
-            return pred_fn(state.dense_params, outs, batch)
+            with obs.scope("embedding_forward"):
+                outs, _ = de.forward_with_residuals(
+                    state.emb_params, cat_inputs,
+                    streaming=(dyn_cfg, streaming_mod.local_state(stream),
+                               False))
+            with obs.scope("dense_forward"):
+                return pred_fn(state.dense_params, outs, batch)
 
     # inputs only: the state (and streaming state) must survive calls
     donate = (1, 2) if donate_inputs else ()

@@ -9,11 +9,11 @@ from .checkpoint import (previous_checkpoint_path, reshard_checkpoint,
                          validate_checkpoint_model, verify_checkpoint)
 from .data import DummyDataset, RawBinaryDataset, fast_forward, power_law_ids
 from .metrics import binary_auc
-from .obs import (MetricsLogger, StepTimer, counter_inc, counters,
+from .obs import (MetricsLogger, counter_inc, counters,
                   fetch_metrics, install_compile_listener,
                   maybe_start_server, metrics_enabled, nanguard_enabled,
                   nanguard_escalation_k, profile_trace, reset_counters,
-                  scope)
+                  scope, span)
 from .runtime import (BackendProbe, BackendUnavailable, CheckpointCorrupt,
                       CheckpointMismatch, CoordinatorUnreachable,
                       DeadlineExceeded, FaultInjected, InvalidInputError,

@@ -17,6 +17,10 @@ Three layers, all off by default and <1% overhead when disabled:
   are therefore always on. :func:`profile_trace` (gated by
   ``DETPU_PROFILE_DIR``) and :func:`maybe_start_server` (gated by
   ``DETPU_PROFILE_PORT``) capture the profiles the scopes annotate.
+  :func:`span` is the host twin: a ``jax.profiler.TraceAnnotation`` on
+  the calling thread's line of the same capture, on the device ops'
+  clock (the serving runtime's flush is opened with it); inert while no
+  capture runs.
 * **On-device step metrics** — a plain-dict pytree (keys
   :data:`STEP_METRIC_KEYS`) computed *inside* the jitted step by
   ``DistributedEmbedding.step_metrics`` + ``trainer.make_hybrid_train_step
@@ -185,6 +189,30 @@ def scope(name: str):
     import jax
 
     return jax.named_scope(f"{SCOPE_PREFIX}/{name}")
+
+
+def span(name: str, **args: Any):
+    """``jax.profiler.TraceAnnotation("detpu/<name>", **args)`` — the host
+    twin of :func:`scope`. While a profiler session runs
+    (:func:`profile_trace`, the profiler server, a benchmark's traced
+    run) the span lands on the calling thread's line of the same trace
+    as the device ops, on their clock, with ``args`` as its metadata;
+    nesting on a thread gives each span its parent. While none runs it
+    records nothing (half a microsecond), so call sites use it
+    unconditionally. A span times the HOST's stay in the block: around
+    an asynchronous call (a transfer, a dispatch) that is the call, not
+    the device work it starts."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(f"{SCOPE_PREFIX}/{name}", **args)
+
+
+def is_span_event(name: Optional[str]) -> bool:
+    """Whether a trace-event NAME is a host :func:`span`. A device op
+    carries its ``detpu/...`` scopes inside its metadata (``tf_op`` /
+    ``op_name``), never at the start of its name, so trace readers skip
+    these before attributing device time."""
+    return bool(name) and str(name).startswith(SCOPE_PREFIX + "/")
 
 
 @contextlib.contextmanager
@@ -628,29 +656,6 @@ def record_retry(describe: str) -> None:
     attempt (the success that needed no retry bumps nothing)."""
     counter_inc("runtime_retries")
     counter_inc(f"runtime_retries.{describe.replace(' ', '_')}")
-
-
-class StepTimer:
-    """Tiny host-side wall-clock phase accumulator for loops that want
-    coarse (non-XLA) timing next to the on-device metrics: ``with
-    timer.section("eval"): ...``; :meth:`totals` returns seconds per
-    label. Not a profiler — the XLA trace is — just enough to see where a
-    *host* loop spends its time."""
-
-    def __init__(self):
-        self._totals: Dict[str, float] = {}
-
-    @contextlib.contextmanager
-    def section(self, label: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._totals[label] = (self._totals.get(label, 0.0)
-                                   + time.perf_counter() - t0)
-
-    def totals(self) -> Dict[str, float]:
-        return dict(self._totals)
 
 
 def env_stamp() -> Dict[str, Any]:

@@ -43,6 +43,7 @@ import json
 import logging
 import os
 import random
+import re
 import signal
 import subprocess
 import sys
@@ -66,26 +67,54 @@ _PKG_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
+#: jax flags :func:`ensure_compile_cache` sets beside the directory: the
+#: cache key then holds each op's metadata, with this checkout's own path
+#: taken off the file names so that a checkout that moves still hits
+CACHE_KEY_FLAGS = {
+    "jax_compilation_cache_include_metadata_in_key": True,
+    "jax_hlo_source_file_canonicalization_regex":
+        re.escape(_PKG_ROOT + os.sep),
+}
+
+
 def ensure_compile_cache() -> str:
     """Point JAX's persistent compilation cache at a stable directory;
     every entry point calls this first. Returns the directory in use.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, the operator placed the
-    cache and nothing is changed. Otherwise the cache goes to
+    cache and the directory is left alone. Otherwise the cache goes to
     ``<checkout>/.jax_cache`` — one fixed path, so the next process finds
     what this one compiled — and the variable is exported so child
     processes inherit the same directory.
     jax reads the variable at import; a jax imported earlier is told
-    through its config (the cache opens lazily at the first compile)."""
+    through its config (the cache opens lazily at the first compile).
+
+    Wherever the cache lies, its key is made to hold the ops' metadata
+    (:data:`CACHE_KEY_FLAGS`). jax leaves it out by default, and an
+    executable loaded from the cache keeps the metadata it was compiled
+    with: after a change to the ``obs.scope`` names alone, a profile
+    would show the device ops under the scopes of whichever commit
+    filled the cache first, and every reader of scopes would attribute
+    by them. A flag the operator's environment sets is left alone."""
+    for flag, value in CACHE_KEY_FLAGS.items():
+        if flag.upper() not in os.environ:
+            os.environ[flag.upper()] = str(value)
+            _tell_jax(flag, value)
     path = os.environ.get(COMPILE_CACHE_ENV)
     if path:
         return path
     path = os.path.join(_PKG_ROOT, ".jax_cache")
     os.environ[COMPILE_CACHE_ENV] = path
+    _tell_jax("jax_compilation_cache_dir", path)
+    return path
+
+
+def _tell_jax(flag: str, value) -> None:
+    """jax reads its flags from the environment at import; one imported
+    earlier is told through its config."""
     jax = sys.modules.get("jax")
     if jax is not None:
-        jax.config.update("jax_compilation_cache_dir", path)
-    return path
+        jax.config.update(flag, value)
 
 
 # ------------------------------------------------------------------ errors

@@ -160,10 +160,13 @@ def parse_events(doc: Dict[str, Any],
         if not isinstance(dur, (int, float)) or dur <= 0:
             continue
         name = str(e.get("name", ""))
-        if is_host_event(name) or obs.is_request_event(name):
+        if (is_host_event(name) or obs.is_request_event(name)
+                or obs.is_span_event(name)):
             # request-tracing events (utils/reqtrace.py exports into the
             # same Chrome-trace container) are serving spans, not device
-            # work — parse_request_traces reads them
+            # work — parse_request_traces reads them; obs.span host
+            # spans are NAMED detpu/..., which the scope regex would
+            # otherwise read as a device op under that phase
             continue
         args = e.get("args")
         phase, resolved = _phase_from_args(

@@ -5,6 +5,7 @@ exits non-zero naming the check. Plus the compile-cache helper every entry
 point calls first."""
 
 import os
+import re
 import subprocess
 import sys
 
@@ -57,9 +58,43 @@ def test_failed_check_exits_nonzero_naming_the_check(tmp_path):
 
 
 def test_compile_cache_respects_an_outside_setting(monkeypatch):
+    import jax
+
     monkeypatch.setenv(runtime.COMPILE_CACHE_ENV, "/somewhere/else")
+    # the key's flags, set by the operator, are the operator's too (and
+    # this process's jax config stays as the other tests expect it)
+    for flag in runtime.CACHE_KEY_FLAGS:
+        monkeypatch.setenv(flag.upper(), "")
     assert runtime.ensure_compile_cache() == "/somewhere/else"
     assert os.environ[runtime.COMPILE_CACHE_ENV] == "/somewhere/else"
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+
+
+def test_compile_cache_key_holds_the_scopes(tmp_path):
+    """An executable from the cache keeps the metadata it was compiled
+    with, so the key holds the metadata (the obs.scope names a profile is
+    read by), with the checkout's own path taken off the file names —
+    whether jax was imported before the call or after, and wherever the
+    operator put the directory."""
+    code = ("import os, sys; sys.path.insert(0, %r); %s"
+            "from distributed_embeddings_tpu.utils import runtime; "
+            "runtime.ensure_compile_cache(); import jax; "
+            "print(jax.config.jax_compilation_cache_include_metadata_in_key,"
+            " jax.config.jax_hlo_source_file_canonicalization_regex)")
+    env = {k: v for k, v in os.environ.items()
+           if k.lower() not in runtime.CACHE_KEY_FLAGS}
+    env[runtime.COMPILE_CACHE_ENV] = str(tmp_path)
+    for early in ("", "import jax; "):
+        p = subprocess.run([sys.executable, "-c", code % (REPO, early)],
+                           env=env, capture_output=True, text=True,
+                           timeout=120)
+        assert p.returncode == 0, p.stderr[-2000:]
+        assert p.stdout.split() == ["True", re.escape(REPO + os.sep)]
+    regex = runtime.CACHE_KEY_FLAGS[
+        "jax_hlo_source_file_canonicalization_regex"]
+    assert re.sub(regex, "", os.path.join(
+        REPO, "distributed_embeddings_tpu", "parallel", "trainer.py")) \
+        == "distributed_embeddings_tpu/parallel/trainer.py"
 
 
 def test_compile_cache_default_is_one_fixed_path_in_the_checkout(tmp_path):

@@ -472,3 +472,23 @@ def test_scope_and_profile_trace_noop(tmp_path, monkeypatch):
         jnp.zeros((2,)).block_until_ready()
     # a capture directory was created for the label
     assert os.path.isdir(os.path.join(d, "lbl"))
+
+
+def test_span_is_inert_outside_a_capture():
+    """obs.span is the host twin of obs.scope: a context manager that
+    records nothing while no profiler session runs, takes its metadata
+    at the call or later, and brings no import of jax to module scope
+    (the package path imports jax anyway, so the rule is the static
+    one)."""
+    from tools import detlint
+
+    with obs.span("unit_test", flush=3, rung=8) as sp:
+        sp.set_metadata(rid=1)         # inert too
+        with obs.span("unit_test/child"):
+            pass
+    assert obs.is_span_event("detpu/serve/flush")
+    assert not obs.is_span_event("fusion.4")
+    assert not obs.is_span_event("req/flush")
+    assert not obs.is_span_event(None)
+    assert [f for f in detlint.run(rule_names=["module-scope-jax"])
+            if f.path.endswith("utils/obs.py")] == []

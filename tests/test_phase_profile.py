@@ -81,6 +81,23 @@ def test_fused_event_and_missing_opname():
     assert events[2].resolved and events[2].phase == ""
 
 
+def test_host_spans_are_not_device_ops():
+    """An obs.span lands in the same capture NAMED detpu/...: the scope
+    regex would read it as an op under that phase, so the parser drops
+    it (like req/ events), and with it phase_profile's measurement."""
+    doc = _doc(
+        _ev("detpu/serve/flush", 0, 900, pid=701, tid=9, flush="3"),
+        _ev("detpu/serve/h2d", 10, 300, pid=701, tid=9, bytes="1024"),
+        _ev("fusion.1", 400, 50, pid=3, tid=3,
+            tf_op="jit(local_eval)/detpu/dense_forward/dot_general:"),
+    )
+    events = traceparse.parse_events(doc)
+    assert [e.name for e in events] == ["fusion.1"]
+    m = traceparse.measure_events(events)
+    assert set(m["phase_ms"]) == {"dense_forward"}
+    assert m["wall_ms"] == pytest.approx(0.05)
+
+
 def test_bare_name_resolver_join():
     """CPU-style events (bare instruction names) join through a
     resolver, including the ``.clone`` fallback and the ``hlo_op``

@@ -707,6 +707,77 @@ def test_served_spans_sum_exactly_to_latency():
     assert seen == 6
 
 
+def _host_spans(capture_dir):
+    """The ``detpu/...`` host events of a profiler capture, by thread
+    and in order of start: ``(name, ts, ts + dur, args)``."""
+    from distributed_embeddings_tpu.utils import traceparse
+
+    (path,) = traceparse.trace_files(capture_dir)
+    by_thread = {}
+    for e in traceparse.load_trace(path)["traceEvents"]:
+        if e.get("ph") == "X" and obs.is_span_event(e.get("name")):
+            by_thread.setdefault((e["pid"], e["tid"]), []).append(
+                (e["name"][len(obs.SCOPE_PREFIX) + 1:], e["ts"],
+                 e["ts"] + e["dur"],
+                 e.get("args") or {}))
+    return {t: sorted(v, key=lambda x: (x[1], -x[2]))
+            for t, v in by_thread.items()}
+
+
+def test_flush_spans_land_in_a_profiler_capture(tmp_path):
+    """Under a jax.profiler capture one flush of two requests leaves one
+    serve/flush span on the serving thread's line whose children are in
+    the flush's own order, whose ``flush`` arg is the ordinal both
+    requests' traces carry, and which holds one serve/h2d span an input
+    leaf. With no capture running nothing of the runtime changes: the
+    tests around this one run the same code."""
+    from distributed_embeddings_tpu.utils import reqtrace
+
+    de, state, rt, clock = _build_ticking()
+    rt.traces = reqtrace.TraceBuffer(
+        capacity=8, sample=1.0, seed=0, enabled=True, process="serve",
+        top_fn=rt._trace_top_decile)
+    rt.warmup(_tmpl())
+    rng = np.random.default_rng(4)
+    reqs = [_req(rng, n=2), _req(rng, n=3)]
+    with jax.profiler.trace(str(tmp_path)):
+        for r in reqs:
+            assert rt.submit(r) is None
+        clock.t += 0.007
+        served = rt.poll()
+    assert [type(r) for r in served] == [Served, Served]
+    for r in served:
+        assert set(r.spans) == {f"{st}_ms" for st in sv.STAGES}
+        assert sum(r.spans.values()) == pytest.approx(r.latency_ms,
+                                                      rel=1e-9)
+    threads = _host_spans(str(tmp_path))
+    (spans,) = threads.values()        # one thread submitted and polled
+    submits = [s for s in spans if s[0] == "serve/submit"]
+    assert [(int(s[3]["rid"]), int(s[3]["n"])) for s in submits] \
+        == [(r.rid, r.n) for r in reqs]
+    (flush,) = [s for s in spans if s[0] == "serve/flush"]
+    assert {k: int(v) for k, v in flush[3].items()} == {
+        "flush": 1, "rung": 8, "requests": 2, "samples": 5}
+    assert {t["attrs"]["flush"] for t in rt.traces.snapshot()} == {1}
+    assert len(rt.traces.snapshot()) == 2
+    inside = [s for s in spans if s is not flush
+              and s[1] >= flush[1] and s[2] <= flush[2]]
+    assert len(inside) == len(spans) - len(submits) - 1
+    names = [s[0] for s in inside]
+    n_leaves = len(jax.tree.leaves(_tmpl()))
+    assert names.count("serve/h2d") == n_leaves == 3
+    # a buffer is filled, then sent; then the call, the wait, the reply
+    assert names[-3:] == ["serve/dispatch", "serve/fetch", "serve/reply"]
+    head = names[:-3]
+    assert set(head) == {"serve/pack", "serve/h2d"}
+    assert all(head[i - 1] == "serve/pack"
+               for i, n in enumerate(head) if n == "serve/h2d")
+    for a, b in zip(inside, inside[1:]):
+        assert a[2] <= b[1]            # siblings: none overlaps the next
+    assert all(int(s[3]["bytes"]) > 0 for s in inside
+               if s[0] == "serve/h2d")
+
+
 def test_stats_sketch_percentiles_match_numpy_reference():
     # the serving battery's pin: sketch-backed stats() percentiles sit
     # within the sketch's guaranteed relative error of the numpy
