@@ -11,8 +11,11 @@ family:
 
 * **The compiled forward** — a no-grad step built from
   :func:`~.trainer.make_hybrid_eval_step` with ``donate_inputs=True``
-  (each flush's freshly packed input buffers are dead the moment the
-  step consumes them) and frozen tables. Streaming tables serve
+  (each flush's freshly packed input buffer is dead the moment the
+  step consumes it) and frozen tables. Every input leaf of a flush
+  travels in ONE staging buffer and one host-to-device transfer
+  (:class:`PackLayout`); the program's first stage unpacks it on the
+  device by static slices. Streaming tables serve
   READ-ONLY: admitted ids read their slots, cold/evicted ids degrade to
   their shared hash-bucket rows, and no admission/eviction runs at
   serve time — the slot map, sketch and counters are bitwise-unchanged
@@ -96,6 +99,13 @@ logger = logging.getLogger(__name__)
 
 #: degradation-ladder levels (index = level)
 LEVELS = ("healthy", "pressure", "shed")
+
+#: the longest :meth:`ServingRuntime.poll` stays away when requests are
+#: queued and none is due: a caller that loops on ``poll()`` through the
+#: batching delay gets its core back in slices of this length instead of
+#: making a quarter of a million empty calls a second, and an arrival
+#: waits at most this long for its ``submit``
+POLL_IDLE_S = 2e-4
 
 #: per-request latency decomposition stages, in pipeline order: the time
 #: between submit and the reply splits EXACTLY into these five spans
@@ -322,6 +332,116 @@ class Unavailable(ServeResult):
     spans: Optional[Dict[str, float]] = None
 
 
+# ------------------------------------------------- the packed input layout
+
+
+@dataclasses.dataclass(frozen=True)
+class _Leaf:
+    """One input leaf's section of a shard's row of the staging buffer."""
+
+    offset: int                 # 32-bit words from the row's start
+    words: int                  # whole words: a narrower dtype is padded up
+    shape: Tuple[int, ...]      # the leaf as one shard of the batch sees it
+    size: int                   # its elements
+    dtype: np.dtype
+
+    def host_view(self, row: np.ndarray) -> np.ndarray:
+        """The leaf's writable view into one shard's row (host side)."""
+        return row[self.offset:self.offset + self.words].view(
+            self.dtype)[:self.size].reshape(self.shape)
+
+    def device_view(self, row):
+        """The leaf rebuilt from one shard's row inside the compiled
+        program: a static slice, a bitcast and a reshape — the bytes the
+        request carried, never a conversion."""
+        w = jax.lax.slice(row, (self.offset,), (self.offset + self.words,))
+        # bool has no bitcast: its bytes travel as uint8 (0 / 1)
+        carrier = np.dtype(np.uint8) if self.dtype == np.bool_ else self.dtype
+        if carrier.itemsize > 4:
+            w = w.reshape(self.size, carrier.itemsize // 4)
+        if carrier != np.int32:
+            w = jax.lax.bitcast_convert_type(w, carrier)
+        if carrier.itemsize < 4:
+            w = w.reshape(-1)[:self.size]
+        if carrier != self.dtype:
+            w = w.astype(self.dtype)
+        return w.reshape(self.shape)
+
+
+class PackLayout:
+    """Where every input leaf of one rung lies in the ONE staging buffer
+    a flush sends: ``int32[world x words]``, shard ``s``'s row of
+    ``words`` holding its ``rung // world`` rows of every leaf, each leaf
+    a contiguous section at a static offset. The buffer is flat so that
+    the mesh axis hands a device its row as it lies, with no reshape
+    ahead of the slices.
+
+    Leaves, in order: per categorical input the ids (``[b]`` or
+    ``[b, hot]`` int32) or, for a ragged input, the shard's CSR pair
+    (``values[b x hot]``, ``row_splits[b + 1]``); then the batch
+    tree's leaves with their trailing shapes and dtypes. 4-byte leaves
+    take their words as they are, narrower ones are padded to whole
+    words, and an 8-byte leaf (x64 mode) takes two a value. The cost of
+    a host-to-device transfer is the call, not the bytes, so one buffer
+    a flush replaces one transfer a leaf; :meth:`unpack` is the compiled
+    forward's first stage and undoes it by static slices."""
+
+    def __init__(self, input_spec: Sequence[tuple], batch_spec: Any,
+                 rung: int, world: int):
+        b = rung // world
+        self.rung, self.world = rung, world
+        off = 0
+
+        def leaf(shape, dtype) -> _Leaf:
+            nonlocal off
+            # what jnp.asarray made of the leaf: x64 off narrows 8 bytes
+            dtype = np.dtype(jax.dtypes.canonicalize_dtype(np.dtype(dtype)))
+            size = int(np.prod(shape))
+            words = (size * dtype.itemsize + 3) // 4
+            out = _Leaf(off, words, tuple(shape), size, dtype)
+            off += words
+            return out
+
+        #: per categorical input ("d", ids) or ("r", values, row_splits)
+        self.cats = [
+            ("d", leaf((b,) if hot == 1 else (b, hot), np.int32))
+            if kind == "d" else
+            ("r", leaf((b * hot,), np.int32), leaf((b + 1,), np.int32))
+            for kind, hot in input_spec]
+        flat, self.batch_tree = jax.tree_util.tree_flatten(
+            batch_spec, is_leaf=lambda x: isinstance(x, tuple)
+            and len(x) == 2 and isinstance(x[1], str))
+        self.batch = [leaf((b,) + tuple(trailing), dtype)
+                      for trailing, dtype in flat]
+        self.words = off
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the whole buffer: what the flush's one transfer
+        carries."""
+        return 4 * self.world * self.words
+
+    def staging(self) -> np.ndarray:
+        """A fresh zeroed staging buffer: all padding (id 0, zero
+        features, zero-length ragged rows) until a request is written.
+        Fresh every flush, never reused: ``jax.device_put`` returns
+        before it has read the host memory (on the v5e an overwrite
+        right after the call reached the device in 200 trials of 200)."""
+        return np.zeros((self.world * self.words,), np.int32)
+
+    def unpack(self, row):
+        """``(cat_inputs, batch)`` of one shard from its row of the
+        packed buffer (the ``[words]`` block that the mesh axis hands
+        one device)."""
+        cats = [c[1].device_view(row) if c[0] == "d" else
+                Ragged(values=c[1].device_view(row),
+                       row_splits=c[2].device_view(row))
+                for c in self.cats]
+        batch = jax.tree_util.tree_unflatten(
+            self.batch_tree, [l.device_view(row) for l in self.batch])
+        return cats, batch
+
+
 # ----------------------------------------------------------- the runtime
 
 
@@ -375,9 +495,18 @@ class ServingRuntime:
             cfg, sstate = streaming
             self._streaming_cfg = streaming_mod.resolve_config(cfg)
             self.streaming_state = sstate
-        self._eval = make_hybrid_eval_step(
-            de, pred_fn, mesh=mesh, dynamic=self._streaming_cfg,
-            donate_inputs=True)
+        self._pred_fn = pred_fn
+        self._mesh = mesh
+        # where the one packed buffer of a flush goes: split by rows over
+        # the mesh axis, as the program's P(axis) in-spec expects it (no
+        # reshard follows the transfer); the default device without a mesh
+        self._packed_sharding = (
+            None if mesh is None
+            else jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec(de.axis_name)))
+        # rung -> (its PackLayout, its compiled forward); built from the
+        # template's spec in warmup (or at the first flush of a rung)
+        self._programs: Dict[int, Tuple[PackLayout, Callable]] = {}
         # writer-side state lock (reentrant: the staleness/level helpers
         # re-acquire it from already-locked callers). In realtime mode
         # ONE runtime is driven from three threads of control — the
@@ -753,8 +882,9 @@ class ServingRuntime:
                     raise ValueError(
                         f"categorical input rank {a.ndim} unsupported")
         bspec = jax.tree.map(
+            # the dtype by name: a bfloat16 leaf's ``str`` is a bare "<V2"
             lambda a: (tuple(np.asarray(a).shape[1:]),
-                       np.asarray(a).dtype.str), batch)
+                       np.asarray(a).dtype.name), batch)
         return spec, bspec
 
     def submit(self, req: Request,
@@ -857,102 +987,96 @@ class ServingRuntime:
                 return r
         return self.rungs[-1]
 
+    def _program(self, rung: int) -> Tuple[PackLayout, Callable]:
+        """The rung's static input layout and the forward compiled
+        against it (``eval(state, packed[, stream])``: the packed buffer
+        donated, the state never)."""
+        prog = self._programs.get(rung)
+        if prog is None:
+            if self._input_spec is None:
+                raise RuntimeError(
+                    "call warmup(template) first — the input layout comes "
+                    "from the template request")
+            layout = PackLayout(self._input_spec, self._batch_spec, rung,
+                                self.world)
+            prog = (layout, make_hybrid_eval_step(
+                self.de, self._pred_fn, mesh=self._mesh,
+                dynamic=self._streaming_cfg, donate_inputs=True,
+                unpack=layout.unpack))
+            with self._state_lock:
+                prog = self._programs.setdefault(rung, prog)
+        return prog
+
     def _zero_inputs(self, rung: int):
-        """Zero-filled padded inputs of one rung (warmup / audit)."""
-        if self._input_spec is None:
-            raise RuntimeError("call warmup(template) first — the input "
-                               "layout comes from the template request")
-        return self._pack([], rung)
+        """The packed all-padding input of one rung (warmup / audit)."""
+        return self._pack([], rung)[0]
 
-    @staticmethod
-    def _h2d(buf: np.ndarray):
-        """One host-to-device transfer under its own ``serve/h2d`` span:
-        the host's time in the ``jnp.asarray`` call, not the DMA's."""
-        import jax.numpy as jnp
-
+    def _h2d(self, buf: np.ndarray):
+        """The flush's one host-to-device transfer under its ``serve/h2d``
+        span: the host's time in the call, not the DMA's."""
         with obs.span("serve/h2d", bytes=buf.nbytes):
-            return jnp.asarray(buf)
+            return jax.device_put(buf, self._packed_sharding)
 
     def _pack(self, reqs: List[Request], rung: int):
-        """Coalesce ``reqs`` (total samples <= rung) into one padded
-        rung-shaped input set. Padding samples are whole fake rows: id 0
-        everywhere, zero dense features, zero-length ragged rows —
-        their predictions are sliced off below. The numpy work runs
-        under ``serve/pack`` spans and each buffer's transfer under a
-        ``serve/h2d`` span of its own (:func:`~.obs.span`: the flush as
-        a profile shows it); a buffer is filled, then sent, so the two
-        interleave and a flush's pack + h2d spans sum to its
-        ``coalesce_ms``."""
-        spec, bspec = self._input_spec, self._batch_spec
+        """Coalesce ``reqs`` (total samples <= rung) into the rung's one
+        staging buffer (:class:`PackLayout`) and send it: every request's
+        leaves are copied into their sections of a fresh zeroed buffer
+        under one ``serve/pack`` span, then ONE transfer under one
+        ``serve/h2d`` span carries the lot, whatever the number of input
+        leaves (a flush's pack + h2d spans sum to its ``coalesce_ms``).
+        Padding samples are whole fake rows: id 0 everywhere, zero dense
+        features, zero-length ragged rows — what the zeroed buffer holds
+        where nothing is written; their predictions are sliced off
+        below. Returns the device buffer and each request's row offset."""
+        layout, _ = self._program(rung)
         with obs.span("serve/pack"):
-            offsets = []
+            buf = layout.staging()
+            rows = buf.reshape(self.world, layout.words)
+            b_local = rung // self.world
+            # request r's rows o..o+n of the global batch lie in shard
+            # o // b_local onwards: one copy a request and leaf, one more
+            # for each shard boundary the request crosses
+            offsets, pieces = [], []
             off = 0
-            for r in reqs:
+            for ri, r in enumerate(reqs):
                 offsets.append(off)
+                lo = 0
+                while lo < r.n:
+                    s, at = divmod(off + lo, b_local)
+                    k = min(r.n - lo, b_local - at)
+                    pieces.append((ri, s, slice(at, at + k),
+                                   slice(lo, lo + k)))
+                    lo += k
                 off += r.n
-        cats_out = []
-        for i, (kind, hot) in enumerate(spec):
-            if kind == "d":
-                with obs.span("serve/pack"):
-                    shape = (rung,) if hot == 1 else (rung, hot)
-                    buf = np.zeros(shape, np.int32)
-                    for r, o in zip(reqs, offsets):
-                        a = np.asarray(r.cats[i], np.int32)
-                        buf[o:o + r.n] = a if hot > 1 or a.ndim == 1 \
-                            else a.reshape(r.n)
-                cats_out.append(self._h2d(buf))
-            else:
-                # ragged: per-SHARD CSR segments concatenated, so the
-                # shard_map P(axis) split hands each rank a local
-                # (values[cap_local], row_splits[b_local+1]) pair
-                with obs.span("serve/pack"):
-                    b_local = rung // self.world
-                    cap_local = b_local * hot
-                    values = np.zeros((self.world * cap_local,), np.int32)
-                    splits = np.zeros((self.world * (b_local + 1),),
-                                      np.int32)
-                    row_lists: List[List[int]] = [[] for _ in range(rung)]
-                    for r, o in zip(reqs, offsets):
-                        for j, row in enumerate(r.cats[i]):
-                            row_lists[o + j] = row
-                    for s in range(self.world):
-                        base = s * cap_local
-                        pos = 0
-                        sbase = s * (b_local + 1)
-                        splits[sbase] = 0
-                        for j in range(b_local):
-                            row = row_lists[s * b_local + j]
-                            values[base + pos:base + pos + len(row)] = row
-                            pos += len(row)
-                            splits[sbase + j + 1] = pos
-                cats_out.append(Ragged(values=self._h2d(values),
-                                       row_splits=self._h2d(splits)))
 
-        def pack_leaf(path_spec, leaves):
-            trailing, dtype = path_spec
-            with obs.span("serve/pack"):
-                buf = np.zeros((rung,) + trailing, np.dtype(dtype))
-                for r, o, leaf in zip(reqs, offsets, leaves):
-                    buf[o:o + r.n] = np.asarray(leaf)
-            return self._h2d(buf)
+            def fill(leaf: _Leaf, per_request) -> None:
+                views = [leaf.host_view(row) for row in rows]
+                for ri, s, dst, src in pieces:
+                    a = np.asarray(per_request[ri])
+                    if a.ndim != len(leaf.shape):  # [n, 1] ids, one-hot
+                        a = a.reshape((-1,) + leaf.shape[1:])
+                    views[s][dst] = a[src]
 
-        if bspec is None or not jax.tree.leaves(bspec):
-            batch_out = bspec if bspec is None else jax.tree.map(
-                lambda s: None, bspec)
-        else:
-            with obs.span("serve/pack"):
-                req_leaves = ([jax.tree.leaves(r.batch) for r in reqs]
-                              or None)
-                flat_spec, tree = jax.tree_util.tree_flatten(
-                    self._batch_spec, is_leaf=lambda x: isinstance(x, tuple)
-                    and len(x) == 2 and isinstance(x[1], str))
-            packed = []
-            for li, s in enumerate(flat_spec):
-                leaves = ([rl[li] for rl in req_leaves]
-                          if req_leaves else [])
-                packed.append(pack_leaf(s, leaves))
-            batch_out = jax.tree_util.tree_unflatten(tree, packed)
-        return cats_out, batch_out, offsets
+            for i, c in enumerate(layout.cats):
+                if c[0] == "d":
+                    fill(c[1], [r.cats[i] for r in reqs])
+                    continue
+                # ragged: each shard's own CSR pair over its b_local rows
+                lists = [ids for r in reqs for ids in r.cats[i]]
+                for s in range(self.world):
+                    mine = lists[s * b_local:(s + 1) * b_local]
+                    if not mine:
+                        break
+                    ends = np.cumsum([len(ids) for ids in mine])
+                    c[1].host_view(rows[s])[:ends[-1]] = [
+                        v for ids in mine for v in ids]
+                    splits = c[2].host_view(rows[s])
+                    splits[1:len(ends) + 1] = ends
+                    splits[len(ends) + 1:] = ends[-1]
+            req_leaves = [jax.tree.leaves(r.batch) for r in reqs]
+            for li, leaf in enumerate(layout.batch):
+                fill(leaf, [rl[li] for rl in req_leaves])
+        return self._h2d(buf), offsets
 
     # ----------------------------------------------------------- serving
 
@@ -970,16 +1094,17 @@ class ServingRuntime:
         # thread-local-ok: warmup precedes serving — the driver/trainer
         # threads only start once the ladder is compiled
         self._input_spec, self._batch_spec = self._spec_of(cats, batch)  # thread-local-ok: warmup precedes serving
+        self._programs = {}  # thread-local-ok: warmup precedes serving
         before = obs.counters().get("recompiles", 0)
         for rung in self.rungs:
-            c, b, _ = self._pack([], rung)
+            packed = self._zero_inputs(rung)
             with warnings.catch_warnings():
                 # input donation is best-effort: a backend that cannot
-                # alias an int32 id buffer into the f32 predictions
+                # alias the int32 staging buffer into the f32 predictions
                 # warns per compile — expected here, not actionable
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not")
-                out = self._dispatch(c, b)
+                out = self._dispatch(packed, rung)
             np.asarray(out)  # block: the compile must finish inside warmup
         self.warmup_compiles = obs.counters().get("recompiles", 0) - before  # thread-local-ok: warmup precedes serving
         self._compiles_at_steady = obs.counters().get("recompiles", 0)  # thread-local-ok: warmup precedes serving
@@ -993,23 +1118,26 @@ class ServingRuntime:
             return 0
         return obs.counters().get("recompiles", 0) - self._compiles_at_steady
 
-    def _dispatch(self, cats, batch, published=None):
+    def _dispatch(self, packed, rung: int, published=None):
         state, sstate, _ = (self._published if published is None
                             else published)
+        _, forward = self._program(rung)
         if sstate is not None:
-            return self._eval(state, cats, batch, sstate)
-        return self._eval(state, cats, batch)
+            return forward(state, packed, sstate)
+        return forward(state, packed)
 
     def _run_flush(self, reqs: List[Request],
                    rung: int) -> List[Served]:
         """One flush, opened for a profile by :func:`~.obs.span`: a
         ``serve/flush`` span (args: the flush ordinal the request traces
         carry, the rung, requests and samples) whose children on this
-        thread are ``serve/pack`` + ``serve/h2d`` (:meth:`_pack`),
-        ``serve/dispatch``, ``serve/fetch`` (device compute + the copy
-        back) and ``serve/reply``. The spans record only while a
-        profiler session runs; ``Served.spans`` and :data:`STAGES` are
-        :meth:`_flush`'s own clock reads, as ever."""
+        thread are, in order, one ``serve/pack`` and one ``serve/h2d``
+        (:meth:`_pack`: every input in one buffer, sent once),
+        ``serve/dispatch`` (the call into the rung's compiled forward,
+        which unpacks the buffer on the device), ``serve/fetch`` (device
+        compute + the copy back) and ``serve/reply``. The spans record
+        only while a profiler session runs; ``Served.spans`` and
+        :data:`STAGES` are :meth:`_flush`'s own clock reads, as ever."""
         with self._state_lock:
             # the flush ordinal doubles as the coalesce-span id linking
             # the N request traces that shared this flush: minted as
@@ -1028,10 +1156,10 @@ class ServingRuntime:
         # streaming state, version stamp — observes exactly this view,
         # however the publisher interleaves (the no-torn-read contract)
         published = self._published
-        cats, batch, offsets = self._pack(reqs, rung)
+        packed, offsets = self._pack(reqs, rung)
         t_pack = self._clock()
         with obs.span("serve/dispatch"):
-            pending = self._dispatch(cats, batch, published)
+            pending = self._dispatch(packed, rung, published)
         t_disp = self._clock()
         with obs.span("serve/fetch"):
             preds = np.asarray(pending)  # device compute + host fetch
@@ -1113,7 +1241,10 @@ class ServingRuntime:
         """Run the scheduler once: expire dead requests, flush every due
         batch, update the degradation level. Returns the completed
         results (:class:`Served` / :class:`Expired`); call it often —
-        it is cheap when nothing is due."""
+        with nothing queued it returns at once, and with requests queued
+        and none due it first sleeps until one is, :data:`POLL_IDLE_S`
+        at most (never under an explicit ``now``: that caller owns the
+        time)."""
         out: List[ServeResult] = []
         explicit = now is not None
         # the seconds half of the freshness SLO can trip between
@@ -1166,6 +1297,10 @@ class ServingRuntime:
             tightest = min(r.deadline for r in self._queue)
             deadline_due = t + self._est_s >= tightest
             if not (full or timed_out or deadline_due):
+                if not explicit:
+                    due_in = min(oldest.t_submit + wait_s,
+                                 tightest - self._est_s) - t
+                    time.sleep(max(0.0, min(POLL_IDLE_S, due_in)))
                 break
             out.extend(self._flush_picked())
         self._update_level()
@@ -1314,14 +1449,14 @@ def audit_serve_program(rt: ServingRuntime, rung: Optional[int] = None,
     from ..analysis import audit as audit_mod
 
     rung = rung or rt.rungs[0]
-    cats, batch, _ = rt._zero_inputs(rung)
-    args: tuple = (rt.state, cats, batch)
+    args: tuple = (rt.state, rt._zero_inputs(rung))
     if rt.streaming_state is not None:
         args = args + (rt.streaming_state,)
     if expected is None:
         expected = audit_mod.expected_eval_collectives(rt.de)
     return audit_mod.audit_step_fn(
-        rt._eval, args, world=rt.world, dp_input=rt.de.dp_input,
+        rt._program(rung)[1], args, world=rt.world,
+        dp_input=rt.de.dp_input,
         expected=expected, expected_donated=expected_donated,
         label=f"serve_rung{rung}")
 

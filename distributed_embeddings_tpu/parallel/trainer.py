@@ -871,7 +871,8 @@ def make_hybrid_eval_step(de: DistributedEmbedding,
                           pred_fn: Callable,
                           mesh=None,
                           dynamic=None,
-                          donate_inputs: bool = False):
+                          donate_inputs: bool = False,
+                          unpack: Optional[Callable] = None):
     """Build ``eval_step(state, cat_inputs, batch) -> global predictions``.
 
     The inference analogue of :func:`make_hybrid_train_step` — the reference
@@ -899,6 +900,14 @@ def make_hybrid_eval_step(de: DistributedEmbedding,
         XLA may reuse them in place. The state (and any streaming
         state) is NEVER donated — it must survive every call. Leave off
         for interactive eval where callers re-feed the same arrays.
+      unpack: the serving runtime's packed-input mode. The step is then
+        ``eval_step(state, packed[, stream])``: ``packed`` stands where
+        ``cat_inputs, batch`` stood (``[world x words]``, one row a
+        device, split like them by the mesh axis), and the program's
+        first stage, under the scope ``serve_unpack``, is ``cat_inputs,
+        batch = unpack(packed)`` on each device's own row. One
+        host-to-device transfer then feeds a call whatever the number
+        of input leaves (:class:`.serving.PackLayout`).
     """
     from . import streaming as streaming_mod
 
@@ -907,25 +916,25 @@ def make_hybrid_eval_step(de: DistributedEmbedding,
 
     # the train step's phase names, so a serve profile attributes its
     # device time the same way (dense_forward: there is no backward)
-    if dyn_cfg is None:
-        def local_eval(state: HybridTrainState, cat_inputs, batch):
-            with obs.scope("embedding_forward"):
+    def local_eval(state: HybridTrainState, *inputs):
+        if unpack is not None:
+            with obs.scope("serve_unpack"):
+                inputs = (*unpack(inputs[0]), *inputs[1:])
+        cat_inputs, batch, *stream = inputs
+        with obs.scope("embedding_forward"):
+            if dyn_cfg is None:
                 outs = de(state.emb_params, cat_inputs)
-            with obs.scope("dense_forward"):
-                return pred_fn(state.dense_params, outs, batch)
-    else:
-        def local_eval(state: HybridTrainState, cat_inputs, batch,
-                       stream):
-            with obs.scope("embedding_forward"):
+            else:
                 outs, _ = de.forward_with_residuals(
                     state.emb_params, cat_inputs,
-                    streaming=(dyn_cfg, streaming_mod.local_state(stream),
-                               False))
-            with obs.scope("dense_forward"):
-                return pred_fn(state.dense_params, outs, batch)
+                    streaming=(dyn_cfg,
+                               streaming_mod.local_state(stream[0]), False))
+        with obs.scope("dense_forward"):
+            return pred_fn(state.dense_params, outs, batch)
 
     # inputs only: the state (and streaming state) must survive calls
-    donate = (1, 2) if donate_inputs else ()
+    n_inputs = 1 if unpack is not None else 2
+    donate = tuple(range(1, 1 + n_inputs)) if donate_inputs else ()
     if world == 1:
         return jax.jit(local_eval, donate_argnums=donate)
     if mesh is None:
@@ -934,9 +943,8 @@ def make_hybrid_eval_step(de: DistributedEmbedding,
     state_specs = HybridTrainState(
         emb_params=P(ax), emb_opt_state=P(ax),
         dense_params=P(), dense_opt_state=P(), step=P())
-    in_specs = (state_specs, P(ax), P(ax))
-    if dyn_cfg is not None:
-        in_specs = in_specs + (P(ax),)
+    in_specs = (state_specs,) + (P(ax),) * (
+        n_inputs + (dyn_cfg is not None))
     sm = jax.shard_map(
         local_eval, mesh=mesh,
         in_specs=in_specs,

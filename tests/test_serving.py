@@ -172,6 +172,140 @@ def test_multihot_and_ragged_inputs_pack():
     assert rt.stats()["ragged_clipped"] == 1
 
 
+def _per_leaf_pack(rt, reqs, rung):
+    """The reference: the padded rung-shaped arrays, one an input leaf,
+    as the runtime built (and sent) them before it packed them into one
+    buffer. Dense ids and batch leaves are global ``[rung, ...]``
+    arrays; a ragged input is its per-shard CSR segments concatenated."""
+    world = rt.world
+    offsets = np.cumsum([0] + [r.n for r in reqs])[:-1]
+    cats = []
+    for i, (kind, hot) in enumerate(rt._input_spec):
+        if kind == "d":
+            buf = np.zeros((rung,) if hot == 1 else (rung, hot), np.int32)
+            for r, o in zip(reqs, offsets):
+                buf[o:o + r.n] = np.asarray(r.cats[i], np.int32)
+            cats.append(buf)
+            continue
+        b_local = rung // world
+        values = np.zeros((world * b_local * hot,), np.int32)
+        splits = np.zeros((world * (b_local + 1),), np.int32)
+        rows = [[] for _ in range(rung)]
+        for r, o in zip(reqs, offsets):
+            rows[o:o + r.n] = r.cats[i]
+        for s in range(world):
+            pos = 0
+            for j in range(b_local):
+                row = rows[s * b_local + j]
+                at = s * b_local * hot + pos
+                values[at:at + len(row)] = row
+                pos += len(row)
+                splits[s * (b_local + 1) + j + 1] = pos
+        cats.append((values, splits))
+    batch = {}
+    for k, tmpl in reqs[0].batch.items():
+        buf = np.zeros((rung,) + tmpl.shape[1:], tmpl.dtype)
+        for r, o in zip(reqs, offsets):
+            buf[o:o + r.n] = r.batch[k]
+        batch[k] = buf
+    return cats, batch
+
+
+def _tree_pred_fn(dp, outs, batch):
+    p = sum(jnp.sum(o, -1) for o in outs)
+    for k in ("f", "h", "i"):
+        p = p + jnp.sum(batch[k].astype(jnp.float32), -1)
+    return p + batch["flag"]
+
+
+@pytest.mark.parametrize("rung", [8, 16])
+@pytest.mark.parametrize("world", [1, 8])
+def test_packed_layout_unpacks_to_the_per_leaf_arrays(world, rung):
+    """Packing N requests into the rung's one staging buffer and running
+    the program's own unpack (jitted, under the mesh where there is one)
+    gives bit for bit the arrays the per-leaf packer built, padding
+    included: one-hot, multi-hot and ragged inputs, and a batch tree of
+    float32, bfloat16 (odd count: padded to whole words), int32 and bool
+    leaves; requests straddle the shards' rows at world 8."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from distributed_embeddings_tpu.ops.embedding_lookup import Ragged
+
+    mesh = (Mesh(np.array(jax.devices()[:8]), ("data",)) if world > 1
+            else None)
+    kinds = ["one", "multi", "ragged"] * 3         # 9 inputs: >= world
+    configs = [{"input_dim": 50 + i, "output_dim": 4,
+                **({} if k == "one" else {"combiner": "sum"})}
+               for i, k in enumerate(kinds)]
+    de = DistributedEmbedding(configs, world_size=world)
+    state = init_hybrid_state(de, SparseSGD(), {"w": jnp.ones((4, 1))},
+                              optax.sgd(0.1), jax.random.key(0), mesh=mesh)
+    rt = ServingRuntime(
+        de, _tree_pred_fn, state, mesh=mesh, clock=ManualClock(),
+        config=ServeConfig(rungs=(8, 16), max_wait_ms=5, deadline_ms=1000,
+                           max_queue=64, ragged_hotness=3))
+    rng = np.random.default_rng(rung + world)
+
+    def request(n):
+        cats = []
+        for i, k in enumerate(kinds):
+            if k == "one":
+                cats.append(rng.integers(1, 50, n).astype(np.int32))
+            elif k == "multi":
+                cats.append(rng.integers(1, 50, (n, 2)).astype(np.int32))
+            else:
+                cats.append([list(rng.integers(1, 50, rng.integers(0, 4)))
+                             for _ in range(n)])
+        return Request(cats=cats, batch={
+            "f": rng.normal(size=(n, 3)).astype(np.float32),
+            "h": rng.normal(size=(n, 3)).astype(jnp.bfloat16),
+            "i": rng.integers(-9, 9, (n, 2)).astype(np.int32),
+            "flag": rng.integers(0, 2, n).astype(bool)})
+
+    tmpl = request(2)
+    rt.warmup((tmpl.cats, tmpl.batch))
+    sizes = (2, 3, 1) if rung == 8 else (3, 5, 1, 4)   # 2 and 3 rows pad
+    reqs = [rt._normalize(request(n), 0.0) for n in sizes]
+    packed, offsets = rt._pack(reqs, rung)
+    assert offsets == list(np.cumsum((0,) + sizes)[:-1])
+    layout = rt._program(rung)[0]
+    assert packed.shape == (world * layout.words,)
+    assert packed.nbytes == layout.nbytes
+    unpack = layout.unpack
+    if world > 1:
+        unpack = jax.shard_map(unpack, mesh=mesh, in_specs=P("data"),
+                               out_specs=P("data"))
+    cats, batch = jax.jit(unpack)(packed)
+    want_cats, want_batch = _per_leaf_pack(rt, reqs, rung)
+    assert len(cats) == len(want_cats) == 9
+    for got, want in zip(cats, want_cats):
+        if isinstance(want, tuple):
+            assert isinstance(got, Ragged)
+            got = (got.values, got.row_splits)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(np.asarray(g), w)
+    assert set(batch) == set(want_batch)
+    for k, w in want_batch.items():
+        g = np.asarray(batch[k])
+        assert g.dtype == w.dtype and g.shape == w.shape, k
+        assert g.tobytes() == w.tobytes(), k
+    # and the flush that carries them answers what the per-leaf program
+    # answers on the per-leaf arrays
+    for r in reqs:
+        assert rt.submit(Request(cats=r.cats, batch=r.batch),
+                         now=0.0) is None
+    served = [x for x in rt.poll(now=1.0) if isinstance(x, Served)]
+    assert [x.rung for x in served] == [rung] * len(reqs)
+    ev = make_hybrid_eval_step(de, _tree_pred_fn, mesh=mesh)
+    direct = np.asarray(ev(
+        state, [Ragged(*map(jnp.asarray, c)) if isinstance(c, tuple)
+                else jnp.asarray(c) for c in want_cats],
+        jax.tree.map(jnp.asarray, want_batch)))
+    for x, o, n in zip(served, offsets, sizes):
+        np.testing.assert_array_equal(np.asarray(x.predictions),
+                                      direct[o:o + n])
+
+
 def test_request_validation():
     de, state, rt, clock = _build()
     rt.warmup(_tmpl())
@@ -226,6 +360,29 @@ def test_flush_on_full_rung():
     res = rt.poll(now=0.0)   # 16 queued = the largest rung: no waiting
     assert sum(isinstance(r, Served) for r in res) == 4
     assert rt.stats()["rung_flushes"] == {"16": 1}
+
+
+def test_poll_yields_while_batching(monkeypatch):
+    """With requests queued and none due, poll() on its own clock sleeps
+    until one is, POLL_IDLE_S at most, so that a caller looping on it
+    does not spin through the batching delay; an explicit ``now``, an
+    empty queue or a due flush never sleeps."""
+    de, state, rt, clock = _build(max_wait_ms=5)
+    rt.warmup(_tmpl())
+    slept = []
+    monkeypatch.setattr(sv.time, "sleep", slept.append)
+    rng = np.random.default_rng(5)
+    assert rt.poll() == [] and slept == []          # nothing queued
+    rt.submit(_req(rng, n=2))
+    assert rt.poll(now=0.001) == [] and slept == []  # the caller's time
+    assert rt.poll() == []
+    assert slept == [sv.POLL_IDLE_S]
+    clock.t = 0.0049                                 # due in 0.1 ms
+    assert rt.poll() == []
+    assert slept[1] == pytest.approx(1e-4)
+    clock.t = 0.005
+    assert [type(r) for r in rt.poll()] == [Served]  # due: no sleep
+    assert len(slept) == 2
 
 
 def test_deadline_propagation_flushes_early():
@@ -728,9 +885,10 @@ def test_flush_spans_land_in_a_profiler_capture(tmp_path):
     """Under a jax.profiler capture one flush of two requests leaves one
     serve/flush span on the serving thread's line whose children are in
     the flush's own order, whose ``flush`` arg is the ordinal both
-    requests' traces carry, and which holds one serve/h2d span an input
-    leaf. With no capture running nothing of the runtime changes: the
-    tests around this one run the same code."""
+    requests' traces carry, and which holds ONE serve/h2d span, after
+    the pack and before the dispatch, carrying the whole packed buffer.
+    With no capture running nothing of the runtime changes: the tests
+    around this one run the same code."""
     from distributed_embeddings_tpu.utils import reqtrace
 
     de, state, rt, clock = _build_ticking()
@@ -764,18 +922,41 @@ def test_flush_spans_land_in_a_profiler_capture(tmp_path):
               and s[1] >= flush[1] and s[2] <= flush[2]]
     assert len(inside) == len(spans) - len(submits) - 1
     names = [s[0] for s in inside]
-    n_leaves = len(jax.tree.leaves(_tmpl()))
-    assert names.count("serve/h2d") == n_leaves == 3
-    # a buffer is filled, then sent; then the call, the wait, the reply
-    assert names[-3:] == ["serve/dispatch", "serve/fetch", "serve/reply"]
-    head = names[:-3]
-    assert set(head) == {"serve/pack", "serve/h2d"}
-    assert all(head[i - 1] == "serve/pack"
-               for i, n in enumerate(head) if n == "serve/h2d")
+    # everything is packed, then sent once; the call, the wait, the reply
+    assert names[-4:] == ["serve/h2d", "serve/dispatch", "serve/fetch",
+                          "serve/reply"]
+    assert names[:-4] and set(names[:-4]) == {"serve/pack"}
     for a, b in zip(inside, inside[1:]):
         assert a[2] <= b[1]            # siblings: none overlaps the next
-    assert all(int(s[3]["bytes"]) > 0 for s in inside
-               if s[0] == "serve/h2d")
+    # rung 8 of two one-hot inputs and three float32 features: 8 x 5 words
+    layout = rt._program(8)[0]
+    assert int(inside[-4][3]["bytes"]) == layout.nbytes == 8 * 5 * 4
+
+
+@pytest.mark.parametrize("n_inputs", [2, 7])
+def test_one_transfer_a_flush_whatever_the_leaves(tmp_path, n_inputs):
+    """A served flush is one host-to-device transfer: 3 input leaves or
+    8 (the categorical inputs and the numerical block), the capture
+    holds one serve/h2d span inside the one serve/flush span."""
+    de, state, rt, clock = _build_ticking(
+        configs=[{"input_dim": 40 + i, "output_dim": 4}
+                 for i in range(n_inputs)])
+    rt.warmup(_tmpl(n_inputs=n_inputs))
+    assert len(jax.tree.leaves(_tmpl(n_inputs=n_inputs))) == n_inputs + 1
+    rng = np.random.default_rng(n_inputs)
+    sizes = [40 + i for i in range(n_inputs)]
+    with jax.profiler.trace(str(tmp_path)):
+        for n in (3, 1, 2):
+            assert rt.submit(_req(rng, sizes, n=n)) is None
+        clock.t += 0.007
+        served = rt.poll()
+    assert [type(r) for r in served] == [Served] * 3
+    (spans,) = _host_spans(str(tmp_path)).values()
+    names = [s[0] for s in spans]
+    assert names.count("serve/flush") == 1
+    assert names.count("serve/h2d") == 1
+    (h2d,) = [s for s in spans if s[0] == "serve/h2d"]
+    assert int(h2d[3]["bytes"]) == 8 * (n_inputs + 3) * 4
 
 
 def test_stats_sketch_percentiles_match_numpy_reference():
