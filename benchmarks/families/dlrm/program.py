@@ -1,6 +1,8 @@
-"""The system under test, built from a configuration file. The only module of
-the benchmark that imports ``distributed_embeddings_tpu``; it takes from the
-program its entry points and nothing that decides a metric or ``correct``.
+"""The system under test, built from a configuration file of family ``dlrm``.
+The family's adapter half: with ``benchmarks/families/system.py`` the only
+code of the benchmark that imports ``distributed_embeddings_tpu``; it takes
+from the program its entry points and nothing that decides a metric or
+``correct``.
 """
 
 from __future__ import annotations
@@ -23,27 +25,8 @@ from distributed_embeddings_tpu.parallel import (
     DistributedEmbedding, ServeConfig, ServingRuntime, SparseSGD,
     init_hybrid_state, make_hybrid_eval_step, make_hybrid_train_step)
 from distributed_embeddings_tpu.parallel import dist_embedding
-from distributed_embeddings_tpu.parallel import serving as serving_mod
-from distributed_embeddings_tpu.utils import obs, runtime
 
 from . import weights
-
-compile_count = lambda: obs.counters().get("recompiles", 0)  # noqa: E731
-
-
-def ensure_compile_cache() -> str:
-    """The persistent compile cache at the program's fixed path inside the
-    checkout (or where ``JAX_COMPILATION_CACHE_DIR`` says), holding every
-    program of a run, the small ones too."""
-    path = runtime.ensure_compile_cache()
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    return path
-
-
-install_compile_listener = obs.install_compile_listener
-Request = serving_mod.Request
-Served = serving_mod.Served
 
 
 class _SeedFreeInit:
@@ -298,8 +281,3 @@ def exchange_left_out():
         yield
     finally:
         exchange.lax = real
-
-
-def is_refused(result) -> bool:
-    """A result the system refused or lost: anything but ``Served``."""
-    return not isinstance(result, serving_mod.Served)

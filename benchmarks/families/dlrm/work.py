@@ -1,7 +1,13 @@
 """Operations and bytes that each kernel's work needs, from the cell's shapes
-alone, so that a roofline share reads the same work whatever implements it."""
+alone, so that a roofline share reads the same work whatever implements it.
+``WORK`` and ``FLOPS`` are what the readers ``roofline`` and ``mfu`` find by
+the name in a metric's file."""
 
 from __future__ import annotations
+
+import numpy as np
+
+from .traffic import live_ids
 
 _BYTES = {"bfloat16": 2, "float32": 4}
 
@@ -40,10 +46,29 @@ def apply_bytes(config: dict, ids: float, distinct_rows: float) -> float:
                   + ids * _BYTES[config["compute_dtype"]])
 
 
-def roofline_share(flops: float, nbytes: float, seconds: float,
-                   peaks: dict) -> float:
-    """The least time the chip could take for the work over the time it took,
-    in percent."""
-    least = max(flops / peaks["flops_per_s"],
-                nbytes / peaks["hbm_bytes_per_s"])
-    return 100.0 * least / seconds
+def step_work(config: dict, traffic: dict, batches) -> dict:
+    """A step's work for the rooflines, from the staged batches alone."""
+    live = [live_ids(b) for b in batches]
+    return {
+        "ids_per_step": float(np.mean([sum(len(i) for i in b)
+                                       for b in live])),
+        "distinct_rows_per_step": float(np.mean(
+            [sum(len(np.unique(i)) for i in b) for b in live])),
+        "outputs_per_step": float(len(config["table_sizes"])
+                                  * int(traffic["global_batch"]))}
+
+
+# name in a metric's file -> (config, work, ctx) -> (FLOP, HBM bytes) a step
+WORK = {
+    "lookup": lambda cfg, w, ctx: (0.0, lookup_bytes(
+        cfg, w["ids_per_step"], w["outputs_per_step"])),
+    "apply": lambda cfg, w, ctx: (0.0, apply_bytes(
+        cfg, w["ids_per_step"], w["distinct_rows_per_step"])),
+    "dense_train": lambda cfg, w, ctx: (
+        dense_train_flops_per_sample(cfg) * ctx["samples"] / ctx["steps"],
+        0.0),
+}
+
+# name in a metric's file -> (config) -> the model's FLOP a sample
+FLOPS = {"dense_train": dense_train_flops_per_sample,
+         "dense_forward": dense_forward_flops_per_sample}

@@ -1,2 +1,5 @@
-"""The benchmark's yardstick: generators, plain reference, work counts, peaks and
-the trace reduction. Only ``program.py`` imports the system under test."""
+"""The benchmark's yardstick as far as it knows no model: the manifest, the
+runner and its windows, the general traffic draws, the comparison's measures
+and verdict, peaks and the trace reduction. What knows a model is a family
+(``benchmarks/families/``), and only code there imports the system under
+test."""

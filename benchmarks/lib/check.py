@@ -1,19 +1,14 @@
 """The comparison that decides ``correct``: what the timed path produced
-against the plain reference, each number beside its limit.
-
-Training numbers (``How correct is decided``, training): the loss of each of
-the first three steps, the first gradient as the optimizer got it (worked out
-from the parameters after one step: ``(p0 - p1) / lr``) and the parameters'
-change after the three, the last two by the worst leaf as a gap of norms.
-A leaf is one MLP kernel or bias, or one embedding table. One more number
-reads the first gradient over the rows that only one half of the first batch
-touches.
+against the plain reference, each number beside its limit. Which numbers a
+cell compares is its family's to say (``train_numbers``, ``serve_numbers``);
+the two measures every family takes them by, the verdict and the printing are
+here.
 """
 
 from __future__ import annotations
 
 import sys
-from typing import Dict, List, Sequence
+from typing import Dict, Sequence
 
 import numpy as np
 
@@ -34,34 +29,6 @@ def worst_leaf_gap(got: Sequence[float], want: Sequence[float],
             continue
         worst = max(worst, abs(g - w) / max(w, med, 1e-30))
     return worst
-
-
-def train_numbers(prog: dict, ref: dict) -> Dict[str, float]:
-    """``prog`` and ``ref`` hold ``losses`` [3], and per group (``dense``,
-    ``tables``) the leaf norms ``grad1_<group>`` and ``delta3_<group>``."""
-    out = {f"loss{k + 1}": rel_gap(prog["losses"][k], ref["losses"][k])
-           for k in range(3)}
-    # the median leaf is its group's (MLP leaves, tables): the two groups have
-    # learning rates of their own, so their changes are not of one scale
-    for group in ("dense", "tables"):
-        g = f"grad1_{group}"
-        d = f"delta3_{group}"
-        # a leaf whose gradient is nought to rounding in the reference moves
-        # by round-off alone: left out of the change by the reference's
-        # gradient
-        tiny = 1e-3 * float(np.median(ref[g]))
-        out[g] = worst_leaf_gap(prog[g], ref[g], ref[g])
-        out[d] = worst_leaf_gap(prog[d], ref[d], ref[d],
-                                skip=[x < tiny for x in ref[g]])
-    # rows that one half of the first batch touches alone (two leaves: the
-    # first half's, the second half's, each over all tables): where half of a
-    # batch is left out they stay put or move double, which a whole table's
-    # norm hides behind its hot rows
-    if "grad1_half_rows" in ref:
-        out["grad1_half_rows"] = worst_leaf_gap(
-            prog["grad1_half_rows"], ref["grad1_half_rows"],
-            ref["grad1_half_rows"])
-    return out
 
 
 def verdict(numbers: Dict[str, float], limits: Dict[str, float]):

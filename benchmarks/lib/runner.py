@@ -10,11 +10,11 @@ import sys
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from . import (check, manifest, peaks, program, serve, tracered, traffic,
-               train, work)
+from benchmarks.families import system
+
+from . import check, manifest, peaks, serve, tracered, train
 
 COMPARE_REQUESTS = 300   # served requests whose answers are compared a run
 
@@ -94,13 +94,13 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     ``hooks["step"]`` wraps the compiled step, ``hooks["results"]`` alters the
     served results where they are produced."""
     hooks = hooks or {}
-    program.install_compile_listener()
+    system.install_compile_listener()
     if trace:
         seconds = min(seconds, float(cell.own["trace_seconds"]))
     capture = tracered.Capture(os.path.join(cell.root, ".bench_trace")) \
         if trace else None
     ctx = {"cell": cell.name, "config": cell.config, "traffic": cell.traffic,
-           "chips": cell.chips,
+           "chips": cell.chips, "family": cell.family,
            "peaks": None if rehearse else peaks.of(device["kind"])}
     kind = cell.traffic["kind"]
     if kind == "train":
@@ -127,47 +127,37 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
 
 
 def _train(cell, seed, seconds, capture, ctx, t_start, hooks):
-    cfg, tr = cell.config, cell.traffic
+    fam, cfg, tr = ctx["family"], cell.config, cell.traffic
     laps = _Laps(t_start)
     laps.lap("imports and backend")
-    one_hot = tr["hotness"]["kind"] == "one"
-    built = program.build(cfg, seed, combiner=None if one_hot else "sum",
-                          dense_lr=float(tr["dense_lr"]))
+    built = fam.build(cfg, tr, seed)
     jax.block_until_ready(built.state)
     laps.lap("weights")
-    batches = traffic.train_batches(tr, cfg["table_sizes"],
-                                    int(cfg["num_numerical"]), seed)
-    staged = jax.block_until_ready([program.stage(built, b) for b in batches])
+    batches = fam.train_batches(cfg, tr, seed)
+    staged = jax.block_until_ready([fam.stage(built, b) for b in batches])
     laps.lap("batches")
-    step = program.train_step(built, float(tr["emb_lr"]),
-                              float(tr["dense_lr"]))
+    step = fam.train_step(built, tr)
     if "step" in hooks:
         step = hooks["step"](step)
-    prog, state = train.first_steps(built, tr, step, staged, batches, seed)
+    prog, state = fam.first_steps(built, tr, step, staged, batches, seed)
     laps.lap("first three steps and their read-outs")
     laps.say()
-    compiles0 = program.compile_count()
+    per_step = int(fam.samples_per_step(cfg, tr))
+    compiles0 = system.compile_count()
     e2e = {"setup_s": time.perf_counter() - t_start}
     with _HostWatch() as host, capture or contextlib.nullcontext():
         sps, n, dt, state, losses, stalls = train.window(
-            step, state, staged, int(tr["global_batch"]), seconds,
-            train.CHECK_STEPS, span=capture.span if capture else None)
+            step, state, staged, per_step, seconds, train.CHECK_STEPS,
+            span=capture.span if capture else None)
     e2e["samples_per_s"] = sps
     ctx["memory_peak_bytes"] = _memory_peak(jax.devices()[:cell.chips])
-    ctx.update(window_s=dt, steps=n, samples=n * int(tr["global_batch"]),
+    ctx.update(window_s=dt, steps=n, samples=n * per_step,
                counters={"compiles_in_window":
-                         program.compile_count() - compiles0})
+                         system.compile_count() - compiles0})
     if capture:
-        live = [train.live_ids(b) for b in batches]
-        ctx["work"] = {
-            "ids_per_step": float(np.mean([sum(len(i) for i in b)
-                                           for b in live])),
-            "distinct_rows_per_step": float(np.mean(
-                [sum(len(np.unique(i)) for i in b) for b in live])),
-            "outputs_per_step": float(len(cfg["table_sizes"])
-                                      * int(tr["global_batch"]))}
+        ctx["work"] = fam.step_work(cfg, tr, batches)
     del state, staged
-    ref = train.reference_numbers(cfg, tr, batches, seed)
+    ref = fam.reference_numbers(cfg, tr, batches, seed)
     bad = int(np.sum(~np.isfinite(losses)))
     print(f"window: {n} steps in {dt:.3f} s; the host's longest wait in a "
           + ", ".join(f"{k} {v * 1e3:.0f} ms" for k, v in stalls.items())
@@ -175,7 +165,7 @@ def _train(cell, seed, seconds, capture, ctx, t_start, hooks):
     print(f"window losses: first {losses[0]:.6g} last {losses[-1]:.6g} "
           f"not finite {bad} of {n}; reference's first three "
           f"{ref['losses']}", file=sys.stderr)
-    return e2e, check.train_numbers(prog, ref), n, bad
+    return e2e, fam.train_numbers(prog, ref), n, bad
 
 
 def _say_serve_window(schedule, results, t_sub, t_last, lat, host) -> None:
@@ -203,16 +193,15 @@ def _say_serve_window(schedule, results, t_sub, t_last, lat, host) -> None:
 
 
 def _serve(cell, seed, seconds, capture, ctx, t_start, hooks):
-    cfg, tr = cell.config, cell.traffic
+    fam, cfg, tr = ctx["family"], cell.config, cell.traffic
     laps = _Laps(t_start)
     laps.lap("imports and backend")
-    built = program.build(cfg, seed)
+    built = fam.build(cfg, tr, seed)
     jax.block_until_ready(built.state)
     laps.lap("weights")
-    rt = program.serving_runtime(built, tr["serve"])
-    schedule = traffic.serve_schedule(tr, cfg["table_sizes"],
-                                      int(cfg["num_numerical"]), seed, seconds)
-    requests = serve.requests_of(schedule)
+    rt = fam.serving_runtime(built, tr["serve"])
+    schedule = fam.serve_schedule(cfg, tr, seed, seconds)
+    requests = fam.requests_of(schedule)
     laps.lap("requests")
     rt.warmup(schedule.request(0))
     laps.lap("rungs")
@@ -261,8 +250,9 @@ def _serve(cell, seed, seconds, capture, ctx, t_start, hooks):
     del rt
     built.state = None
     picked = serve.sample_to_compare(seed, results, sizes, COMPARE_REQUESTS)
-    numbers = serve.compare(schedule, results, picked,
-                            serve.reference_logits(cfg, schedule, picked, seed))
+    numbers = fam.serve_numbers(
+        schedule, results, picked,
+        fam.reference_answers(cfg, schedule, picked, seed))
     numbers["unanswered"] = float(len(schedule) - len(results))
     n_failed = len(schedule) - len(served)
     return e2e, numbers, len(schedule), n_failed

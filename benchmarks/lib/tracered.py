@@ -196,10 +196,16 @@ def breakdown(trace: Trace, top: int = 10) -> Dict[str, list]:
     gaps = [(a[1], b[0]) for a, b in zip(dev0, dev0[1:]) if b[0] > a[1]]
     spans = sorted((s, s + d, n) for n, s, d in trace.spans)
     by_span: Dict[str, float] = {}
+    first = 0    # spans before it end before this gap, and so before the next
     for gs, ge in gaps:
+        while first < len(spans) and spans[first][1] <= gs:
+            first += 1
         covered = 0.0
-        for ss, se, name in spans:
-            if se <= gs or ss >= ge:
+        k = first
+        while k < len(spans) and spans[k][0] < ge:
+            ss, se, name = spans[k]
+            k += 1
+            if se <= gs:    # a short span behind a long one that goes on
                 continue
             part = min(ge, se) - max(gs, ss)
             by_span[name] = by_span.get(name, 0.0) + part

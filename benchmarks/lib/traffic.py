@@ -1,5 +1,6 @@
-"""The one general traffic generator: a traffic file's parameters and a seed
-give the staged training batches or the serving requests of a run.
+"""The one general traffic generator, as far as it knows no model: the draws
+(ids by a power law, multi-hot row lengths) and the arrival process that a
+family's batch and request makers are built from.
 
 Every seed gets the same multiset of request sizes, arrival gaps and
 multi-hot row lengths in another order, so that the seed changes which ids
@@ -7,9 +8,6 @@ are drawn and not how much work a run holds.
 """
 
 from __future__ import annotations
-
-import dataclasses
-from typing import List, Optional
 
 import numpy as np
 
@@ -25,18 +23,8 @@ def power_law_ids(rng: np.random.Generator, vocab: int, shape,
     return np.clip(ids.astype(np.int64), 0, vocab - 1).astype(np.int32)
 
 
-def _rng(seed: int, stream: int) -> np.random.Generator:
+def rng_of(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([int(seed), stream])
-
-
-@dataclasses.dataclass
-class TrainBatch:
-    """One global batch on the host. ``splits`` is None for one-hot ids;
-    otherwise ``ids[t]`` is ``[capacity]`` and ``splits[t]`` ``[batch+1]``."""
-    ids: List[np.ndarray]
-    splits: Optional[List[np.ndarray]]
-    numerical: np.ndarray
-    labels: np.ndarray
 
 
 def row_lengths(rng, batch: int, lo: int, hi: int, capacity: int) -> np.ndarray:
@@ -52,56 +40,6 @@ def row_lengths(rng, batch: int, lo: int, hi: int, capacity: int) -> np.ndarray:
         over -= cut
         i -= 1
     return hot
-
-
-def train_batches(traffic: dict, table_sizes, num_numerical: int,
-                  seed: int) -> List[TrainBatch]:
-    """``distinct_batches`` global batches drawn from the seed."""
-    rng = _rng(seed, 1)
-    b = int(traffic["global_batch"])
-    alpha = float(traffic["id_alpha"])
-    hot = traffic["hotness"]
-    out = []
-    for _ in range(int(traffic["distinct_batches"])):
-        if hot["kind"] == "one":
-            ids = [power_law_ids(rng, s, (b,), alpha) for s in table_sizes]
-            splits = None
-        elif hot["kind"] == "uniform":
-            cap = int(hot["capacity"])
-            ids, splits = [], []
-            for s in table_sizes:
-                lens = row_lengths(rng, b, int(hot["min"]), int(hot["max"]),
-                                   cap)
-                sp = np.zeros(b + 1, np.int32)
-                np.cumsum(lens, out=sp[1:])
-                v = np.zeros(cap, np.int32)
-                v[:sp[-1]] = power_law_ids(rng, s, (int(sp[-1]),), alpha)
-                ids.append(v)
-                splits.append(sp)
-        else:
-            raise ValueError(f"unknown hotness kind {hot['kind']!r}")
-        out.append(TrainBatch(
-            ids=ids, splits=splits,
-            numerical=rng.normal(size=(b, num_numerical)).astype(np.float32),
-            labels=rng.integers(0, 2, size=(b, 1)).astype(np.float32)))
-    return out
-
-
-@dataclasses.dataclass
-class ServeSchedule:
-    """Every request of a window: request ``i`` is due ``due_s[i]`` seconds
-    after the window opens and holds samples ``offsets[i]:offsets[i+1]``."""
-    due_s: np.ndarray
-    offsets: np.ndarray
-    ids: List[np.ndarray]
-    numerical: np.ndarray
-
-    def __len__(self):
-        return len(self.due_s)
-
-    def request(self, i: int):
-        a, b = int(self.offsets[i]), int(self.offsets[i + 1])
-        return [c[a:b] for c in self.ids], self.numerical[a:b]
 
 
 def _quantile_grid(n: int) -> np.ndarray:
@@ -136,11 +74,12 @@ def _burst_warp(due: np.ndarray, every: float, factor: float) -> np.ndarray:
                                      1.0 + (work - factor) / slow)
 
 
-def serve_schedule(traffic: dict, table_sizes, num_numerical: int, seed: int,
-                   seconds: float) -> ServeSchedule:
+def arrivals(traffic: dict, rng: np.random.Generator, seconds: float):
     """Open-loop Poisson arrivals at the traffic file's fixed rate for
-    ``seconds`` seconds, each request one ranking query."""
-    rng = _rng(seed, 2)
+    ``seconds`` seconds: ``(due_s [n], offsets [n+1])``, request ``i`` due
+    ``due_s[i]`` seconds after the window opens and holding samples
+    ``offsets[i]:offsets[i+1]``. A family goes on drawing each request's
+    content from the same ``rng``."""
     rate = float(traffic["rate_per_s"])
     n = max(1, int(round(rate * seconds)))
     sizes = rng.permutation(request_sizes(traffic, n))
@@ -152,10 +91,4 @@ def serve_schedule(traffic: dict, table_sizes, num_numerical: int, seed: int,
                           float(burst["factor"]))
     offsets = np.zeros(n + 1, np.int64)
     np.cumsum(sizes, out=offsets[1:])
-    total = int(offsets[-1])
-    alpha = float(traffic["id_alpha"])
-    return ServeSchedule(
-        due_s=due, offsets=offsets,
-        ids=[power_law_ids(rng, s, (total,), alpha) for s in table_sizes],
-        numerical=rng.standard_normal(size=(total, num_numerical),
-                                      dtype=np.float32))
+    return due, offsets

@@ -1,6 +1,7 @@
 """A kernel's share of its roofline: the least time the chip could take for
-the work its scopes did in a step, over the device time they took."""
-from benchmarks.lib import tracered, work
+the work its scopes did in a step, over the device time they took. The work
+is counted by the cell's family: ``WORK[<the metric file's "work">]``."""
+from benchmarks.lib import peaks, tracered
 
 
 def read(ctx, spec):
@@ -12,19 +13,11 @@ def read(ctx, spec):
                                      spec.get("exclude", ())) / steps
     if seconds <= 0.0:
         return None
-    cfg, chips = ctx["config"], ctx["chips"]
-    flops = nbytes = 0.0
-    if spec["work"] == "lookup":
-        nbytes = work.lookup_bytes(cfg, w["ids_per_step"],
-                                   w["outputs_per_step"])
-    elif spec["work"] == "apply":
-        nbytes = work.apply_bytes(cfg, w["ids_per_step"],
-                                  w["distinct_rows_per_step"])
-    elif spec["work"] == "dense_train":
-        flops = work.dense_train_flops_per_sample(cfg) \
-            * ctx["samples"] / ctx["steps"]
-    else:
+    count = ctx["family"].WORK.get(spec["work"])
+    if count is None:
         raise SystemExit(f"no work function {spec['work']!r}")
+    flops, nbytes = count(ctx["config"], w, ctx)
+    chips = ctx["chips"]
     # the step's work is spread over the chips; the time is a device's own
-    return work.roofline_share(flops / chips, nbytes / chips, seconds,
-                               ctx["peaks"])
+    return peaks.roofline_share(flops / chips, nbytes / chips, seconds,
+                                ctx["peaks"])

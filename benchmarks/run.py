@@ -40,12 +40,12 @@ def main(argv=None) -> int:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={cell.chips}")
     try:
-        from benchmarks.lib import program
+        from benchmarks.families import system
     except ImportError as e:
         print(f"the system under test is not in this checkout: {e}",
               file=sys.stderr)
         return 3
-    program.ensure_compile_cache()
+    system.ensure_compile_cache()
     import jax
     if args.rehearse_cpu:
         jax.config.update("jax_platforms", "cpu")

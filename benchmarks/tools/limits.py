@@ -1,7 +1,8 @@
 """Read, on the chip and in one process, the numbers a cell's limits are set
 from: the program against the reference on many seeds (the lower readings), and
-on a few of them the control (the reference in float8 in the program's place)
-and the planted faults (the upper readings).
+on a few of them the control (the reference in the precision below, in the
+program's place) and the planted faults (the upper readings). Whatever knows
+the model is asked of the cell's family.
 
     python benchmarks/tools/limits.py --workload <cell> --seeds 12 --controls 3
 
@@ -13,6 +14,7 @@ import json
 import os
 import sys
 import time
+import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -39,12 +41,12 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={cell.chips}")
-    from benchmarks.lib import program
-    program.ensure_compile_cache()
-    from benchmarks.lib import check, runner, serve, traffic, train
+    from benchmarks.families import system
+    system.ensure_compile_cache()
     import numpy as np
+    from benchmarks.lib import runner, serve, train
 
-    cfg, tr = cell.config, cell.traffic
+    fam, cfg, tr = cell.family, cell.config, cell.traffic
     seeds = [int(x) for x in args.seed_list.split(",") if x] \
         + [args.first_seed + 7919 * k for k in range(args.seeds)]
 
@@ -54,60 +56,62 @@ def main(argv=None) -> int:
     def program_numbers(seed, batches):
         """The compiled step's first three steps, as a run's set-up reads
         them."""
-        one_hot = tr["hotness"]["kind"] == "one"
-        built = program.build(cfg, seed, combiner=None if one_hot else "sum",
-                              dense_lr=float(tr["dense_lr"]))
-        staged = [program.stage(built, b) for b in batches]
-        step = program.train_step(built, float(tr["emb_lr"]),
-                                  float(tr["dense_lr"]))
-        return train.first_steps(built, tr, step, staged, batches, seed)[0]
+        built = fam.build(cfg, tr, seed)
+        staged = [fam.stage(built, b) for b in batches]
+        step = fam.train_step(built, tr)
+        return fam.first_steps(built, tr, step, staged, batches, seed)[0]
 
     for k, seed in enumerate(seeds):
         t0 = time.perf_counter()
         if tr["kind"] == "train":
-            batches = traffic.train_batches(
-                dict(tr, distinct_batches=train.CHECK_STEPS),
-                cfg["table_sizes"], int(cfg["num_numerical"]), seed)
+            batches = fam.train_batches(
+                cfg, dict(tr, distinct_batches=train.CHECK_STEPS), seed)
             prog = program_numbers(seed, batches)
-            ref = train.reference_numbers(cfg, tr, batches, seed)
-            say(seed=seed, who="program", numbers=check.train_numbers(prog, ref),
+            ref = fam.reference_numbers(cfg, tr, batches, seed)
+            say(seed=seed, who="program", numbers=fam.train_numbers(prog, ref),
                 seconds=time.perf_counter() - t0)
             if k >= args.controls:
                 continue
             if cell.chips > 1:
-                with program.exchange_left_out():
+                with fam.exchange_left_out():
                     bad = program_numbers(seed, batches)
                 say(seed=seed, who="fault_no_exchange",
-                    numbers=check.train_numbers(bad, ref))
-            for who, kw in (("control_float8", {"precision": "float8"}),
-                            ("fault_half_batch", {"fault": "half_batch"})):
-                low = train.reference_numbers(cfg, tr, batches, seed, **kw)
-                say(seed=seed, who=who, numbers=check.train_numbers(low, ref))
+                    numbers=fam.train_numbers(bad, ref))
+            for who, kw in [("control_" + fam.CONTROL_PRECISION,
+                             {"precision": fam.CONTROL_PRECISION})] + [
+                    ("fault_" + f, {"fault": f}) for f in fam.REFERENCE_FAULTS]:
+                low = fam.reference_numbers(cfg, tr, batches, seed, **kw)
+                say(seed=seed, who=who, numbers=fam.train_numbers(low, ref))
         else:
-            built = program.build(cfg, seed)
-            rt = program.serving_runtime(built, tr["serve"])
-            schedule = traffic.serve_schedule(
-                tr, cfg["table_sizes"], int(cfg["num_numerical"]), seed,
-                args.seconds)
+            built = fam.build(cfg, tr, seed)
+            rt = fam.serving_runtime(built, tr["serve"])
+            schedule = fam.serve_schedule(cfg, tr, seed, args.seconds)
             rt.warmup(schedule.request(0))
             results, _, _ = serve.open_loop(rt, schedule,
-                                            serve.requests_of(schedule))
+                                            fam.requests_of(schedule))
             rt.state = None
             del rt, built
-            picked = serve.sample_to_compare(
-                seed, results, np.diff(schedule.offsets),
-                runner.COMPARE_REQUESTS)
-            want = serve.reference_logits(cfg, schedule, picked, seed)
-            nums = serve.compare(schedule, results, picked, want)
+            sizes = np.diff(schedule.offsets)
+            picked = serve.sample_to_compare(seed, results, sizes,
+                                             runner.COMPARE_REQUESTS)
+            want = fam.reference_answers(cfg, schedule, picked, seed)
+            nums = fam.serve_numbers(schedule, results, picked, want)
             nums["failed"] = float(sum(serve.failed(r)
                                        for r in results.values()))
             say(seed=seed, who="program", numbers=nums,
                 seconds=time.perf_counter() - t0)
             if k < args.controls:
-                low = serve.reference_logits(cfg, schedule, picked, seed,
-                                             precision="float8")
-                say(seed=seed, who="control_float8", numbers={
-                    "logit_gap": float(np.abs(low - want).max())})
+                # the reference in the lower precision, put in the program's
+                # place: its answers as the picked requests' results
+                low = fam.reference_answers(cfg, schedule, picked, seed,
+                                            precision=fam.CONTROL_PRECISION)
+                cuts = np.cumsum([0] + [int(sizes[i]) for i in picked])
+                stand_in = {i: types.SimpleNamespace(
+                    predictions=low[cuts[j]:cuts[j + 1]])
+                    for j, i in enumerate(picked)}
+                say(seed=seed, who="control_" + fam.CONTROL_PRECISION,
+                    numbers=fam.serve_numbers(schedule, stand_in, picked,
+                                              want))
     return 0
 
 

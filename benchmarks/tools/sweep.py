@@ -26,30 +26,29 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=2_100_000_000)
     args = ap.parse_args(argv)
 
-    from benchmarks.lib import manifest, program
+    from benchmarks.families import system
+    from benchmarks.lib import manifest
     cell = manifest.Cell(args.workload)
-    program.ensure_compile_cache()
+    system.ensure_compile_cache()
     import numpy as np
     from benchmarks.lib import serve, traffic
 
-    cfg, tr = cell.config, cell.traffic
-    built = program.build(cfg, args.seed)
+    fam, cfg, tr = cell.family, cell.config, cell.traffic
+    built = fam.build(cfg, tr, args.seed)
     rates = [float(r) for r in args.rates.split(",")]
     mean_size = float(np.mean(traffic.request_sizes(tr, 1000)))
     # admission as the cell sets it, sized for the highest rate swept
     params = dict(tr["serve"], max_queue=max(int(max(rates) * mean_size),
                                              int(tr["serve"]["rungs"][-1])))
-    rt = program.serving_runtime(built, params)
+    rt = fam.serving_runtime(built, params)
     for k, rate in enumerate(rates):
-        schedule = traffic.serve_schedule(dict(tr, rate_per_s=rate),
-                                          cfg["table_sizes"],
-                                          int(cfg["num_numerical"]),
-                                          args.seed + k, args.seconds)
+        schedule = fam.serve_schedule(cfg, dict(tr, rate_per_s=rate),
+                                      args.seed + k, args.seconds)
         if k == 0:
             rt.warmup(schedule.request(0))
         before = rt.stats()["flushes"]
         results, t_sub, t_last = serve.open_loop(rt, schedule,
-                                                 serve.requests_of(schedule))
+                                                 fam.requests_of(schedule))
         lat = serve.latencies_ms(schedule.due_s, t_sub, results)
         q = len(lat) // 4
         sizes = np.diff(schedule.offsets)

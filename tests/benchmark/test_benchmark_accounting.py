@@ -3,8 +3,11 @@ against a fake runtime with one stall."""
 
 import numpy as np
 
-from benchmarks.lib import program, serve, traffic
+from benchmarks.families import system
+from benchmarks.lib import manifest, serve
 from distributed_embeddings_tpu.parallel import serving as sv
+
+traffic = manifest.load_family("dlrm").traffic
 
 
 def test_a_late_answer_is_not_failed_and_a_refusal_is():
@@ -64,7 +67,7 @@ def test_p95_counts_the_wait_a_stall_imposes_on_later_requests():
     sched = traffic.ServeSchedule(
         due_s=np.arange(n) * 0.010, offsets=np.arange(n + 1),
         ids=[np.zeros(n, np.int32)], numerical=np.zeros((n, 1), np.float32))
-    reqs = [program.Request(cats=c, batch=b)
+    reqs = [system.Request(cats=c, batch=b)
             for c, b in (sched.request(i) for i in range(n))]
     clock = _Clock()
     rt = _FakeRuntime(clock, stall_at=3, stall_s=0.200)

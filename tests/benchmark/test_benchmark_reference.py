@@ -7,14 +7,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmarks.lib import (check, manifest, program, runner, traffic, train,
-                            weights)
+from benchmarks.lib import check, manifest, runner
 
 import benchmark_tiny
 
 DEVICE = {"platform": "cpu", "kind": "cpu", "count": 8}
-LIMITS = {k: v for k, v in benchmark_tiny.TINY_LIMITS.items()
-          if k.startswith(("loss", "grad1", "delta3"))}
 
 
 @pytest.fixture(scope="module")
@@ -23,13 +20,12 @@ def root(tmp_path_factory):
 
 
 def _first_steps(cell, seed):
-    cfg, tr = cell.config, cell.traffic
-    built = program.build(cfg, seed, dense_lr=float(tr["dense_lr"]))
-    batches = traffic.train_batches(tr, cfg["table_sizes"], 13, seed)
-    staged = [program.stage(built, b) for b in batches]
-    step = program.train_step(built, float(tr["emb_lr"]),
-                              float(tr["dense_lr"]))
-    prog, _ = train.first_steps(built, tr, step, staged, batches, seed)
+    fam, cfg, tr = cell.family, cell.config, cell.traffic
+    built = fam.build(cfg, tr, seed)
+    batches = fam.train_batches(cfg, tr, seed)
+    staged = [fam.stage(built, b) for b in batches]
+    step = fam.train_step(built, tr)
+    prog, _ = fam.first_steps(built, tr, step, staged, batches, seed)
     return prog, batches
 
 
@@ -38,14 +34,15 @@ def one_hot(root):
     cell = manifest.Cell("kaggle_train_onehot", root)
     seed = 2**31 + 77
     prog, batches = _first_steps(cell, seed)
-    ref = train.reference_numbers(cell.config, cell.traffic, batches, seed)
+    ref = cell.family.reference_numbers(cell.config, cell.traffic, batches,
+                                        seed)
     return cell, seed, prog, batches, ref
 
 
 def test_the_hybrid_step_follows_the_plain_reference(one_hot):
     cell, seed, prog, batches, ref = one_hot
-    numbers = check.train_numbers(prog, ref)
-    ok, compared = check.verdict(numbers, LIMITS)
+    numbers = cell.family.train_numbers(prog, ref)
+    ok, compared = check.verdict(numbers, cell.own["limits"])
     assert ok, compared
     assert ref["losses"][0] != ref["losses"][1]
 
@@ -54,10 +51,10 @@ def test_the_hybrid_step_follows_the_plain_reference(one_hot):
 def test_the_control_and_the_planted_faults_fail(one_hot, wrong):
     cell, seed, prog, batches, ref = one_hot
     kw = {"precision": "float8"} if wrong == "float8" else {"fault": wrong}
-    bad = train.reference_numbers(cell.config, cell.traffic, batches, seed,
-                                  **kw)
-    numbers = check.train_numbers(bad, ref)
-    ok, compared = check.verdict(numbers, LIMITS)
+    bad = cell.family.reference_numbers(cell.config, cell.traffic, batches,
+                                        seed, **kw)
+    numbers = cell.family.train_numbers(bad, ref)
+    ok, compared = check.verdict(numbers, cell.own["limits"])
     assert not ok, compared
     if wrong == "state_unchanged":
         assert numbers["delta3_dense"] == pytest.approx(1.0)
@@ -66,13 +63,15 @@ def test_the_control_and_the_planted_faults_fail(one_hot, wrong):
 
 def test_half_of_a_multi_hot_batch_left_out_fails(root):
     cell = manifest.Cell("kaggle_train_multihot", root)
-    seed = 2**31 + 78
-    batches = traffic.train_batches(cell.traffic, cell.config["table_sizes"],
-                                    13, seed)
-    ref = train.reference_numbers(cell.config, cell.traffic, batches, seed)
-    bad = train.reference_numbers(cell.config, cell.traffic, batches, seed,
-                                  fault="half_batch")
-    ok, compared = check.verdict(check.train_numbers(bad, ref), LIMITS)
+    fam, seed = cell.family, 2**31 + 78
+    batches = fam.train_batches(cell.config, cell.traffic, seed)
+    ref = fam.reference_numbers(cell.config, cell.traffic, batches, seed)
+    bad = fam.reference_numbers(cell.config, cell.traffic, batches, seed,
+                                fault="half_batch")
+    # held to the one-hot cell's toy limits: the multi-hot cell's own leave
+    # out every number past the first forward and its dense gradient
+    limits = manifest.Cell("kaggle_train_onehot", root).own["limits"]
+    ok, compared = check.verdict(fam.train_numbers(bad, ref), limits)
     assert not ok, compared
 
 
@@ -118,7 +117,8 @@ def test_a_run_over_a_broken_timed_path_is_not_correct(root, name, hooks):
 
 def test_a_run_without_the_exchange_is_not_correct(root):
     assert _run(root, "criteo1tb_train_x4", {})["correct"] is True
-    with program.exchange_left_out():
+    fam = manifest.Cell("criteo1tb_train_x4", root).family
+    with fam.exchange_left_out():
         out = _run(root, "criteo1tb_train_x4", {}, seed=2**31 + 6)
     assert out["correct"] is False, out["compared"]
 
@@ -129,6 +129,8 @@ def test_narrow_rows_on_one_chip_hold_the_seeds_weights(root):
     the program's own lookup then returns is the seed's rows, on one device
     with no mesh too."""
     cell = manifest.Cell("kaggle_train_onehot", root)
+    program, train, weights = (cell.family.program, cell.family.train,
+                               cell.family.weights)
     cfg = dict(cell.config, embedding_dim=32, bottom_mlp=[64, 32])
     seed = 2**31 + 79
     built = program.build(cfg, seed)

@@ -14,8 +14,7 @@ from benchmarks.lib import manifest
 
 import benchmark_tiny
 
-CELLS = [w["name"] for w in manifest.manifest()["workloads"]] \
-    + [benchmark_tiny.FOUR_CHIP]
+CELLS = [w["name"] for w in manifest.manifest()["workloads"]]
 
 
 @pytest.fixture(scope="module")

@@ -4,7 +4,11 @@ bytes, and every seed the same amount of work."""
 import numpy as np
 import pytest
 
-from benchmarks.lib import traffic, weights
+from benchmarks.lib import manifest
+from benchmarks.lib import traffic as draws
+
+traffic = manifest.load_family("dlrm").traffic
+weights = manifest.load_family("dlrm").weights
 
 SIZES = [1000, 3, 50000, 200]
 ONE = {"kind": "train", "global_batch": 64, "id_alpha": 1.05,
@@ -43,7 +47,7 @@ def test_multi_hot_rows_fit_and_hold_the_same_lengths():
 
 def test_row_lengths_trim_to_the_capacity():
     rng = np.random.default_rng(0)
-    lens = traffic.row_lengths(rng, 64, 1, 30, capacity=500)
+    lens = draws.row_lengths(rng, 64, 1, 30, capacity=500)
     assert lens.sum() <= 500 and lens.min() >= 1
 
 
