@@ -8,8 +8,8 @@ import. This module is the gate that runs *before either*: a pure-host
 analytic model of what a :class:`~..parallel.strategy.
 DistEmbeddingStrategy` plan will cost once executed — per-rank
 parameter + optimizer + exchange-buffer bytes, per-step all-to-all
-payload bytes, padded-group shape count (the recompile surface), apply-
-scatter slab sizes against the measured cliff, placement imbalance —
+payload bytes, padded-group shape count (the recompile surface), what the
+apply's scatter pays for each slab's size, placement imbalance —
 with nothing but integer arithmetic over the plan. GSPMD-style systems
 validate placements before touching a pod (SNIPPETS.md [2]'s "8-chip →
 6000-chip without changing application code"); this is that validation
@@ -35,16 +35,15 @@ The model is *calibrated*, not parallel-universe arithmetic:
   enforces agreement.
 
 On top sit declarative :class:`PlanContract` s (max per-rank HBM, max
-a2a bytes/step, zero slabs past the scatter cliff, every rank owns a
-table, padded-group ceiling), enforced by ``tools/plan_audit.py
---strict`` inside ``make verify`` — including a ``criteo1tb`` case with
+a2a bytes/step, every rank owns a table, padded-group ceiling),
+enforced by ``tools/plan_audit.py --strict`` inside ``make verify`` —
+including a ``criteo1tb`` case with
 the real vocab vector — and consumed by planners through
 :meth:`DistEmbeddingStrategy.predicted_cost` / :func:`rank_strategies`
 to rank candidate plans by predicted cost before anything is built.
 
 This module is also the repo's **capacity registry**: chip capability
-numbers (HBM bytes, ICI bandwidth, peak FLOPs) and measured byte
-thresholds (the 2.7→8.65 GB scatter cliff) live HERE as named
+numbers (HBM bytes, ICI bandwidth, peak FLOPs) live HERE as named
 constants. The detlint rule ``hardcoded-capacity`` forbids capacity
 literals elsewhere in the package — a device count or HBM size inlined
 at a call site drifts silently when hardware assumptions change.
@@ -116,14 +115,22 @@ def chip_spec_for_device_kind(device_kind: str) -> ChipSpec:
         "add it with its published peaks and their source")
 
 
-#: The measured apply-scatter rate cliff (docs/perf_tpu.md, VERDICT.md
-#: Weak #3): a single uncapped scatter into a 2.7 GB slab ran at 43 ms
-#: while the same op into an 8.65 GB slab took 70 ms — the cliff lies
-#: inside that bracket. Slabs at or past the upper bound are flagged as
-#: contract violations; slabs inside the bracket are reported as
-#: "cliff_band" (exposed, but not proven slow).
-SCATTER_CLIFF_SAFE_BYTES = 2_700_000_000
-SCATTER_CLIFF_BYTES = 8_650_000_000
+def _scatter_price(stream_rows: int, slab_bytes: int) -> Tuple[str, float]:
+    """``(form, ms)`` of the one scatter-add a step makes into a slab, by the
+    rule the step itself uses (``parallel/optimizers.py:scatter_form`` and
+    its costs, read on the v5e inside the benchmark's cells: ``PERF.md``
+    section 6, PR 31). What older notes called a rate cliff between a 2.7
+    and an 8.65 GB slab (43 -> 70 ms, 4.5 ms a GB) was the sweep's one pass
+    over the slab, 4.04 ms a GiB here; the step now goes row at a time
+    where that pass costs more than the stream, so a slab's size is priced,
+    not refused. No routing (``stream_rows`` 0) prices nothing."""
+    if not stream_rows:
+        return "", 0.0
+    from ..parallel.optimizers import scatter_form, scatter_ns
+
+    form = scatter_form(stream_rows, slab_bytes)
+    return form, scatter_ns(form, stream_rows, slab_bytes) / 1e6
+
 
 #: Default ceiling on padded (width, kind, hotness) group shapes per
 #: plan. Each group is one statically-shaped exchange region — the
@@ -392,7 +399,9 @@ class SlabBudget:
     phys_rows: int
     phys_width: int
     rank_bytes: int
-    cliff: str                # "sub_cliff" | "cliff_band" | "past_cliff"
+    stream_rows: int          # update rows a step scatters into it (0: no routing)
+    scatter_form: str         # the form the step's rule picks for them
+    scatter_ms: float         # and what that form costs (all rows distinct)
 
     def to_json(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -454,7 +463,7 @@ class PlanReport:
                 "violation(s):\n  " + "\n  ".join(self.violations))
 
     def markdown(self) -> str:
-        """Per-rank budget table + slab/cliff table, for docs and CLI."""
+        """Per-rank budget table + slab/scatter table, for docs and CLI."""
         lines = [
             f"### plan audit: {self.label}",
             "",
@@ -491,12 +500,13 @@ class PlanReport:
                 f"| {_gb(r.opt_state_bytes):.3f} "
                 f"| {_gb(r.a2a_buffer_bytes):.3f} "
                 f"| {_gb(r.total_bytes):.3f} | {r.hbm_frac:.1%} |")
-        lines += ["", "| slab | phys shape | rank GB | cliff |",
+        lines += ["", "| slab | phys shape | rank GB | scatter |",
                   "|---|---|---:|---|"]
         for s in self.slabs:
             lines.append(
                 f"| w{s.width} | [{s.phys_rows}, {s.phys_width}] "
-                f"| {_gb(s.rank_bytes):.3f} | {s.cliff} |")
+                f"| {_gb(s.rank_bytes):.3f} "
+                f"| {s.scatter_form or '-'} {s.scatter_ms:.1f} ms |")
         if self.violations:
             lines += ["", "violations:"] + [f"* {v}" for v in self.violations]
         return "\n".join(lines)
@@ -522,15 +532,13 @@ class PlanContract:
     max_rank_bytes: Optional[int] = None
     max_a2a_bytes_per_step: Optional[int] = None
     max_groups: Optional[int] = DEFAULT_MAX_GROUPS
-    forbid_cliff_slabs: bool = True
     require_every_rank_owns_a_table: bool = True
     reason: str = ""
 
 
 def default_contract(chip: str = "v5e") -> PlanContract:
     """The make-verify contract: fit the chip's usable HBM, keep every
-    rank populated, no apply slab past the measured scatter cliff,
-    padded-group count within the zoo-scale invariant."""
+    rank populated, padded-group count within the zoo-scale invariant."""
     spec = CHIP_SPECS[chip]
     return PlanContract(
         max_rank_bytes=int(spec.hbm_bytes * spec.hbm_headroom),
@@ -581,16 +589,6 @@ def check_contract(report: PlanReport, contract: PlanContract,
             f"{contract.max_groups} — the rank-uniform O(#groups) layout "
             "property is lost (compile surface grows with table "
             "heterogeneity)")
-    if contract.forbid_cliff_slabs:
-        for s in report.slabs:
-            if s.cliff == "past_cliff":
-                out.append(
-                    f"slab w{s.width}: per-rank apply-scatter target "
-                    f"{_gb(s.rank_bytes):.2f} GB is past the measured "
-                    f"scatter cliff (>= "
-                    f"{SCATTER_CLIFF_BYTES / 1e9:.2f} GB: 43→70 ms apply, "
-                    "docs/perf_tpu.md) — split it with "
-                    "column_slice_threshold or spread over more ranks")
     report.violations.extend(out)
     return out
 
@@ -789,15 +787,20 @@ def audit_plan(target,
             snapshot_bytes=snap_bytes,
             shm_region_bytes=shm_bytes))
 
+    # update rows a step scatters into each width slab on one rank: every
+    # source's block of every slot (parallel/apply.py builds the same streams)
+    stream = {w: 0 for w in geom.widths}
+    for g in plan.groups:
+        per_source = b_local * g.n * g.hot if g.kind == "d" else g.n * g.hot
+        stream[g.width] += world * per_source
     slabs = []
     for w in geom.widths:
         rb = geom.phys_cap[w] * geom.phys_w[w] * p_isz
-        cliff = ("past_cliff" if rb >= SCATTER_CLIFF_BYTES
-                 else "cliff_band" if rb > SCATTER_CLIFF_SAFE_BYTES
-                 else "sub_cliff")
+        form, ms = _scatter_price(stream[w], rb)
         slabs.append(SlabBudget(
             width=w, phys_rows=geom.phys_cap[w], phys_width=geom.phys_w[w],
-            rank_bytes=rb, cliff=cliff))
+            rank_bytes=rb, stream_rows=stream[w], scatter_form=form,
+            scatter_ms=ms))
 
     # per-step off-chip payloads — the exact step_metrics formulas, so
     # the prediction is checkable against the on-device *_a2a_bytes
@@ -847,8 +850,8 @@ def audit_plan_spec(spec: Dict[str, Any],
     """Capacity-only audit of a bare :meth:`DistEmbeddingStrategy.
     plan_spec` dict (e.g. read back from a checkpoint's ``meta.json``).
     The spec carries slice geometry but no input routing, so exchange
-    payloads/groups price at zero — HBM and cliff contracts still
-    apply (pair with :func:`audit_plan` for the full model)."""
+    payloads, groups and the slabs' scatters price at zero — the HBM
+    contract still applies (pair with :func:`audit_plan` for the full model)."""
 
     class _SpecView:
         """Duck-typed strategy view over the spec's ``local_tables``."""
@@ -888,11 +891,8 @@ def audit_plan_spec(spec: Dict[str, Any],
     slabs = []
     for w in geom.widths:
         rb = geom.phys_cap[w] * geom.phys_w[w] * p_isz
-        cliff = ("past_cliff" if rb >= SCATTER_CLIFF_BYTES
-                 else "cliff_band" if rb > SCATTER_CLIFF_SAFE_BYTES
-                 else "sub_cliff")
         slabs.append(SlabBudget(w, geom.phys_cap[w], geom.phys_w[w], rb,
-                                cliff))
+                                0, "", 0.0))
     mean_live = sum(live_rank) / world if world else 0.0
     report = PlanReport(
         label=label or f"spec/{view.strategy}/world{world}",

@@ -90,7 +90,10 @@ def dedup_sparse_grad(ids: jax.Array, grads: jax.Array, *,
         return _dedup_sparse_grad(ids, grads, pad_id, valid, max_unique)
 
 
-def _dedup_sparse_grad(ids, grads, pad_id, valid, max_unique):
+def _dedup_sparse_grad(ids, grads, pad_id, valid, max_unique,
+                       sum_dtype=None):
+    """``sum_dtype``: the dtype the duplicates' rows are summed (and
+    returned) in; the rows' own when None."""
     n = ids.shape[0]
     u = n if max_unique is None else min(n, int(max_unique))
     if valid is not None:
@@ -103,8 +106,10 @@ def _dedup_sparse_grad(ids, grads, pad_id, valid, max_unique):
     seg = jnp.cumsum(boundary) - 1  # [n], segment index per sorted row
     # seg ascends by construction; declaring it buys the sorted-scatter fast
     # path (measured 1.8x on v5e, docs/perf_tpu.md)
-    unique_grads = jnp.zeros((u,) + grads.shape[1:], grads.dtype
-                             ).at[seg].add(sorted_grads, mode="drop",
+    sum_dtype = sum_dtype or grads.dtype
+    unique_grads = jnp.zeros((u,) + grads.shape[1:], sum_dtype
+                             ).at[seg].add(sorted_grads.astype(sum_dtype),
+                                           mode="drop",
                                            indices_are_sorted=True)
     unique_ids = jnp.full((u,), pad_id, dtype=ids.dtype
                           ).at[seg].set(sorted_ids, mode="drop",

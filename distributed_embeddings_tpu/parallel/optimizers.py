@@ -57,8 +57,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.packed_slab import expand_lane_mask, pack_factor
-from ..ops.sparse_grad import dedup_sparse_grad
-from ..utils import envvars
+from ..ops.sparse_grad import _dedup_sparse_grad, dedup_sparse_grad
+from ..utils import envvars, obs
 
 SGD_DEDUP_ENV = "DETPU_SGD_DEDUP"
 
@@ -71,41 +71,124 @@ def sgd_dedup_forced() -> bool:
     return envvars.enabled(SGD_DEDUP_ENV)
 
 
-# The explicit-sort scatter wins only in a WINDOW of stream lengths —
-# XLA's TPU scatter lowering changes algorithm with stream length, slab
-# size and dtype, and measurement (docs/perf_tpu.md round-4 table) beats
-# modeling here:
-#   * 1.7M rows:  sorted wins big (5.4 GB fp32: 38.5 -> ~18 ms;
-#     10.2 GB bf16: 139 -> 73 ms);
-#   * >= 2.9M rows (tiny zoo, DCNv2 ragged): the sort+permute chain costs
-#     MORE than the internal lowering (+31 / +16 ms end-to-end);
-#   * small streams into huge slabs (65k rows / 10.2 GB bf16, the
-#     Criteo-1TB shard): sorted is 3x WORSE (54 vs 19 ms) — the unsorted
-#     lowering is slab-copy-bound there and the sorted one is worse still.
-# r5 re-test: ISOLATED scan-chained probes at the two loss shapes showed
-# sorted winning (86.4 -> 70.2 ms / 154.0 -> 130.7 ms), but lifting the 2M
-# cap regressed the END-TO-END benches (tiny-zoo bf16 Adagrad 167 -> 195
-# ms; multihot unchanged) — in the full step the scatter fuses/schedules
-# differently than in isolation. The window is an end-to-end fact; always
-# re-validate candidate changes on the bench variants, not probes alone.
-_SORT_STREAM_MIN = 256_000
-_SORT_STREAM_MAX = 2_000_000
+#: The forms of the one scatter-add a width slab gets: the same rows added
+#: to the same slab, duplicates accumulating, out-of-range ids dropped.
+#: XLA's TPU backend has two scatters, and the forms differ in which one
+#: they reach (readings: ``PERF.md`` section 6, PR 31, on the v5e inside
+#: the benchmark's cells and in isolation at their shapes):
+#:
+#: * the **sweep**, which a scatter declared sorted gets: one pass over the
+#:   whole operand at about 530 GB/s of read plus write, the rows merged in
+#:   as it goes with duplicates summed before they are rounded;
+#: * **row at a time**, which an undeclared scatter gets while the stream
+#:   is short for the slab: nothing paid for the slab, 73-79 ns a row, and
+#:   every duplicate rounded into the slab's dtype on its own (a hot row of
+#:   a bf16 slab then drifts: the one-hot cell read ``correct`` false).
+#:
+#: ``sort_fused`` sorts the ids and hands the sweep the permute of the rows
+#: as its fused producer; ``unsorted`` leaves the choice to XLA, which
+#: sorts and sweeps by itself once the stream is long for the slab, 2 %
+#: cheaper than ``sort_fused``; ``dedup_rows`` sums duplicates in float32
+#: first and then goes row at a time over the distinct rows only.
+SCATTER_FORMS = ("sort_fused", "unsorted", "dedup_rows")
+
+# What each scatter costs, as (ns a stream row, ns a slab byte); PERF.md
+# section 6, PR 31, has every reading. The sweep alone: 15.2 ns a row and
+# 4.04 ms a GiB fit the scatter fusion at all four (rows, slab) pairs of the
+# benchmark (58.4, 135.8, 49.4 and 7.9 ms read; 58.4, 136.1, 49.2, 8.1 fitted).
+# Row at a time alone: 24.4 ms for 328 k rows, 123.9 for 1.70 M, 288 for 4 M.
+_SWEEP_NS = (15.2, 4.04e6 / 2 ** 30)
+_RMW_ROW_NS = 75.0
+_SCATTER_NS = {
+    # the sweep and the key sort beside it (2.04 ms for 1.70 M, 13.1 for 6.8 M)
+    "sort_fused": (_SWEEP_NS[0] + 1.9, _SWEEP_NS[1]),
+    # XLA's own sort and sweep: 146.1 ms for 6.8 M rows where ours read 148.9
+    "unsorted": (_SWEEP_NS[0] + 1.5, _SWEEP_NS[1]),
+    # every row distinct, and the sort, the permute and the float32 sums
+    # before it: 6.8 ms for 328 k rows, 38 for 1.70 M
+    "dedup_rows": (_RMW_ROW_NS + 22.0, 0.0),
+}
+# XLA's own lowering of an undeclared scatter was the sweep at 1 268 slab
+# bytes a stream row and below, and row at a time at 2 160 and above:
+# ``unsorted`` is admitted only where the sweep has been read.
+_XLA_SWEEPS_BELOW_BYTES_A_ROW = 1300
+# distinct rows a step of the row-at-a-time loop; 8 k to 64 k read the same
+_RMW_CHUNK = 8192
+
+
+def scatter_ns(form: str, rows: int, slab_bytes: int) -> float:
+    """What ``form`` costs for ``rows`` update rows into ``slab_bytes``."""
+    per_row, per_byte = _SCATTER_NS[form]
+    return per_row * rows + per_byte * slab_bytes
+
+
+def declare_sorted(rows: int, slab_bytes: int) -> bool:
+    """Whether a scatter of ``rows`` ids that ARE sorted and distinct should
+    say so: declared it is the sweep, undeclared row at a time."""
+    return (_SWEEP_NS[0] * rows + _SWEEP_NS[1] * slab_bytes
+            <= _RMW_ROW_NS * rows)
+
+
+def scatter_form(rows: int, slab_bytes: int) -> str:
+    """The cheapest of :data:`SCATTER_FORMS` for ``rows`` update rows into a
+    slab of ``slab_bytes``: row at a time where even a stream of all
+    distinct rows costs less that way than one pass over the slab, else the
+    sweep, by XLA's own hand where that is known to be the sweep."""
+    forms = [f for f in SCATTER_FORMS if f != "unsorted"
+             or slab_bytes <= _XLA_SWEEPS_BELOW_BYTES_A_ROW * rows]
+    return min(forms, key=lambda f: scatter_ns(f, rows, slab_bytes))
+
+
+def _scatter_dedup_rows(slab, ids, vals):
+    """Sum the rows of equal ids in float32, then add each distinct row to
+    the slab once, row at a time, in steps of ``_RMW_CHUNK`` rows, as many
+    steps as hold distinct rows (the tail of the buffers is padding)."""
+    rows = slab.shape[0]
+    # the unscoped body of dedup_sparse_grad: this is the scatter's own
+    # work, not the ``dedup`` phase the pass budgets count
+    uids, sums = _dedup_sparse_grad(ids, vals, rows, None, None,
+                                    sum_dtype=jnp.float32)
+    # distinct rows sort ahead of the dropped ones (``rows`` and past it)
+    distinct = jnp.sum(uids < rows)
+    chunk = min(_RMW_CHUNK, ids.shape[0])
+    pad = -ids.shape[0] % chunk
+    uids = jnp.pad(uids, (0, pad), constant_values=rows)
+    sums = jnp.pad(sums.astype(slab.dtype), ((0, pad), (0, 0)))
+
+    def step(i, slab):
+        at = i * chunk
+        return slab.at[lax.dynamic_slice_in_dim(uids, at, chunk)].add(
+            lax.dynamic_slice_in_dim(sums, at, chunk), mode="drop")
+
+    return lax.fori_loop(0, (distinct + chunk - 1) // chunk, step, slab)
+
+
+def _scatter_add_as(form: str, slab: jax.Array, ids: jax.Array,
+                    vals: jax.Array) -> jax.Array:
+    """``slab.at[ids].add(vals, mode="drop")`` in the given form."""
+    if form == "unsorted":
+        return slab.at[ids].add(vals, mode="drop")
+    if form == "dedup_rows":
+        return _scatter_dedup_rows(slab, ids, vals)
+    sorted_ids, perm = lax.sort_key_val(
+        ids, jnp.arange(ids.shape[0], dtype=jnp.int32))
+    upd = jnp.take(vals, perm, axis=0)  # fuses into the scatter
+    return slab.at[sorted_ids].add(upd, mode="drop",
+                                   indices_are_sorted=True)
 
 
 def _sorted_scatter_add(slab: jax.Array, ids: jax.Array,
                         vals: jax.Array) -> jax.Array:
-    """``slab.at[ids].add(vals)``, sorting the id keys first when the stream
-    length falls in the measured win window (see above): keys sort at
-    3.4 ns/key, the value permute rides the scatter as a fused gather
-    operand, and the scatter declares sortedness."""
-    n = ids.shape[0]
-    if not (_SORT_STREAM_MIN <= n <= _SORT_STREAM_MAX):
-        return slab.at[ids].add(vals, mode="drop")
-    sorted_ids, perm = lax.sort_key_val(
-        ids, jnp.arange(n, dtype=jnp.int32))
-    upd = jnp.take(vals, perm, axis=0)  # fuses into the scatter
-    return slab.at[sorted_ids].add(upd, mode="drop",
-                                   indices_are_sorted=True)
+    """``slab.at[ids].add(vals, mode="drop")`` in the form
+    :func:`scatter_form` picks from the stream's rows and the slab's
+    bytes, both static at trace time. The form's name is the scope the
+    scatter runs under (``sparse_apply_w{k}/scatter_<form>``), so a
+    profile says which one engaged."""
+    if not ids.shape[0]:
+        return slab
+    form = scatter_form(ids.shape[0], slab.size * slab.dtype.itemsize)
+    with obs.scope(f"scatter_{form}"):
+        return _scatter_add_as(form, slab, ids, vals)
 
 
 class SparseSGD:
@@ -116,6 +199,10 @@ class SparseSGD:
     sort + segment-sum dedup pass is skipped entirely — the first
     statically-verified pass cut of ROADMAP 3(a); ``tools/hlo_audit.py
     --strict`` pins the compiled dedup phase to zero row ops on this path.
+    The one scatter-add takes the form :func:`scatter_form` picks for the
+    stream and the slab; its ``dedup_rows`` form sums duplicates itself,
+    inside the scatter's scope and for the scatter's sake (a short stream
+    into a huge slab then skips the pass over the slab), not as a phase.
     ``DETPU_SGD_DEDUP=1`` forces the pass back in for A/B (mathematically
     identical; floating-point-identical too whenever the per-row sums are
     exact, which the equivalence test engineers)."""

@@ -55,18 +55,21 @@ from jax import lax
 
 from ..ops.embedding_lookup import IdsLike, Ragged, SparseIds, embedding_lookup
 from ..utils import obs
-from .optimizers import _SORT_STREAM_MAX, _SORT_STREAM_MIN, sgd_dedup_forced
+from .optimizers import declare_sorted, sgd_dedup_forced
 
 
-def _sorted_decl(n: int) -> bool:
-    """Whether a scatter should DECLARE its (truly sorted) indices sorted.
+def _sorted_decl(n: int, slab: jax.Array) -> bool:
+    """Whether a scatter of ``n`` (truly sorted, distinct) ids into ``slab``
+    should DECLARE them sorted.
 
-    The declaration changes XLA's TPU scatter lowering, and the sorted
-    lowering measured 3x WORSE for small streams into huge slabs (the
-    regime window of :mod:`.optimizers`; a 16M-row table step here went
-    ~100 GB/s -> full-rate when the declaration was dropped). Outside the
-    measured win window, stay on the default lowering."""
-    return _SORT_STREAM_MIN <= int(n) <= _SORT_STREAM_MAX
+    The declaration picks XLA's TPU scatter: declared, one sweep over the
+    whole slab with the rows merged in; undeclared and short for the slab,
+    row at a time, nothing paid for the slab (``optimizers.SCATTER_FORMS``).
+    So a small stream into a huge slab wants it dropped (the old "3x WORSE"
+    note, and a 16M-row table step here that went ~100 GB/s -> full-rate,
+    were the sweep's pass over the slab: ``PERF.md`` section 6, PR 31, has
+    the readings), and the costs of :mod:`.optimizers` say where."""
+    return declare_sorted(int(n), slab.size * slab.dtype.itemsize)
 
 
 @struct.dataclass
@@ -373,7 +376,7 @@ def sparse_rows_adagrad(learning_rate,
             # vocab 16M; docs/perf_tpu.md r5)
             new_acc = acc.at[g.ids].add(
                 rows * rows, mode="drop",
-                indices_are_sorted=_sorted_decl(g.ids.shape[0]))
+                indices_are_sorted=_sorted_decl(g.ids.shape[0], acc))
             new_rows = jnp.take(new_acc, g.ids, axis=0, mode="clip")
             upd = (-lr * rows * lax.rsqrt(new_rows + eps)).astype(
                 g.rows.dtype)
@@ -408,7 +411,7 @@ def sparse_rows_momentum(learning_rate, momentum: float = 0.9,
                 return _Out(-lr * step, t_new)
             _require_unique(g, "sparse_rows_momentum")
             rows = g.rows.astype(tr.dtype)
-            srt = _sorted_decl(g.ids.shape[0])
+            srt = _sorted_decl(g.ids.shape[0], tr)
             # the affine state transition t <- m*t + g runs as two single-
             # use scatters (multiply, add) so the trace slab updates in
             # place under donation; a gather+scatter-set would copy the
@@ -458,7 +461,7 @@ def sparse_rows_adam(learning_rate, b1: float = 0.9, b2: float = 0.999,
                     mu_n, nu_n)
             _require_unique(g, "sparse_rows_adam")
             rows = g.rows.astype(mu.dtype)
-            srt = _sorted_decl(g.ids.shape[0])
+            srt = _sorted_decl(g.ids.shape[0], mu)
             # affine moment transitions as in-place-able multiply+add
             # scatter pairs (see sparse_rows_momentum)
             new_mu = mu.at[g.ids].multiply(
@@ -495,7 +498,7 @@ def apply_sparse_updates(params, updates):
             with obs.scope("sparse_rows_apply"):
                 # unique=False rows (dedup skipped) are unsorted: declaring
                 # sortedness would be a lie XLA is allowed to punish
-                srt = u.unique and _sorted_decl(u.ids.shape[0])
+                srt = u.unique and _sorted_decl(u.ids.shape[0], p)
                 return p.at[u.ids].add(
                     u.rows.astype(p.dtype), mode="drop",
                     indices_are_sorted=srt)

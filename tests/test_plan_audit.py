@@ -10,8 +10,9 @@ Three layers of teeth:
 * **measured validation** — the predicted per-step all-to-all payloads
   must equal the on-device ``*_a2a_bytes`` step metrics on the
   8-virtual-device mesh (the predictor is validated, not decorative);
-* **contract drills** — a seeded over-HBM plan and a seeded past-cliff
-  slab must each FAIL with a violation naming the rank / slab, and the
+* **contract drills** — a seeded over-HBM plan and a seeded empty rank
+  must each FAIL with a violation naming the rank, an unsliced 9.5 GB slab
+  is priced and not refused, and the
   real Criteo-1TB deployment plan (world=16, bf16, column-sliced) must
   pass, all without materializing a single array.
 """
@@ -175,7 +176,9 @@ def test_criteo1tb_deployment_plan_passes():
                         contract=pa.default_contract())
     assert rep.ok, rep.violations
     assert rep.n_sliced_tables >= CRITEO1TB_WORLD
-    assert all(s.cliff != "past_cliff" for s in rep.slabs)
+    # every slab's scatter is priced by the step's own rule
+    assert all(s.stream_rows > 0 and s.scatter_form and s.scatter_ms > 0
+               for s in rep.slabs)
     # the whole point of the threshold: the ~40M-row tables split
     assert rep.n_sliced_tables > len(C1TB_CONFIGS)
     rep.raise_on_violations()  # no-op when clean
@@ -273,17 +276,28 @@ def test_isolated_serving_bills_shm_region():
     assert "shm serving region" not in off.markdown()
 
 
-def test_seeded_past_cliff_slab_fails_naming_slab():
+def test_unsliced_slab_is_priced_not_refused():
     """Criteo-1TB bf16 on 16 ranks WITHOUT column slicing stacks the
-    ~40M-row tables into a ~9.5 GB apply slab — past the measured
-    2.7→8.65 GB scatter cliff; must be rejected with the slab named."""
+    ~40M-row tables into a ~9.5 GB apply slab. Older notes read a rate
+    cliff between 2.7 and 8.65 GB and refused it; on the v5e that was the
+    sweep's pass over the slab (``PERF.md`` section 6, PR 31), which the
+    step now avoids, so the slab is priced by the step's own rule."""
     st = DistEmbeddingStrategy(C1TB_CONFIGS, CRITEO1TB_WORLD,
                                strategy="comm_balanced")
     rep = pa.audit_plan(st, CRITEO1TB_BATCH, optimizer="sgd",
                         param_dtype="bfloat16", dp_input=False,
                         contract=pa.default_contract())
-    assert any("slab w128" in v and "scatter cliff" in v
-               for v in rep.violations), rep.violations
+    assert not any("slab w" in v for v in rep.violations), rep.violations
+    from distributed_embeddings_tpu.parallel import optimizers as opt
+    (slab,) = [s for s in rep.slabs if s.width == 128]
+    assert slab.rank_bytes > 9e9 and slab.stream_rows > 0
+    assert slab.scatter_form == opt.scatter_form(slab.stream_rows,
+                                                 slab.rank_bytes)
+    assert slab.scatter_ms == pytest.approx(opt.scatter_ns(
+        slab.scatter_form, slab.stream_rows, slab.rank_bytes) / 1e6)
+    # the pass over the slab alone would cost more than the whole stream
+    assert slab.scatter_ms < opt._SWEEP_NS[1] * slab.rank_bytes / 1e6
+    assert "scatter" in rep.markdown() and slab.scatter_form in rep.markdown()
 
 
 def test_empty_rank_flagged():

@@ -2,11 +2,10 @@
 
 Device counts, HBM sizes, bandwidth figures, and byte-scale limit
 literals inlined in package code drift silently when hardware
-assumptions change — the scatter-cliff threshold measured on v5e, a
-16 GiB HBM figure, an ICI bandwidth — and a stale copy turns the
-capacity contracts into fiction. PR 8 made
+assumptions change — a 16 GiB HBM figure, an ICI bandwidth — and a
+stale copy turns the capacity contracts into fiction. PR 8 made
 ``analysis/plan_audit.py`` the single registry (``ChipSpec`` /
-``CHIP_SPECS``, ``SCATTER_CLIFF_*``, ``LANES``): everything else in
+``CHIP_SPECS``, ``LANES``): everything else in
 ``distributed_embeddings_tpu/`` must import from it.
 
 Two triggers:
@@ -93,7 +92,7 @@ def check(tree: ast.Module, path: str, src: str, ctx) -> list:
             f"capacity-named constant {'/'.join(n for n in names if n)!r} "
             "assigned from a literal — hardware capability numbers live in "
             "the capacity registry (analysis/plan_audit.py: CHIP_SPECS / "
-            "SCATTER_CLIFF_* / LANES); import from there (or annotate "
+            "LANES); import from there (or annotate "
             f"'# {MARKER} <reason>' if this is genuinely not a hardware "
             "number)"))
     # trigger 1: byte-scale magnitudes anywhere. Hex/binary spellings are

@@ -8,7 +8,7 @@ every shared reference configuration (``tools/_profcommon.build_case``)
 **plus the real Criteo-1TB vocab vector** it prices the placement plan
 with :mod:`distributed_embeddings_tpu.analysis.plan_audit` — per-rank
 param+optimizer+exchange-buffer bytes, per-step all-to-all payloads,
-apply-slab sizes against the measured 2.7→8.65 GB scatter cliff, padded
+what the apply's scatter pays for each slab (the step's own rule), padded
 group-shape count — and enforces the default :class:`PlanContract`.
 
 Strict mode additionally
@@ -17,10 +17,10 @@ Strict mode additionally
   ``analysis.memory.table_memory_report``'s ``eval_shape`` accounting
   (drift beyond ``--calibration-tol`` fails: the mirror broke);
 * runs two seeded NEGATIVE drills — an over-HBM plan (Criteo-1TB fp32 +
-  Adam on 8 ranks) and a past-cliff slab (Criteo-1TB bf16 unsliced on
-  16 ranks) — and fails unless each is rejected with a violation naming
-  the offending rank / slab (a gate that cannot catch a seeded
-  violation is not a gate).
+  Adam on 8 ranks) and a plan that leaves ranks empty (4 tables on 6
+  ranks) — and fails unless each is rejected with a violation naming
+  the offending rank (a gate that cannot catch a seeded violation is
+  not a gate).
 
 Nothing executes on any backend: plans are host metadata, inputs are
 ``ShapeDtypeStruct``s, and the only jax use is ``eval_shape`` inside the
@@ -100,17 +100,14 @@ def seeded_drills():
     over = plan_audit.audit_plan(
         st8, pc.CRITEO1TB_BATCH, optimizer="adam", param_dtype="float32",
         label="drill_over_hbm", contract=default_contract())
-    # drill 2: bf16 on 16 ranks WITHOUT column slicing — the ~40M-row
-    # tables stack into a 9.5 GB apply slab, past the measured cliff;
-    # must fail naming the slab
-    st16 = DistEmbeddingStrategy(configs, pc.CRITEO1TB_WORLD,
-                                 strategy="comm_balanced")
-    cliff = plan_audit.audit_plan(
-        st16, pc.CRITEO1TB_BATCH, optimizer="sgd", param_dtype="bfloat16",
-        dp_input=False, label="drill_past_cliff",
-        contract=default_contract())
+    # drill 2: four tables on six ranks — two ranks own no table slice;
+    # must fail naming them
+    st6 = DistEmbeddingStrategy(
+        [{"input_dim": 100, "output_dim": 8}] * 4, 6)
+    empty = plan_audit.audit_plan(
+        st6, 12, label="drill_empty_rank", contract=default_contract())
     return [("over_hbm", over.violations, "rank "),
-            ("past_cliff", cliff.violations, "slab w")]
+            ("empty_rank", empty.violations, "own no table slice")]
 
 
 def main(argv=None) -> int:
