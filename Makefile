@@ -3,7 +3,7 @@
 
 SHELL := /bin/bash
 
-.PHONY: all native test test-fast bench bench-diff chip-smoke clean pkg \
+.PHONY: all native test test-fast chip-smoke clean pkg \
         verify lint plan-audit audit-step hlo-audit schedule-audit \
         concurrency-audit \
         check-obs check-obs-report check-resilience \
@@ -23,9 +23,6 @@ test:
 # RSS-bounded streaming, 2-process cluster); CI runs the full `test`
 test-fast:
 	python -m pytest tests/ -q -m "not slow"
-
-bench:
-	python bench.py
 
 # the driver's tier-1 gate (ROADMAP.md "Tier-1 verify", verbatim semantics)
 # plus the static gates (detlint rules, the SPMD step auditor), the
@@ -106,7 +103,8 @@ check-phase-profile:
 	env JAX_PLATFORMS=cpu python tools/phase_profile.py --smoke --strict
 
 # observability gate: obs.py imports cleanly under JAX_PLATFORMS=cpu and
-# the DETPU_OBS=1 smoke bench emits a parseable step-metrics sidecar
+# a DETPU_OBS=1 run of examples/dlrm/main.py at toy size with
+# --metrics_out writes a parseable step-metrics sidecar
 check-obs:
 	python tools/check_obs.py
 
@@ -194,13 +192,6 @@ check-tracing:
 # table(s) (utils/mplane.py)
 check-obsplane:
 	python tools/check_obsplane.py
-
-# optional regression gate: diff two BENCH records, nonzero exit on a >10%
-# throughput regression. Usage: make bench-diff OLD=before.json NEW=after.json
-bench-diff:
-	@test -n "$(OLD)" -a -n "$(NEW)" || \
-	  { echo "usage: make bench-diff OLD=<record> NEW=<record>"; exit 2; }
-	python tools/compare_bench.py $(OLD) $(NEW)
 
 # the quickest proof that the system still starts on the chip: run it on
 # a machine that has one (one process per chip). Here, without a chip, it

@@ -2,7 +2,7 @@
 
 Drives the main path once, in ONE process, through the entry points a user
 calls, at the full width of the MLPerf DLRM this repo benchmarks
-(``bench.make_cfg`` over the 26 Criteo-Kaggle tables capped at 2 M rows,
+(the published widths over the 26 Criteo-Kaggle tables capped at 2 M rows,
 global batch 65536, bf16 compute, bf16 tables, ``SparseSGD`` +
 ``optax.sgd``, seeded power-law ids, random weights from a seed):
 
@@ -47,6 +47,8 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(_HERE, "chiprun_out", "chip_smoke")
 
 SEED = 0
+CAP = 2_000_000         # rows a table keeps of its Criteo-Kaggle vocabulary
+BATCH = 65536           # global batch
 LR = 0.1                # both halves; large enough that 8 steps visibly learn
 TIMED_STEPS = 8         # make_hybrid_train_step calls inside the timed window
 PROFILED_STEPS = 2      # further steady steps, under the profiler
@@ -120,10 +122,10 @@ def main(argv=None):
     if dry:
         jax.config.update("jax_platforms", "cpu")
 
-    from bench import BATCH, CAP, CRITEO_KAGGLE_SIZES, make_cfg
+    from tools._profcommon import CRITEO_KAGGLE_SIZES
     from distributed_embeddings_tpu.analysis import audit, plan_audit
     from distributed_embeddings_tpu.models.dlrm import (
-        DLRM, DLRMDense, bce_with_logits)
+        DLRM, DLRMConfig, DLRMDense, bce_with_logits)
     from distributed_embeddings_tpu.parallel import (
         DistributedEmbedding, ServeConfig, Served, ServingRuntime,
         SnapshotPublisher, SparseSGD, bootstrap, init_hybrid_state,
@@ -203,7 +205,11 @@ def main(argv=None):
         agree_rows = AGREE_ROWS
         check("model.full_width", sum(sizes) == 10_569_296
               and batch == 65536, f"{sum(sizes)} rows, batch {batch}")
-    cfg = make_cfg(sizes, jnp.bfloat16)
+    cfg = DLRMConfig(table_sizes=sizes, embedding_dim=128,
+                     num_numerical_features=13,
+                     bottom_mlp_dims=(512, 256, 128),
+                     top_mlp_dims=(1024, 1024, 512, 256, 1),
+                     compute_dtype=jnp.bfloat16)
     dense = DLRMDense(cfg)
     emb_opt = SparseSGD()
     tx = optax.sgd(LR)

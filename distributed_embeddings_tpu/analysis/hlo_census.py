@@ -24,9 +24,7 @@ On top of the census sit declarative :class:`PassBudget` contracts
 ("the ``dedup`` phase holds zero sort/segment-sum passes when the sparse
 optimizer declares ``needs_dedup=False``", "at most N gather passes per
 lookup group", "no float convert round-trips inside the apply phase"),
-enforced by ``tools/hlo_audit.py --strict`` inside ``make verify`` and by
-the bench's ``phase_budget`` section (gated by ``tools/compare_bench.py``
-— a pass-count regression fails the candidate like a recompile does).
+enforced by ``tools/hlo_audit.py --strict`` inside ``make verify``.
 
 Counting convention: one HLO instruction of a row-op opcode = one pass.
 Backend lowering differences are normalized where they matter for the
@@ -57,12 +55,6 @@ from ..utils import obs
 #: "fusion"); these are the row-op passes of the ROADMAP 3(a) budget
 ROW_OP_KINDS = ("gather", "scatter", "sort", "cumsum", "all_to_all",
                 "convert", "transpose")
-
-#: the kinds tools/compare_bench.py gates between bench rounds (convert/
-#: transpose counts are reported but not gated: they move with benign
-#: layout choices; gather/scatter/sort/cumsum/all-to-all passes are the
-#: budget). Keep in sync with compare_bench.PHASE_GATE_KINDS.
-GATED_KINDS = ("gather", "scatter", "sort", "cumsum", "all_to_all")
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -297,8 +289,8 @@ class CensusReport:
         return self
 
     def phase_table(self) -> Dict[str, Dict[str, int]]:
-        """The compact per-phase budget the bench record embeds: kind
-        counts + fusion + bytes_est per phase path, gated kinds first."""
+        """The per-phase budget as rows: kind counts + fusion + bytes_est
+        per phase path."""
         out: Dict[str, Dict[str, int]] = {}
         for path, p in sorted(self.phases.items()):
             row = {k: p.counts.get(k, 0) for k in ROW_OP_KINDS}

@@ -3,10 +3,9 @@ cost-model calibration against the schedule auditor.
 
 Every phase number the repo had before this module was *modeled*:
 :mod:`.schedule_audit` prices the compiled step's dependency DAG from
-:data:`~.plan_audit.CHIP_SPECS` byte arithmetic, and the bench gates ride
-those predictions. Nothing measured where a step's milliseconds actually
-go — ``DETPU_PROFILE_DIR`` dumped raw TensorBoard traces no tool ever
-read. This module closes the loop, with the same profile-then-optimize
+:data:`~.plan_audit.CHIP_SPECS` byte arithmetic. Nothing measured where
+a step's milliseconds actually go — ``DETPU_PROFILE_DIR`` dumped raw
+TensorBoard traces no tool ever read. This module closes the loop, with the same profile-then-optimize
 discipline the reference library applies to its fused lookup kernels:
 
 * :func:`profile_steps` runs N timed steps, each under its own
@@ -38,14 +37,10 @@ discipline the reference library applies to its fused lookup kernels:
   model is lying about the dependency structure); a modeled
   **overlappable** collective may measure either way — structural
   possibility is not realized overlap until the pipelined step ships
-  (ROADMAP item 2), and exactly this asymmetry makes the gate a ratchet:
-  once the pipelined step wins real overlap, the measured classification
-  flips and ``tools/compare_bench.py::check_phase_profile`` refuses to
-  let it regress.
+  (ROADMAP item 2).
 
 Profiling is strictly opt-in: nothing here touches how steps are built —
-an unprofiled step is bitwise the program it always was, and the bench's
-``phase_profile`` section prices the profiler's own overhead.
+an unprofiled step is bitwise the program it always was.
 
 Module-scope imports stay jax-free (the dataclasses and the calibration
 math must be importable by report tooling without a backend); everything
@@ -296,8 +291,7 @@ class PhaseProfile:
         return d
 
     def summary(self) -> Dict[str, Any]:
-        """The compact record the bench ``phase_profile`` section embeds
-        (and ``check_phase_profile`` gates)."""
+        """The compact record ``tools/phase_profile.py`` writes."""
         return {
             "label": self.label,
             "world": self.world,
@@ -367,7 +361,7 @@ def profile_steps(run_step: Callable[[], Any], *,
 
     ``run_step`` runs exactly one already-compiled step AND blocks on its
     result (the caller owns state threading and the readback — the same
-    contract as the bench's timed loops). Each step gets its OWN
+    contract as any timed loop). Each step gets its OWN
     ``jax.profiler.trace`` capture so the per-phase numbers carry real
     p50/p95 spread instead of one blurred total. Captures land under
     ``profile_dir`` (default ``DETPU_PHASE_PROFILE_DIR``, else a temp
@@ -446,7 +440,7 @@ class CalibrationRow:
 @dataclasses.dataclass
 class CalibrationReport:
     """The measured-vs-modeled drift table: where the byte-cost model
-    that prices every bench gate drifts from the clock."""
+    of the schedule auditor drifts from the clock."""
     label: str
     rows: List[CalibrationRow]
     scale: float                 # the cancelled backend-speed factor

@@ -986,8 +986,7 @@ def price_int8_serving(target,
 
     Returns a plain JSON-able dict next to a baseline
     :func:`audit_plan` run (optimizer ``"sgd"`` — zero slots, the
-    inference bill). Keyed so ``tools/serve_bench.py`` can embed it in
-    the bench ``serving`` section.
+    inference bill).
     """
     strategy = (target if hasattr(target, "local_configs_list")
                 else target.strategy)

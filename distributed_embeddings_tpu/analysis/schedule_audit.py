@@ -37,11 +37,6 @@ On top of the graph sit two contract layers:
   mesh size, because the DAG shape — unlike the wall clock — does not
   depend on how many chips run the program.
 
-``tools/compare_bench.py::check_schedule`` gates the bench record's
-``schedule`` section round over round: a candidate whose
-``serialized_collective_fraction`` or modeled critical-path bytes GROW
-fails, so overlap, once won, can never silently regress.
-
 Like the census, everything here is ``lower().compile()`` + text
 parsing: nothing executes on any backend.
 """
@@ -504,8 +499,7 @@ def declared_overlap_contracts(schedule) -> List[ScheduleContract]:
     these next to :meth:`ScheduleReport.check_against_schedule` makes
     the gate two-sided: the declaration check verifies the claimed
     partner compute exists, and these verify the collective's GLOBAL
-    classification flipped to overlappable (the serialized fraction the
-    bench ratchet rides)."""
+    classification flipped to overlappable."""
     out: List[ScheduleContract] = []
     for p in schedule.phases:
         if p.kind == "collective" and p.overlaps:
@@ -701,8 +695,7 @@ class ScheduleReport:
 
     # -- serialization ----------------------------------------------------
     def summary(self) -> Dict[str, Any]:
-        """The compact record the bench's ``schedule`` section embeds and
-        ``tools/compare_bench.py::check_schedule`` gates."""
+        """The compact record: the head of :meth:`to_json`."""
         return {
             "label": self.label,
             "world": self.world,

@@ -103,7 +103,7 @@ def resolve_config(telemetry) -> Optional[TelemetryConfig]:
     changes the step's *call* arity, so an env variable must never flip
     it under an unsuspecting 3-arg call site. ``DETPU_TELEMETRY`` is
     consumed by the telemetry-aware entry points instead (the dlrm
-    example, ``tools/obs_report.py``, the bench telemetry section),
+    example, ``tools/obs_report.py``),
     which pass ``telemetry=``/the carried state together.
     """
     if telemetry is None or telemetry is False:

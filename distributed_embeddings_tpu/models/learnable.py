@@ -15,8 +15,8 @@ DLRM-shaped signal in synthetic data instead:
 * labels draw ``Bernoulli(sigmoid(logit))``.
 
 A model that learns nothing scores AUC 0.5 on held-out draws; the Bayes
-ceiling is well above 0.8 for the default scale. Used by the convergence
-bench (``bench.py``) and the slow convergence test.
+ceiling is well above 0.8 for the default scale. Used by the slow
+convergence test (``tests/test_convergence.py``).
 """
 
 from __future__ import annotations
@@ -73,8 +73,8 @@ def train_dlrm_convergence(task: LearnableClicks, *, world_size: int = 1,
     """Train DLRM on ``task`` through the FULL hybrid path and return
     ``(auc_start, auc_mid, auc_end)`` on a held-out draw.
 
-    The one convergence driver shared by the bench (single chip) and the
-    slow tests (8-device CPU mesh) — sparse embedding optimizer, optax
+    The convergence driver of the slow tests (one device or the
+    8-device CPU mesh) — sparse embedding optimizer, optax
     dense side, eval via :func:`~..parallel.make_hybrid_eval_step` +
     exact AUC.
 

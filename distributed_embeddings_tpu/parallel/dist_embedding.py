@@ -1203,7 +1203,7 @@ class DistributedEmbedding:
           mp→dp activation exchange, and the reverse cotangent exchange
           (static consequences of the plan layout, included so a metrics
           record prices the padded exchange exactly like
-          ``bench.plan_exchange_bytes`` does).
+          ``analysis.plan_audit.audit_plan`` does).
         * ``out_pad_frac`` — dead-column fraction of this rank's rows in
           the output exchange (the placement-imbalance signal
           ``comm_balanced`` minimizes).

@@ -496,7 +496,7 @@ class OnlineRuntime:
             raise
         if not train.preempted:
             # final publish + drain: the freshest completed state serves
-            # the tail (and the bench's served-AUC tracks the offline
+            # the tail (so a served AUC tracks the offline
             # final model)
             if self.publisher._last_step != train.step:
                 self.publisher.publish(train.state, train.streaming)

@@ -274,7 +274,7 @@ def streaming_schedule() -> StepSchedule:
     measured phase profile confirmed it on the clock in PR 13 (0.036
     measured serialized). Declaring it here is what lets
     ``make schedule-audit`` certify the overlap against the compiled
-    DAG and ``compare_bench.check_schedule`` ratchet it so a refactor
+    DAG (its ``--strict`` run builds the streaming case), so a refactor
     that re-serializes the staging chain fails loudly.
 
     The lookup's real dependency on the SERVE half of the admit phase

@@ -22,7 +22,7 @@ family:
   by any amount of serving. The program family is a small fixed
   **ladder** of padded batch shapes (one compiled executable per rung,
   warmed up front), so steady-state serving is pinned to ZERO
-  recompiles by the same compile-listener counter the bench gates on.
+  recompiles by the compile-listener counter (``steady_recompiles``).
 * **The request coalescer** — variable-size requests (1..n samples
   each, single-hot, fixed multi-hot, or ragged-hotness inputs) are
   packed FIFO into the smallest rung that holds them; padding samples
@@ -69,8 +69,8 @@ makes :func:`drive` spike the arrival rate during second ``<pos>`` of
 the stream (the QPS-spike drill). ``tools/check_serving.py`` (= ``make
 check-serving``) runs both against the ladder in CI and requires
 bounded p99, clean typed shedding, zero steady-state recompiles, and
-post-burst recovery; ``tools/serve_bench.py`` measures p50/p95/p99 at a
-fixed Zipfian QPS for the bench ``serving`` section.
+post-burst recovery; the benchmark's ``kaggle_serve_ranking`` cell
+(``benchmarks/run.py``) measures the latency tails on the chip.
 
 The runtime is single-threaded and clock-injectable: callers own the
 loop (``submit`` + ``poll``), tests drive a manual clock, and
@@ -580,8 +580,8 @@ class ServingRuntime:
         # minted in _normalize (or adopted from Request.trace when an
         # upstream supervisor minted it), finished with the five-stage
         # partition in _run_flush or the minimal queue_wait span on a
-        # terminal outcome. ``trace=None`` defers to DETPU_TRACE; the
-        # bench passes explicit False/True to measure the delta
+        # terminal outcome. ``trace=None`` defers to DETPU_TRACE; an
+        # explicit False/True overrides it (tests measure the delta)
         self.traces = reqtrace.TraceBuffer(
             enabled=trace, process="serve", top_fn=self._trace_top_decile)
 
@@ -1113,7 +1113,7 @@ class ServingRuntime:
 
     def steady_recompiles(self) -> int:
         """Compiles observed since :meth:`warmup` finished — the serving
-        analogue of the bench's ``steady_state_recompiles`` gate."""
+        analogue of a train window's ``compiles_in_window`` (must be 0)."""
         if not self._warm:
             return 0
         return obs.counters().get("recompiles", 0) - self._compiles_at_steady
@@ -1354,7 +1354,7 @@ class ServingRuntime:
     def stats(self) -> Dict[str, Any]:
         """Host summary: counts, latency percentiles over served
         requests, aggregate pad fraction, queue-depth p95, recompile
-        verdicts — the dict the bench section and the check drill
+        verdicts — the dict the benchmark's cell and the check drill
         read. Percentiles come from the registry's mergeable
         log-bucketed sketches (bounded memory, no full sort); every
         key that predates the sketch migration is preserved as a view,

@@ -573,7 +573,7 @@ def occupancy(de, state) -> Dict[str, Any]:
     """Host summary of a streaming state: per-table slot occupancy and
     the cumulative admission/eviction/bucket counters — the streaming
     analogue of ``telemetry.load_balance`` (``tools/check_streaming.py``
-    and the bench section read this)."""
+    and ``tools/check_online.py`` read this)."""
     host = jax.tree.map(np.asarray, state)
     tables = []
     for tid, (cap, nb) in sorted(de.streaming_tables.items()):

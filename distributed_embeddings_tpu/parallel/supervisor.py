@@ -982,7 +982,7 @@ class Supervisor:
         socket when ``sync`` and the worker is alive; otherwise the
         last received) plus the ``"supervisor"`` block: restarts,
         outage bookkeeping, shm publish latency, restart-to-first-served
-        — the isolation-layer stats the bench gates."""
+        — the isolation-layer stats the drills read."""
         if sync and self._alive:
             self._stats_event.clear()
             self._send_q.put(("stats",))

@@ -19,4 +19,4 @@ from .runtime import (BackendProbe, BackendUnavailable, CheckpointCorrupt,
                       DeadlineExceeded, FaultInjected, InvalidInputError,
                       NonFiniteLossError, SectionRecorder, deadline,
                       ensure_compile_cache, fault_point, preempt_step,
-                      probe_backend, retry, run_section)
+                      probe_backend, retry)

@@ -1,7 +1,7 @@
 """Single registry of every ``DETPU_*`` environment variable.
 
 The knob surface grew one env read at a time (``DETPU_OBS``,
-``DETPU_FAULT``, ``DETPU_BENCH_SMOKE``, ...) with no one place that says
+``DETPU_FAULT``, ``DETPU_NANGUARD``, ...) with no one place that says
 what exists, what the default is, or what a value means — and nothing
 stopping a typo'd ``os.environ.get("DETPU_OBSS")`` from shipping as a
 silently-dead knob. This module is that place: every ``DETPU_*`` variable
@@ -129,15 +129,12 @@ declare("DETPU_OBS_MAX_FILES", default="2",
             "sidecar (<path>.1 newest .. <path>.N oldest — the "
             "checkpoint-ring idiom); total disk is bounded by "
             "(N + 1) * DETPU_OBS_MAX_BYTES")
-declare("DETPU_OBS_SIDECAR", default="BENCH.metrics.jsonl",
-        doc="path of the step-metrics JSONL sidecar bench.py writes under "
-            "DETPU_OBS=1")
 
 # access telemetry (analysis/telemetry.py; carried through train steps
 # built by parallel/trainer.py when enabled)
 declare("DETPU_TELEMETRY", default="",
         doc="1 = telemetry-aware entry points (examples/dlrm, "
-            "tools/obs_report.py, bench telemetry section) build their "
+            "tools/obs_report.py) build their "
             "steps with jit-carried access telemetry. Plain step "
             "builders need the explicit telemetry= opt-in (it changes "
             "the step's call arity)")
@@ -209,14 +206,9 @@ declare("DETPU_MICROBATCH", default="2",
             "the serialized step (K=1 IS the serialized baseline, "
             "bitwise — the opt-in default is 2 so asking for a pipeline "
             "actually builds one). The per-device batch must divide by K")
-declare("DETPU_MICROBATCH_BENCH", default="2",
-        doc="microbatch count K of bench.py's `pipeline` section (the "
-            "pipelined-vs-serialized throughput A/B); independent of "
-            "DETPU_MICROBATCH so a bench run never inherits a training "
-            "run's K")
 
 # deadline-bounded serving runtime (parallel/serving.py +
-# tools/serve_bench.py / tools/check_serving.py = make check-serving)
+# tools/check_serving.py = make check-serving)
 declare("DETPU_SERVE_BURST_X", default="8",
         doc="arrival-rate multiplier of the burst@<pos> QPS-spike drill "
             "(the serving load generator applies it during each burst "
@@ -328,9 +320,8 @@ declare("DETPU_TRACE", default="1",
         doc="request tracing master switch: when enabled every "
             "ServingRuntime/Supervisor submit mints a trace whose stage "
             "spans partition the request's life (sum == latency_ms); "
-            "the per-request cost is a dict and a hash, and the bench "
-            "tracing section gates that tracing-off throughput is "
-            "unchanged. Empty/0 disables minting entirely")
+            "the per-request cost is a dict and a hash. Empty/0 "
+            "disables minting entirely")
 declare("DETPU_TRACE_RING", default="256",
         doc="capacity of the retained-trace ring per TraceBuffer: "
             "tail-sampled traces beyond this evict oldest-first, so "
@@ -447,14 +438,6 @@ declare("DETPU_DRYRUN_TIMEOUT_S", default="600",
 declare("_DETPU_DRYRUN_CHILD", default=None,
         doc="internal: set in the dryrun child's environment so it knows "
             "to touch the backend directly")
-
-# bench.py
-declare("DETPU_BENCH_SMOKE", default="",
-        doc="1 = shrink every bench shape to smoke-test size")
-declare("DETPU_BENCH_SIDECAR", default="BENCH.partial.jsonl",
-        doc="path of bench.py's crash-surviving per-section JSONL sidecar")
-declare("DETPU_BENCH_SECTION_DEADLINE_S", default="1200",
-        doc="best-effort SIGALRM deadline (seconds) per bench section")
 
 # sparse optimizer paths (parallel/optimizers.py, parallel/sparse_optax.py)
 declare("DETPU_SGD_DEDUP", default="",

@@ -222,7 +222,7 @@ def profile_trace(label: Optional[str] = None) -> Iterator[None]:
     transparent no-op when the variable is unset.
 
     ``label`` names a subdirectory so successive captures (e.g. one per
-    bench section) do not overwrite each other.
+    phase) do not overwrite each other.
     """
     base = envvars.get(PROFILE_DIR_ENV)
     if not base:
@@ -403,8 +403,8 @@ class MetricsLogger:
 
     Rides :class:`.runtime.SectionRecorder` (append one JSON line, flush,
     fsync), so a process killed at any point leaves every previously
-    logged record parseable — the property that made ``BENCH.partial.jsonl``
-    survive rc=124. Records:
+    logged record parseable — what a run that dies at its time limit
+    (rc=124) leaves behind. Records:
 
     * ``{"section": "step_metrics", "step": N, "metrics": {...}, ...}``
       from :meth:`log_step` — device arrays are fetched and listified

@@ -26,10 +26,10 @@ Pieces, all composable and CPU-testable:
 * :func:`fault_point` — env-driven fault injection
   (``DETPU_FAULT=hang:backend,slow:coordinator,die:checkpoint_write``)
   so every failure mode above is exercisable in CPU-only tests.
-* :class:`SectionRecorder` / :func:`run_section` — append-only,
-  fsynced JSONL sidecar of per-section results, so a process killed
-  mid-run (OOM, SIGKILL, driver timeout) leaves every completed section's
-  record parseable on disk. ``bench.py`` rides this.
+* :class:`SectionRecorder` — append-only, fsynced JSONL sidecar of
+  per-section results, so a process killed mid-run (OOM, SIGKILL, driver
+  timeout) leaves every completed section's record parseable on disk
+  (``obs.MetricsLogger`` rides this).
 
 This module deliberately does NOT import jax at module scope: importing it
 must never touch an accelerator backend.
@@ -589,51 +589,3 @@ def _jsonable(x: Any) -> Any:
     if isinstance(x, (set, tuple)):
         return list(x)
     return repr(x)
-
-
-def run_section(recorder: Optional[SectionRecorder], name: str,
-                fn: Callable[[], Any], *, default: Any = None,
-                retries: int = 1, deadline_s: Optional[float] = None
-                ) -> Any:
-    """Run one named section under a (best-effort) deadline, with retries,
-    recording the outcome to ``recorder`` the moment it is known.
-
-    One failed or hung section must not take down the run: failures are
-    logged + recorded and ``default`` is returned. ``fault_point('<name>')``
-    fires first, so any section is individually killable/hangable via
-    ``DETPU_FAULT`` in tests.
-    """
-    import traceback
-
-    last_err = None
-    for attempt in range(retries + 1):
-        t0 = time.monotonic()
-        try:
-            # fault_point INSIDE the deadline: an injected hang at a
-            # section point must be bounded like any other section work
-            with deadline(deadline_s, label=f"section {name!r}"):
-                fault_point(name)
-                value = fn()
-        except Exception as e:  # noqa: BLE001 - report and continue
-            last_err = e
-            print(f"[runtime] section {name} failed "
-                  f"(attempt {attempt + 1}/{retries + 1}):", file=sys.stderr)
-            traceback.print_exc()
-            continue
-        if recorder is not None:
-            # outside the try: a recording hiccup (full disk, odd payload)
-            # must not re-run — or worse, discard — a computed result
-            try:
-                recorder.record(name, ok=True, value=value,
-                                elapsed_s=round(time.monotonic() - t0, 3),
-                                attempt=attempt + 1)
-            except Exception:  # noqa: BLE001 - the value still stands
-                logger.exception("could not record section %r result", name)
-        return value
-    if recorder is not None:
-        try:
-            recorder.record(name, ok=False, error=repr(last_err),
-                            attempts=retries + 1)
-        except Exception:  # noqa: BLE001 - sidecar is best-effort
-            logger.exception("could not record section %r failure", name)
-    return default

@@ -133,7 +133,7 @@ def test_hardcoded_capacity_rule():
     assert detlint._matches(
         "distributed_embeddings_tpu/analysis/plan_audit.py",
         hardcoded_capacity.EXCLUDE)
-    assert not detlint._matches("bench.py", hardcoded_capacity.SCOPE)
+    assert not detlint._matches("chip_smoke.py", hardcoded_capacity.SCOPE)
 
 
 def test_module_scope_jax_rule():

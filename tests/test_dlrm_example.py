@@ -107,3 +107,28 @@ def test_save_restore_resumes_data_stream(tmp_path):
     blob = (tmp_path / "state2" / "dense.msgpack").read_bytes()
     assert int(np.asarray(
         serialization.msgpack_restore(blob)["step"])) == 6
+
+
+def test_obs_sidecar_comes_from_the_example(tmp_path, monkeypatch):
+    """What ``tools/check_obs.py`` (``make check-obs``) runs: the toy-size
+    example under ``DETPU_OBS=1`` with ``--metrics_out`` writes the
+    step-metrics sidecar of the ISSUE 2 acceptance criterion."""
+    import json
+
+    from tools import check_obs
+
+    side = tmp_path / "metrics.jsonl"
+    monkeypatch.setenv("DETPU_OBS", "1")
+    _run(tmp_path, list(check_obs.EXAMPLE_ARGS) + ["--metrics_out", str(side)])
+    assert check_obs.sidecar_errors(str(side)) == []
+    recs = [json.loads(line) for line in side.read_text().splitlines()]
+    steps = [r for r in recs if r["section"] == "step_metrics"]
+    assert len(steps) == 4  # --num_batches 4 --metrics_interval 1
+    for field in check_obs.REQUIRED_METRIC_FIELDS:
+        assert len(steps[0]["metrics"][field]) == 8  # one entry a rank
+    assert recs[-1]["section"] == "counters" and recs[-1]["final"]
+    assert recs[-1]["counters"]["recompiles"] > 0
+    # the check names what a sidecar lacks
+    bare = tmp_path / "bare.jsonl"
+    bare.write_text('{"section": "step_metrics", "metrics": {}}\n')
+    assert len(check_obs.sidecar_errors(str(bare))) == 4

@@ -172,15 +172,6 @@ def test_min_greater_than_max_rejected():
         PassBudget("dedup", "sort", max_passes=0, min_passes=1)
 
 
-def test_gated_kinds_in_sync_with_compare_bench():
-    # compare_bench must stay importable without jax, so it duplicates
-    # the tuple; this is the sync the comments on both sides promise
-    from tools import compare_bench
-
-    from distributed_embeddings_tpu.analysis import hlo_census
-    assert compare_bench.PHASE_GATE_KINDS == hlo_census.GATED_KINDS
-
-
 # ------------------------------------------------- phase attribution (mesh)
 
 

@@ -474,38 +474,3 @@ def test_blackbox_ring_env_controls_capacity(tmp_path, monkeypatch):
     for i in range(9):
         rec.note_event("e", i=i)
     assert len(rec.snapshot()["events"]) == 3
-
-
-# ------------------------------------------------- compare_bench gate
-
-
-def test_compare_bench_obs_plane_gate():
-    from tools import compare_bench as cb
-
-    def rec(stats_us=120.0, scrape=1.5, dump=2.0, ok=1, rc=0):
-        return {"metric": "x",
-                "obs_plane": {"stats_wall_us": stats_us,
-                              "scrape_ms": scrape, "dump_ms": dump,
-                              "scrape_ok": ok,
-                              "steady_state_recompiles": rc}}
-
-    base = rec()
-    assert cb.check_obs_plane(base, rec()) == 0
-    # within the 100% cost ratchet
-    assert cb.check_obs_plane(base, rec(stats_us=230.0)) == 0
-    # beyond it: the plane's own read path got structurally slower
-    assert cb.check_obs_plane(base, rec(stats_us=300.0)) == 1
-    assert cb.check_obs_plane(base, rec(scrape=3.5)) == 1
-    assert cb.check_obs_plane(base, rec(dump=4.5)) == 1
-    # below the noise floor the ratchet is skipped: 3us -> 9us is timer
-    # jitter, not a regression
-    cheap = rec(stats_us=3.0)
-    assert cb.check_obs_plane(cheap, rec(stats_us=9.0)) == 0
-    # hard failures regardless of the baseline
-    assert cb.check_obs_plane(base, rec(ok=0)) == 1
-    assert cb.check_obs_plane(base, rec(rc=2)) == 1
-    # missing section vs a baseline that has it fails; both-missing and
-    # new-section-no-baseline pass (rounds legitimately add sections)
-    assert cb.check_obs_plane(base, {"metric": "x"}) == 1
-    assert cb.check_obs_plane({"metric": "x"}, {"metric": "x"}) == 0
-    assert cb.check_obs_plane({"metric": "x"}, rec()) == 0

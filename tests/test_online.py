@@ -559,32 +559,6 @@ def test_preempt_mid_serve_then_resume_consistent_pair(tmp_path,
         == r2.train.step
 
 
-def test_compare_bench_online_gate():
-    from tools import compare_bench as cb
-
-    def rec(p95=10.0, rc=0, fresh=2.0, slo=4, delta=0.0):
-        return {"metric": "x",
-                "online": {"latency_p95_ms": p95,
-                           "steady_state_recompiles": rc,
-                           "freshness_p95_steps": fresh,
-                           "freshness_slo_steps": slo,
-                           "auc_delta_vs_replay": delta}}
-
-    base = rec()
-    assert cb.check_online(base, rec()) == 0
-    assert cb.check_online(base, rec(p95=10.9)) == 0      # within 10%
-    assert cb.check_online(base, rec(p95=11.5)) == 1      # p95 ratchet
-    assert cb.check_online(base, rec(rc=1)) == 1          # recompiles
-    assert cb.check_online(base, rec(fresh=5.0)) == 1     # SLO breach
-    assert cb.check_online(base, rec(delta=0.01)) == 1    # AUC drifted
-    assert cb.check_online(base, rec(delta=-0.01)) == 1   # either sign
-    # missing section vs a baseline that has it fails; both-missing and
-    # new-section-no-baseline pass (rounds legitimately add sections)
-    assert cb.check_online(base, {"metric": "x"}) == 1
-    assert cb.check_online({"metric": "x"}, {"metric": "x"}) == 0
-    assert cb.check_online({"metric": "x"}, rec()) == 0
-
-
 # ------------------------------------------- freshness-breach post-mortem
 
 

@@ -11,8 +11,7 @@ Three layers, mirroring the module split:
   recovers the ``detpu/`` phase names), :func:`profile_steps` on a tiny
   jitted step, and the opt-in guarantee (a profiled step's outputs are
   bitwise the unprofiled step's);
-* calibration/agreement units plus the ``tools/compare_bench.py``
-  gates (``check_phase_profile``, the cross-backend refusal) — no jax.
+* calibration/agreement units — no jax.
 """
 
 import gzip
@@ -380,43 +379,3 @@ def test_check_agreement_semantics():
     prof = _profile_with({}, collectives=[(ida, 0.1), (outa, 0.1)])
     assert any("not a collective of the modeled" in v
                for v in pp.check_agreement(prof, sched))
-
-
-# ------------------------------------------------- compare_bench gates
-
-
-def test_check_phase_profile_gate():
-    from tools import compare_bench as cb
-
-    base = {"phase_profile": {"measured_serialized_fraction": 0.2,
-                              "violations": []}}
-    ok = {"phase_profile": {"measured_serialized_fraction": 0.25,
-                            "violations": []}}
-    regress = {"phase_profile": {"measured_serialized_fraction": 0.6,
-                                 "violations": []}}
-    broken = {"phase_profile": {"measured_serialized_fraction": 0.2,
-                                "violations": ["agreement: ..."]}}
-    assert cb.check_phase_profile(base, ok) == 0
-    assert cb.check_phase_profile(base, regress) == 1
-    assert cb.check_phase_profile(base, broken) == 1
-    # missing section while the baseline has one -> fail; both missing ok
-    assert cb.check_phase_profile(base, {}) == 1
-    assert cb.check_phase_profile({}, {}) == 0
-    # first record carrying the section: absolute checks only
-    assert cb.check_phase_profile({}, ok) == 0
-
-
-def test_check_env_backend_refusal():
-    from tools import compare_bench as cb
-
-    cpu = {"backend": "cpu", "device_count": 1}
-    tpu = {"backend": "tpu", "device_count": 16}
-    assert cb.check_env(cpu, dict(cpu)) == 0
-    assert cb.check_env(cpu, tpu) == 2          # backend AND count differ
-    assert cb.check_env(cpu, tpu, allow_mismatch=True) == 0
-    # env-block fallback for records predating the top-level stamp
-    old = {"env": {"backend": "tpu", "device_count": 16}}
-    assert cb.check_env(old, tpu) == 0
-    assert cb.check_env(old, cpu) == 2
-    # unstamped records keep comparing (pre-PR-2)
-    assert cb.check_env({}, tpu) == 0

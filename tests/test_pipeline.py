@@ -118,7 +118,6 @@ def test_microbatch_knobs_registered():
     # actually build a pipeline (the serialized baseline is the DEFAULT
     # schedule, not a pipelined_schedule degenerate)
     assert reg["DETPU_MICROBATCH"].default == "2"
-    assert "DETPU_MICROBATCH_BENCH" in reg
 
 
 def test_schedule_pipelined_string_actually_pipelines(monkeypatch):

@@ -5,8 +5,7 @@ Scenarios: probe timeout on a hung backend; dryrun_multichip failing on an
 unprobeable backend or a failed child and drilling on the CPU only when
 the backend has too few devices; bootstrap retry-then-succeed,
 retry-then-raise (cluster expected) and no join attempt on a single host;
-crash-surviving JSONL section records; bench killed mid-run keeping every
-completed section.
+crash-surviving JSONL section records.
 """
 
 import json
@@ -241,9 +240,10 @@ def test_section_recorder_survives_process_death(tmp_path):
         f"import os, sys; sys.path.insert(0, {_REPO!r})\n"
         "from distributed_embeddings_tpu.utils import runtime\n"
         f"rec = runtime.SectionRecorder({side!r})\n"
-        "runtime.run_section(rec, 'alpha', lambda: 1.5)\n"
+        "runtime.fault_point('alpha')\n"
+        "rec.record('alpha', ok=True, value=1.5)\n"
         "os.environ[runtime.FAULT_ENV] = 'die:beta'\n"
-        "runtime.run_section(rec, 'beta', lambda: 2.5)\n"
+        "runtime.fault_point('beta')\n"
         "rec.record('never_reached', ok=True)\n")
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=120)
@@ -256,67 +256,3 @@ def test_section_recorder_survives_process_death(tmp_path):
         f.write('{"section": "torn", "ok"')
     recs = runtime.SectionRecorder.load(side)
     assert [r["section"] for r in recs] == ["alpha"]
-
-
-def test_run_section_records_failure_and_returns_default(tmp_path):
-    rec = runtime.SectionRecorder(str(tmp_path / "s.jsonl"))
-
-    def boom():
-        raise RuntimeError("nope")
-
-    out = runtime.run_section(rec, "bad", boom, default="dflt", retries=1)
-    assert out == "dflt"
-    recs = runtime.SectionRecorder.load(rec.path)
-    assert recs[0]["section"] == "bad" and recs[0]["ok"] is False
-    assert recs[0]["attempts"] == 2
-
-
-def test_bench_killed_mid_run_leaves_parseable_sidecar(tmp_path):
-    """Acceptance: bench.py killed mid-run (die:bench.bf16, the second
-    section) leaves a parseable JSONL sidecar containing the device stamp
-    and every completed section (fp32)."""
-    side = str(tmp_path / "bench.partial.jsonl")
-    env = dict(os.environ)
-    env.update({
-        "JAX_PLATFORMS": "cpu",
-        "DETPU_BENCH_SMOKE": "1",
-        "DETPU_BENCH_SIDECAR": side,
-        "DETPU_FAULT": "die:bench.bf16",
-        "PYTHONPATH": _REPO,
-    })
-    proc = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py")],
-        env=env, cwd=_REPO, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 17, (proc.stdout, proc.stderr[-2000:])
-    recs = runtime.SectionRecorder.load(side)
-    by_name = {r["section"]: r for r in recs}
-    assert by_name["meta"]["value"]["backend"] == "cpu"
-    assert by_name["bench.fp32"]["ok"] is True
-    assert by_name["bench.fp32"]["value"] > 0
-    assert "final" not in by_name  # killed before completion
-
-
-def test_bench_failed_section_is_remembered_for_the_exit_code(
-        tmp_path, monkeypatch):
-    """A failed section still yields its default (the sections after it
-    run) but is recorded as failed and remembered: bench.main() exits 1
-    when the list is not empty. No retry hides the failure."""
-    monkeypatch.syspath_prepend(_REPO)
-    import bench
-
-    monkeypatch.setattr(bench, "_RECORDER", runtime.SectionRecorder(
-        str(tmp_path / "bench.partial.jsonl")))
-    monkeypatch.setattr(bench, "_FAILED_SECTIONS", [])
-    calls = []
-
-    def boom():
-        calls.append(1)
-        raise RuntimeError("section broke")
-
-    assert bench._guard("boom", boom, 0.0) == 0.0
-    assert bench._guard("fine", lambda: 3.5) == 3.5
-    assert bench._FAILED_SECTIONS == ["boom"] and len(calls) == 1
-    recs = {r["section"]: r for r in runtime.SectionRecorder.load(
-        str(tmp_path / "bench.partial.jsonl"))}
-    assert recs["bench.boom"]["ok"] is False
-    assert recs["bench.fine"]["ok"] is True

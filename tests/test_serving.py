@@ -772,29 +772,6 @@ def test_unavailable_is_typed_and_ranked_below_stale():
     assert (u.reason, u.outage_s, u.restarts) == ("worker_down", 1.5, 2)
 
 
-def test_compare_bench_serving_gate():
-    from tools import compare_bench as cb
-
-    base = {"metric": "x",
-            "serving": {"latency_p95_ms": 10.0,
-                        "steady_state_recompiles": 0}}
-
-    def cand(p95=10.0, rc=0):
-        return {"metric": "x",
-                "serving": {"latency_p95_ms": p95,
-                            "steady_state_recompiles": rc}}
-
-    assert cb.check_serving(base, cand()) == 0
-    assert cb.check_serving(base, cand(p95=10.9)) == 0   # within 10%
-    assert cb.check_serving(base, cand(p95=11.5)) == 1   # p95 ratchet
-    assert cb.check_serving(base, cand(rc=2)) == 1       # recompiles
-    # missing section vs a baseline that has it fails; both-missing and
-    # new-section-no-baseline pass (rounds legitimately add sections)
-    assert cb.check_serving(base, {"metric": "x"}) == 1
-    assert cb.check_serving({"metric": "x"}, {"metric": "x"}) == 0
-    assert cb.check_serving({"metric": "x"}, cand()) == 0
-
-
 def test_stats_surface():
     de, state, rt, clock = _build()
     rt.warmup(_tmpl())

@@ -126,39 +126,6 @@ def test_unstarted_supervisor_answers_typed_unavailable():
         s.close()
 
 
-# ------------------------------------------------- compare_bench gate
-
-
-def test_compare_bench_isolated_serving_gate():
-    from tools import compare_bench as cb
-
-    def rec(crashes=1, restarts=1, budget=1, conserved=1, rc=0,
-            inp99=8.0, oop99=14.0, rtfs=20.0):
-        return {"isolated_serving": {
-            "crashes": crashes, "restarts": restarts,
-            "budget_ok": budget, "conserved": conserved,
-            "steady_state_recompiles": rc,
-            "inproc_p99_ms": inp99, "oop_p99_ms": oop99,
-            "restart_to_first_served_ms": rtfs}}
-
-    base = rec()
-    assert cb.check_isolated_serving(base, rec()) == 0
-    assert cb.check_isolated_serving(base, rec(crashes=0)) == 1
-    assert cb.check_isolated_serving(base, rec(restarts=0)) == 1
-    assert cb.check_isolated_serving(base, rec(budget=0)) == 1
-    assert cb.check_isolated_serving(base, rec(conserved=0)) == 1
-    assert cb.check_isolated_serving(base, rec(rc=2)) == 1
-    # boundary overhead: 5x floor + 10ms slack
-    assert cb.check_isolated_serving(base, rec(oop99=49.0)) == 0
-    assert cb.check_isolated_serving(base, rec(oop99=51.0)) == 1
-    assert cb.check_isolated_serving(base, rec(rtfs=40_000.0)) == 1
-    # missing section vs a baseline that has it fails; both-missing and
-    # new-section-no-baseline pass
-    assert cb.check_isolated_serving(base, {}) == 1
-    assert cb.check_isolated_serving({}, {}) == 0
-    assert cb.check_isolated_serving({}, rec()) == 0
-
-
 # ----------------------------------------------------- one real worker
 
 

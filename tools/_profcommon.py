@@ -1,44 +1,35 @@
-"""Shared setup for the ``tools/profile_*.py`` microbenchmarks: the
-``sys.path`` insert, the capped Criteo-Kaggle vocab table, the
-repetition-slope timing helpers and the process set-up
-(:func:`ensure_backend`: compile cache, device line, observability hooks).
+"""Shared setup for the static audit tools (``tools/*_audit.py``,
+``tools/phase_profile.py``) and ``chip_smoke.py``: the ``sys.path``
+insert, the two published vocabulary vectors, the reference
+``DistributedEmbedding`` cases (:func:`build_case`) and the CPU pinning
+(:func:`force_cpu`, :func:`cpu_mesh`).
 
-A profile tool takes the chip in its own process — one process per chip,
-no probing child in front of it.
-
-Usage, at the top of a tool::
-
-    import _profcommon as pc
-    ...
-    if __name__ == "__main__":
-        pc.ensure_backend()
-        main(sys.argv[1:])
+``tests/test_tree_records.py`` holds both vectors to ``table_sizes`` of
+``benchmarks/configs/<name>.json``: the smoke test, the auditors and the
+benchmark's cells price the same vectors.
 """
 
 from __future__ import annotations
 
 import os
 import sys
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-# the bench's capped Criteo-Kaggle vocabs — the shapes every profile tool
-# times (kept here so the five tools cannot drift apart)
-CAP = 2_000_000
+# Criteo-Kaggle (MLPerf DLRM) vocab sizes, uncapped: 26 tables, 33.8M rows
+# (chip_smoke.py caps them at 2M rows; dlrm-kaggle runs them whole)
 CRITEO_KAGGLE_SIZES = [
     1460, 583, 10131227, 2202608, 305, 24, 12517, 633, 3, 93145, 5683,
     8351593, 3194, 27, 14992, 5461306, 10, 5652, 2173, 4, 7046547, 18, 15,
     286181, 105, 142572,
 ]
-CAP_SIZES = [min(s, CAP) for s in CRITEO_KAGGLE_SIZES]
 
 # Criteo-1TB (MLPerf DLRM) vocab sizes + the reference's "+1" convention
 # (its examples/dlrm/main.py loads model_size.json and adds 1): 26 tables,
 # ~187.8M rows total — the real shapes behind the ≥2M samples/s v5e-16
-# north star. Shared here so bench.py, the capacity auditor, and the
+# north star. Shared here so the capacity auditor and the
 # dress-rehearsal tooling price the SAME vector (they used to drift).
 CRITEO_1TB_SIZES = [s + 1 for s in [
     39884406, 39043, 17289, 7420, 20263, 3, 7120, 1543, 63, 38532951,
@@ -61,8 +52,8 @@ def build_case(name: str, world: int, batch: int):
     batch_tree, dense_params, loss_fn)`` with abstract (ShapeDtypeStruct)
     inputs — the shapes the static tools audit. Shared by
     ``tools/audit_step.py`` (jaxpr-level SPMD contract) and
-    ``tools/hlo_audit.py`` (optimized-HLO pass budgets) so both gates and
-    the profile tools cannot drift apart.
+    ``tools/hlo_audit.py`` (optimized-HLO pass budgets) so the gates
+    cannot drift apart.
 
     Cases: ``dense`` / ``ragged`` / ``row_sliced`` (the tier-1 shapes),
     ``pipelined`` — the dense shapes under ``pipelined_schedule(2)``,
@@ -232,60 +223,3 @@ def cpu_mesh(world: int):
         raise RuntimeError(
             f"host platform exposes {len(devs)} devices < {world}")
     return Mesh(np.array(devs[:world]), ("data",))
-
-
-def ensure_backend():
-    """Process set-up of a profile tool: place the compile cache, touch
-    the backend (no backend raises here), say which device answers, and
-    arm the observability hooks (recompile counter, ``DETPU_PROFILE_PORT``
-    server) so captured profiles carry the named scopes this repo's step
-    is annotated with. Returns ``jax.devices()``."""
-    from distributed_embeddings_tpu.utils import obs, runtime
-
-    runtime.ensure_compile_cache()
-    import jax
-
-    devs = jax.devices()
-    print(f"backend: {devs[0].platform} {devs[0].device_kind!r} "
-          f"x{len(devs)}", flush=True)
-    obs.install_compile_listener()
-    obs.maybe_start_server()
-    return devs
-
-
-def slope(make_fn, args, iters_hi: int = 3) -> float:
-    """Repetition-slope timing in ms: jit ``make_fn(1)`` and
-    ``make_fn(iters_hi)`` (K in-jit repetitions of the phase under test),
-    time both after compile, report the per-repetition slope — dispatch
-    constants cancel."""
-    import jax
-
-    f1 = jax.jit(make_fn(1))
-    fh = jax.jit(make_fn(iters_hi))
-    done = jax.block_until_ready
-    done(f1(*args))  # compile
-    done(fh(*args))
-    t0 = time.perf_counter(); done(f1(*args)); t1 = time.perf_counter()
-    done(fh(*args)); t2 = time.perf_counter()
-    return ((t2 - t1) - (t1 - t0)) / (iters_hi - 1) * 1e3
-
-
-def slope_donate(make_fn, args, iters_hi: int = 3) -> float:
-    """:func:`slope` with the FIRST argument donated and re-threaded
-    between calls — for phases that update a multi-GB slab in place
-    (without donation XLA copies the slab and the program OOMs). The
-    ``make_fn(k)`` body must return ``(scalar, slab)``."""
-    import jax
-
-    f1 = jax.jit(make_fn(1), donate_argnums=(0,))
-    fh = jax.jit(make_fn(iters_hi), donate_argnums=(0,))
-    state = {"args": args}
-
-    def run(f):
-        s, sl = jax.block_until_ready(f(*state["args"]))
-        state["args"] = (sl,) + state["args"][1:]
-
-    run(f1); run(fh)
-    t0 = time.perf_counter(); run(f1); t1 = time.perf_counter()
-    run(fh); t2 = time.perf_counter()
-    return ((t2 - t1) - (t1 - t0)) / (iters_hi - 1) * 1e3

@@ -20,7 +20,7 @@ from .. import Finding
 
 NAME = "env-registry"
 SCOPE = ("distributed_embeddings_tpu/**", "tools/**", "examples/**",
-         "bench.py", "__graft_entry__.py", "setup.py")
+         "__graft_entry__.py", "setup.py")
 EXCLUDE = ("distributed_embeddings_tpu/utils/envvars.py",)
 
 REGISTRY_PATH = "distributed_embeddings_tpu/utils/envvars.py"

@@ -3,11 +3,10 @@
 ``utils.runtime`` / ``utils.obs`` / ``utils.envvars`` hold the
 never-touch-a-backend-at-import contract: their counter/registry halves
 must work in processes that never load jax at all, and importing them must
-never risk initializing an accelerator backend. ``tools/compare_bench.py``
-and detlint itself promise the same ("runs anywhere, instantly"). This
-rule pins the contract: any module-scope ``import jax`` /
-``from jax... import`` in a scoped file is a finding — import it inside
-the function that needs it.
+never risk initializing an accelerator backend. detlint itself promises
+the same ("runs anywhere, instantly"). This rule pins the contract: any
+module-scope ``import jax`` / ``from jax... import`` in a scoped file is
+a finding — import it inside the function that needs it.
 """
 
 from __future__ import annotations
@@ -21,7 +20,6 @@ SCOPE = ("distributed_embeddings_tpu/utils/obs.py",
          "distributed_embeddings_tpu/utils/runtime.py",
          "distributed_embeddings_tpu/utils/envvars.py",
          "distributed_embeddings_tpu/utils/traceparse.py",
-         "tools/compare_bench.py",
          "tools/detlint/**")
 
 

@@ -34,7 +34,7 @@ import ast
 from .. import Finding
 
 NAME = "spawn-context"
-SCOPE = ("distributed_embeddings_tpu/**", "tools/**", "bench.py",
+SCOPE = ("distributed_embeddings_tpu/**", "tools/**",
          "__graft_entry__.py")
 
 MARKER = "spawn-ok:"

@@ -1,7 +1,7 @@
 """Shared model factory for the process-isolation drill + tests.
 
 Both sides of the process boundary — the trainer child in
-``tools/check_isolation.py`` / ``bench.py`` and the spawned
+``tools/check_isolation.py`` and the spawned
 :mod:`~distributed_embeddings_tpu.parallel.supervisor` serving worker —
 must build the SAME model at the SAME world size (the snapshot payload
 is the flattened parameter leaves; slab shapes carry the world dim), so

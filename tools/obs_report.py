@@ -16,9 +16,9 @@ Three ways in:
   (``obs.install_compile_listener`` delta over the post-warmup steps)
   and zero host callbacks in the audited jaxpr. Nonzero exit when any
   of that fails, so the target doubles as a gate.
-* ``python tools/obs_report.py --metrics BENCH.metrics.jsonl
+* ``python tools/obs_report.py --metrics run.metrics.jsonl
   [--telemetry run.telemetry.json] [--phases phase_profile.json]`` —
-  fuse existing artifacts (a bench sidecar, a resilient run's
+  fuse existing artifacts (a step-metrics sidecar, a resilient run's
   checkpoint-side telemetry flush, a ``tools/phase_profile.py --json``
   measured-phase artifact — or a raw ``DETPU_PROFILE_DIR`` trace
   capture, parsed jax-free) without running anything.
