@@ -195,18 +195,35 @@ def dedup_zero_contracts(reason: str) -> List[PassBudget]:
             for k in ("sort", "scatter", "cumsum", "gather")]
 
 
+def apply_contracts() -> List[PassBudget]:
+    """The sparse apply's budget. A small table's cotangents are summed as
+    ``onehot(ids)^T @ cotangents`` (``parallel/apply.py:small_table_sums``):
+    the ``small_sum`` scope holds no sort, scatter, cumsum or gather, or the
+    sums are back on a row path. And the sweep's forms are ONE scatter a
+    width slab, whatever joined its stream."""
+    why = ("small tables are summed on the MXU: a row operation there is "
+           "the scatter's stream again")
+    out = [PassBudget("small_sum", k, max_passes=0, reason=why)
+           for k in ("sort", "scatter", "cumsum", "gather")]
+    out += [PassBudget("scatter_" + form, "scatter", max_passes=1,
+                       per_path=True, reason="ONE scatter a width slab")
+            for form in ("sort_fused", "unsorted")]
+    return out
+
+
 def default_contracts(emb_optimizer=None) -> List[PassBudget]:
     """Config-independent contracts for a hybrid train step census.
 
-    Today that is the dedup budget: when the sparse optimizer declares
-    ``needs_dedup=False`` (and ``DETPU_SGD_DEDUP`` does not force the pass
-    back in), the compiled dedup phase must be empty. Shape-dependent
+    That is :func:`apply_contracts` and the dedup budget: when the sparse
+    optimizer declares ``needs_dedup=False`` (and ``DETPU_SGD_DEDUP`` does
+    not force the pass back in), the compiled dedup phase must be empty.
+    Shape-dependent
     budgets (gathers per lookup group, pinned dedup counts for stateful
     optimizers) belong to the caller — ``tools/hlo_audit.py`` pins them
     for the reference configurations."""
     from ..parallel.optimizers import sgd_dedup_forced
 
-    out: List[PassBudget] = []
+    out = apply_contracts()
     if emb_optimizer is not None and not getattr(
             emb_optimizer, "needs_dedup", True) and not sgd_dedup_forced():
         out += dedup_zero_contracts(

@@ -443,6 +443,10 @@ class PlanReport:
     out_pad_frac: float       # dead-column fraction of the padded exchange
     violations: List[str] = dataclasses.field(default_factory=list)
     n_streaming_tables: int = 0  # dynamic-vocab tables in the plan
+    # small-table slots the backward sums into dense blocks, and the stream
+    # rows (one an id) that they would have sent (``GroupSpec.block``)
+    dense_slots: int = 0
+    dense_rows: int = 0
 
     @property
     def ok(self) -> bool:
@@ -500,13 +504,17 @@ class PlanReport:
                 f"| {_gb(r.opt_state_bytes):.3f} "
                 f"| {_gb(r.a2a_buffer_bytes):.3f} "
                 f"| {_gb(r.total_bytes):.3f} | {r.hbm_frac:.1%} |")
-        lines += ["", "| slab | phys shape | rank GB | scatter |",
-                  "|---|---|---:|---|"]
+        lines += ["", "| slab | phys shape | rank GB | stream rows "
+                  "| scatter |", "|---|---|---:|---:|---|"]
         for s in self.slabs:
             lines.append(
                 f"| w{s.width} | [{s.phys_rows}, {s.phys_width}] "
-                f"| {_gb(s.rank_bytes):.3f} "
+                f"| {_gb(s.rank_bytes):.3f} | {s.stream_rows} "
                 f"| {s.scatter_form or '-'} {s.scatter_ms:.1f} ms |")
+        if self.dense_slots:
+            lines += ["", f"{self.dense_slots} small-table slot(s) summed "
+                      f"into dense blocks: {self.dense_rows} rows a step "
+                      "left the streams"]
         if self.violations:
             lines += ["", "violations:"] + [f"* {v}" for v in self.violations]
         return "\n".join(lines)
@@ -787,12 +795,11 @@ def audit_plan(target,
             snapshot_bytes=snap_bytes,
             shm_region_bytes=shm_bytes))
 
-    # update rows a step scatters into each width slab on one rank: every
-    # source's block of every slot (parallel/apply.py builds the same streams)
+    # update rows a step scatters into each width slab on one rank, by the
+    # plan's own count (parallel/apply.py builds these streams): a row an id
+    # of every source's block, or a small table's dense block
     stream = {w: 0 for w in geom.widths}
-    for g in plan.groups:
-        per_source = b_local * g.n * g.hot if g.kind == "d" else g.n * g.hot
-        stream[g.width] += world * per_source
+    stream.update(plan.stream_rows())
     slabs = []
     for w in geom.widths:
         rb = geom.phys_cap[w] * geom.phys_w[w] * p_isz
@@ -826,7 +833,9 @@ def audit_plan(target,
         n_sliced_tables=n_sliced,
         n_groups=len(plan.groups), l_max=plan.l_max, s_max=plan.s_max,
         groups=[{"kind": g.kind, "width": g.width, "hot": g.hot,
-                 "slots": g.n, "block_len": g.blen} for g in plan.groups],
+                 "slots": g.n, "block_len": g.blen,
+                 "block_rows": list(g.block)} for g in plan.groups],
+        dense_slots=plan.dense_slots, dense_rows=plan.dense_rows,
         per_rank=per_rank, slabs=slabs,
         id_a2a_bytes_per_step=int(id_a2a),
         out_a2a_bytes_per_step=int(out_a2a),

@@ -18,6 +18,7 @@ the monolith's methods produced.
 
 from __future__ import annotations
 
+import itertools
 from typing import Dict, List, Optional
 
 import jax
@@ -111,6 +112,48 @@ def sparse_apply_gradients(de, params, opt_state, residuals, out_grads,
                                optimizer, lr, scale, enable=enable)
 
 
+def small_table_sums(g, ids4, grads, live, roff, sent):
+    """The stream of a small-table group (``GroupSpec.block``): per slot ONE
+    dense block, rows ``roff .. roff + V - 1`` holding ``onehot(ids)^T @
+    cotangents``, in place of a row an id. Slots of equal ``V`` share one
+    batched matmul; the one-hot (a count matrix where ``hot > 1``) is exact
+    in the cotangents' dtype and is the matmul's fused producer, never a
+    buffer, and the sums accumulate in float32 and round once: what a
+    dedup of the slot's rows would have made of them.
+
+    ``ids4 [world, n, b, hot]`` are table-local ids, ``grads [world, b, n,
+    w]`` the slots' cotangent rows (``mean`` already divided), ``live [n]``
+    this rank's table rows a slot (0: dead slot). A block row past ``live``
+    or that no id of the step touched goes to ``sent``: out-of-range ids and
+    dead slots train nothing, and a lazy optimizer leaves an untouched row
+    and its state alone. Returns ``(ids [sum V], vals [sum V, w])``."""
+    world, n, b, hot = ids4.shape
+    w = grads.shape[-1]
+    precision = (lax.Precision.HIGHEST if grads.dtype == jnp.float32
+                 else None)  # 0/1 times float32 stays float32
+    ids_l, vals_l = [], []
+    k0 = 0
+    for v, run in itertools.groupby(g.block):
+        k1 = k0 + len(list(run))
+        # [slots, 1, world * b, hot] against the block's row numbers
+        loc = ids4[:, k0:k1].transpose(1, 0, 2, 3).reshape(
+            k1 - k0, 1, world * b, hot)
+        row = lax.broadcasted_iota(loc.dtype, (1, v, 1, 1), 1)
+        eq = loc == row
+        sums = lax.dot_general(
+            jnp.sum(eq, axis=3, dtype=grads.dtype),   # [slots, v, world * b]
+            grads[:, :, k0:k1].reshape(world * b, k1 - k0, w),
+            (((2,), (0,)), ((0,), (1,))), precision=precision,
+            preferred_element_type=jnp.float32)       # [slots, v, w]
+        at = row.reshape(1, v)
+        keep = jnp.any(eq, axis=(2, 3)) & (at < live[k0:k1, None])
+        ids_l.append(jnp.where(keep, at + roff[k0:k1, None], sent
+                               ).reshape(-1))
+        vals_l.append(sums.astype(grads.dtype).reshape(-1, w))
+        k0 = k1
+    return jnp.concatenate(ids_l), jnp.concatenate(vals_l)
+
+
 def cotangent_width_streams(de, residuals, out_grads, fallback_dtype=None,
                             tag: str = ""):
     """The sparse backward MINUS the optimizer scatter: route the output
@@ -190,24 +233,6 @@ def cotangent_width_streams(de, residuals, out_grads, fallback_dtype=None,
                         (world, b, g.col + g.n * g.width))
         gsl = gsl.reshape(world, b, g.n, g.width)
         if g.kind == "d":
-            # b-major stream: the value rows are then exactly the
-            # [world, b, n, w] grad layout — a FREE reshape of the
-            # exchange row instead of a materialized transpose (the
-            # [b, n*w] -> [n, b, w] copy + cast measured ~26 ms at the
-            # DLRM headline shapes); only the small int id tensor
-            # transposes. The optimizer sorts the stream anyway, so
-            # stream order is free to choose (docs/perf_tpu.md r4).
-            ids4 = region.reshape(world, g.n, b, g.hot
-                                  ).transpose(0, 2, 1, 3)
-            if rbase is not None:  # row-sliced slots: range-local ids
-                ids4 = ids4 - rbase[None, None, :, None]
-            # out-of-range ids were clipped in the forward (safety net)
-            # but are dropped here: a bad id trains nothing (see the
-            # dist_embedding module docstring contract)
-            ok = (ids4 >= 0) & (ids4 < rows[None, None, :, None])
-            if valid is not None:
-                ok = ok & (valid[None, None, :, None] > 0)
-            ids = jnp.where(ok, ids4 + roff[None, None, :, None], sent)
             gb = gsl
             if g.hot > 1 and any_mean:
                 if all_mean:
@@ -216,6 +241,32 @@ def cotangent_width_streams(de, residuals, out_grads, fallback_dtype=None,
                     mean = de._plan_row(plan.mean[gi], my)
                     gb = jnp.where(mean[None, None, :, None] > 0,
                                    gsl / g.hot, gsl)
+            ids4 = region.reshape(world, g.n, b, g.hot)
+            if rbase is not None:  # row-sliced slots: range-local ids
+                ids4 = ids4 - rbase[None, :, None, None]
+        if g.block:
+            live = rows if valid is None else jnp.where(valid > 0, rows, 0)
+            # under the scope the width's scatter will run in, so that a
+            # profile counts the sums to the apply and names them
+            with obs.scope(f"sparse_apply_{_wkey(g.width)}"), \
+                    obs.scope("small_sum"):
+                ids, vals = small_table_sums(g, ids4, gb, live, roff, sent)
+        elif g.kind == "d":
+            # b-major stream: the value rows are then exactly the
+            # [world, b, n, w] grad layout — a FREE reshape of the
+            # exchange row instead of a materialized transpose (the
+            # [b, n*w] -> [n, b, w] copy + cast measured ~26 ms at the
+            # DLRM headline shapes); only the small int id tensor
+            # transposes. The optimizer sorts the stream anyway, so
+            # stream order is free to choose (docs/perf_tpu.md r4).
+            ids4 = ids4.transpose(0, 2, 1, 3)
+            # out-of-range ids were clipped in the forward (safety net)
+            # but are dropped here: a bad id trains nothing (see the
+            # dist_embedding module docstring contract)
+            ok = (ids4 >= 0) & (ids4 < rows[None, None, :, None])
+            if valid is not None:
+                ok = ok & (valid[None, None, :, None] > 0)
+            ids = jnp.where(ok, ids4 + roff[None, None, :, None], sent)
             vals = jnp.broadcast_to(
                 gb[:, :, :, None, :],
                 (world, b, g.n, g.hot, g.width))

@@ -97,6 +97,11 @@ SCATTER_FORMS = ("sort_fused", "unsorted", "dedup_rows")
 # 4.04 ms a GiB fit the scatter fusion at all four (rows, slab) pairs of the
 # benchmark (58.4, 135.8, 49.4 and 7.9 ms read; 58.4, 136.1, 49.2, 8.1 fitted).
 # Row at a time alone: 24.4 ms for 328 k rows, 123.9 for 1.70 M, 288 for 4 M.
+# The sweep's row term is linear in the stream's rows whatever they hold, so a
+# small table's ids cost what a huge one's do; :func:`sums_densely` takes such
+# a slot off the stream for a block of ``onehot(ids)^T @ cotangents`` at
+# ``_ONEHOT_ELEM_NS`` an element of the one-hot (PERF.md section 6, PR 33: the
+# one-hot cell's sweep 58.4 -> 43.1 ms for 1.70 M -> 0.68 M rows, the sums 3.1).
 _SWEEP_NS = (15.2, 4.04e6 / 2 ** 30)
 _RMW_ROW_NS = 75.0
 _SCATTER_NS = {
@@ -114,6 +119,54 @@ _SCATTER_NS = {
 _XLA_SWEEPS_BELOW_BYTES_A_ROW = 1300
 # distinct rows a step of the row-at-a-time loop; 8 k to 64 k read the same
 _RMW_CHUNK = 8192
+# One element of a small table's one-hot in the backward's dense sum
+# (``parallel/apply.py``: the compare, the convert, its multiply-add on the
+# MXU and its part of the touched-rows reduce): ``small_sum`` read 3.086 ms a
+# step for 21 120 block rows x 65 536 samples in the one-hot cell (PERF.md
+# section 6, PR 33). A block of 6 800 rows then costs what its 65 536 ids
+# cost the sweep.
+_ONEHOT_ELEM_NS = 2.23e-3
+# a block holds whole tiles of the matmul's output rows
+_BLOCK_TILE = 128
+# A slot of padding a sample, in the forward and the exchanges: the gather
+# reads 12.4 ns a row (``lookup_ms`` 8.93 for 11 slots x 65 536 rows, four
+# chips) and the three exchanges 6.5 ns a slot of 128 columns
+# (``exchange_ms`` 3.07 for 928 columns; ledger, PR 32).
+_SLOT_NS = 18.9
+# a count matrix of hotness past this is not exact in bfloat16
+_COUNT_EXACT = 256
+
+
+def block_rows(table_rows: int) -> int:
+    """Rows of the dense block a table of ``table_rows`` rows is summed into."""
+    return -(-table_rows // _BLOCK_TILE) * _BLOCK_TILE
+
+
+def small_sum_ns(blocks, ids: int) -> float:
+    """What it costs to sum slots of ``ids`` ids a step each into dense
+    blocks of ``blocks`` rows: the one-hots' elements, and the blocks' rows
+    in the scatter's stream."""
+    return sum(blocks) * (_ONEHOT_ELEM_NS * ids
+                          + _SCATTER_NS["sort_fused"][0])
+
+
+def padded_slots_ns(slots: int, samples: int) -> float:
+    """What ``slots`` more slots in a plan's layout cost a step of
+    ``samples`` samples before the backward: a gathered row and a slot's
+    columns in each exchange, dead or not."""
+    return _SLOT_NS * slots * samples
+
+
+def sums_densely(table_rows: int, ids: int, hot: int = 1) -> bool:
+    """Whether a dense slot that sends ``ids`` ids a step (``hot`` a sample)
+    into a table of ``table_rows`` rows should leave the scatter's stream:
+    the backward then sums its cotangents as ``onehot(ids)^T @ cotangents``
+    into one block of the table's rows, and the stream gets the block's rows
+    in place of a row an id. It should where that costs less than the ids
+    cost the cheapest sweep."""
+    return (hot <= _COUNT_EXACT
+            and small_sum_ns([block_rows(table_rows)], ids)
+            < scatter_ns("sort_fused", ids, 0))
 
 
 def scatter_ns(form: str, rows: int, slab_bytes: int) -> float:

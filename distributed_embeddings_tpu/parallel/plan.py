@@ -18,7 +18,13 @@ id-exchange block and the output-exchange row are laid out as a sequence of
   static-capacity CSR feature: ``c`` values + ``b`` lengths in the block,
   ``w`` output columns;
 * ``n`` is the max slot count over ranks — ranks with fewer tables of that
-  shape pad with dead slots (zero ids in, never-read columns out).
+  shape pad with dead slots (zero ids in, never-read columns out);
+* a dense group is one of two **size classes**: the slots of tables small
+  enough that the backward sums their cotangents as ``onehot(ids)^T @
+  cotangents`` (``optimizers.sums_densely``: the cost rule) meet in a group
+  of their own, each rank's slots largest first, and its ``GroupSpec.block``
+  holds every slot's block rows. The forward, the exchanges and the serve
+  program treat it as any dense group.
 
 What *differs* per rank — which table a slot reads (row count, slab row
 offset), its combiner, whether the slot is live — is carried in small
@@ -43,6 +49,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import optimizers
+
 
 @dataclasses.dataclass(frozen=True)
 class GroupSpec:
@@ -55,6 +63,19 @@ class GroupSpec:
     blen: int    # ints one slot occupies per source block
     goff: int    # region start within the [l_max] id block
     col: int     # region start within the [s_max] output row
+    #: small-table class only: per slot, the rows of the dense block its
+    #: cotangents are summed into (the slot's largest table over the ranks,
+    #: in whole tiles); empty where the slots ride the scatter's stream
+    block: Tuple[int, ...] = ()
+
+    def stream_rows(self, world: int, b: int) -> int:
+        """Update rows the group puts into its width's stream a step: a
+        block's rows, else one row an id (ragged: a row a capacity slot)."""
+        if self.block:
+            return sum(self.block)
+        per_source = (b * self.n * self.hot if self.kind == "d"
+                      else self.n * self.hot)
+        return world * per_source
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,6 +135,42 @@ class ExchangePlan:
     def out_width(self, inst: InstanceSpec) -> int:
         return self.groups[inst.group].width * inst.num_slots
 
+    @property
+    def world(self) -> int:
+        return self.rows[0].shape[0] if self.rows else 1
+
+    def stream_rows(self) -> Dict[int, int]:
+        """Per width, the update rows a step scatters into the slab on one
+        rank (``parallel/apply.py`` builds these streams)."""
+        out: Dict[int, int] = {}
+        for g in self.groups:
+            out[g.width] = (out.get(g.width, 0)
+                            + g.stream_rows(self.world, self.b))
+        return out
+
+    @property
+    def dense_slots(self) -> int:
+        """Slots whose cotangents are summed into a dense block."""
+        return sum(len(g.block) for g in self.groups)
+
+    @property
+    def dense_rows(self) -> int:
+        """Stream rows a step those slots would have sent (one an id)."""
+        return sum(self.world * self.b * g.hot * len(g.block)
+                   for g in self.groups)
+
+
+def _n_slots(insts) -> int:
+    return sum(len(entries) for _, _, entries in insts)
+
+
+def _blocks(insts_by_rank) -> Tuple[int, ...]:
+    """Per slot, the block rows of the largest table a rank has there."""
+    rows = [[e[0] for _, _, entries in insts for e in entries]
+            for insts in insts_by_rank]
+    return tuple(optimizers.block_rows(max(r[k] for r in rows if k < len(r)))
+                 for k in range(max(map(len, rows))))
+
 
 def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
                encs: Sequence[tuple], b: int) -> ExchangePlan:
@@ -129,9 +186,10 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
       b: per-shard batch size.
     """
     world = strategy.world_size
-    # pass 1: per-rank slot lists per group key, in worker order
-    key_slots: Dict[tuple, List[list]] = {}
-    inst_raw = []  # (input_id, rank, key, slot0, num_slots)
+    # pass 1: per-rank instance lists per group key, in worker order; an
+    # instance is (position in worker order, input, the entries of its slots)
+    key_insts: Dict[tuple, List[list]] = {}
+    pos = 0
     for r in range(world):
         for j, i in enumerate(strategy.input_ids_list[r]):
             m = strategy.local_map_list[r][j]
@@ -150,14 +208,18 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
             if kind == "d":
                 if comb:
                     # N-D inputs: one hotness-`param` slot per lead position
-                    key = ("d", w, param)
+                    hot = param
                     entries = [(rows, roff, 1.0,
                                 1.0 if comb == "mean" else 0.0, rbase, rsl)
                                ] * nslots
                 else:
-                    key = ("d", w, 1)
+                    hot = 1
                     entries = [(rows, roff, 1.0, 0.0, rbase, rsl)
                                ] * (param * nslots)
+                # the size class: 1 where the backward sums the slot's
+                # cotangents into a dense block (sorts behind class 0)
+                key = ("d", w, hot, int(optimizers.sums_densely(
+                    rows, world * b * hot, hot)))
             else:
                 if comb is None:
                     # without this, a combiner-less table would silently get
@@ -171,24 +233,50 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
                 # separately — their slots are one capacity longer)
                 entries = [(rows, roff, 1.0,
                             1.0 if comb == "mean" else 0.0, rbase, rsl)]
-            slots = key_slots.setdefault(key, [[] for _ in range(world)])
-            inst_raw.append((i, r, key, len(slots[r]), len(entries)))
-            slots[r].extend(entries)
+            key_insts.setdefault(key, [[] for _ in range(world)]
+                                 )[r].append((pos, i, entries))
+            pos += 1
+
+    # The small class of a (width, hotness): each rank's slots largest table
+    # first, so that a slot's block (the largest table any rank has there)
+    # is tight. Two classes pad each to its fullest rank, so the class
+    # stands only where the step's work is then less than with one group.
+    for k in [k for k in key_insts if k[0] == "d" and k[3]]:
+        small = [sorted(insts, key=lambda inst: -inst[2][0][0])
+                 for insts in key_insts[k]]
+        large = key_insts.get(k[:3] + (0,), [[] for _ in range(world)])
+        n_large = max(_n_slots(insts) for insts in large)
+        n_one = max(_n_slots(x) + _n_slots(y) for x, y in zip(large, small))
+        n_small = max(_n_slots(insts) for insts in small)
+        ids = world * b * k[2]
+        a_row = optimizers.scatter_ns("sort_fused", ids, 0)
+        if (optimizers.small_sum_ns(_blocks(small), ids) + a_row * n_large
+                + optimizers.padded_slots_ns(n_large + n_small - n_one,
+                                             world * b)
+                < a_row * n_one):
+            key_insts[k] = small
+        else:  # one group, in worker order
+            del key_insts[k]
+            key_insts[k[:3] + (0,)] = [sorted(x + y)
+                                       for x, y in zip(large, small)]
 
     # pass 2: deterministic group order, cumulative offsets, plan tensors
-    keys = sorted(key_slots)
-    gidx = {k: g for g, k in enumerate(keys)}
+    keys = sorted(key_insts)
     groups = []
+    inst_raw = []  # (position, input_id, rank, group, slot0, num_slots)
     rows_l, roff_l, valid_l, mean_l, rbase_l, rsl_l = [], [], [], [], [], []
     goff = col = 0
-    for k in keys:
-        slots = key_slots[k]
-        kind, w, hp = k
+    for gi, k in enumerate(keys):
+        kind, w, hp = k[:3]
+        small = kind == "d" and k[3]
+        slots: List[list] = []
+        for r, insts in enumerate(key_insts[k]):
+            slots.append([])
+            for at, i, entries in insts:
+                inst_raw.append((at, i, r, gi, len(slots[r]), len(entries)))
+                slots[r].extend(entries)
         n = max(len(s) for s in slots)
         blen = {"d": b * hp, "r": hp + b, "rw": 2 * hp + b}[kind]
-        groups.append(GroupSpec(kind, w, hp, n, blen, goff, col))
-        goff += n * blen
-        col += n * w
         rows_a = np.ones((world, n), np.int32)
         roff_a = np.zeros((world, n), np.int32)
         val_a = np.zeros((world, n), np.float32)
@@ -200,6 +288,10 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
                 rows_a[r, kk], roff_a[r, kk] = tr, to
                 val_a[r, kk], mn_a[r, kk] = tv, tm
                 rb_a[r, kk], rs_a[r, kk] = trb, trs
+        block = _blocks(key_insts[k]) if small else ()
+        groups.append(GroupSpec(kind, w, hp, n, blen, goff, col, block))
+        goff += n * blen
+        col += n * w
         rows_l.append(rows_a)
         roff_l.append(roff_a)
         valid_l.append(val_a)
@@ -207,8 +299,9 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
         rbase_l.append(rb_a)
         rsl_l.append(rs_a)
 
-    instances = tuple(
-        InstanceSpec(i, r, gidx[k], s0, ns) for i, r, k, s0, ns in inst_raw)
+    # worker order (rank, then the rank's inputs): what the unpack of the
+    # output exchange and the backward's zip with worker grads walk
+    instances = tuple(InstanceSpec(*raw[1:]) for raw in sorted(inst_raw))
     return ExchangePlan(
         b=b, groups=tuple(groups), instances=instances,
         l_max=max(goff, 1), s_max=max(col, 1),

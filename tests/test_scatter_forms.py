@@ -169,7 +169,9 @@ def test_form_is_in_the_lowered_steps_scope_names():
     text = step.lower(state, cats, jnp.zeros((batch, 1), jnp.float32)
                       ).as_text(debug_info=True)
     slab = state.emb_params["w128"]
-    form = opt.scatter_form(batch * len(configs),
+    plan, = de._plan_cache.values()
+    assert plan.stream_rows() == {128: batch * len(configs) // world}
+    form = opt.scatter_form(plan.stream_rows()[128],
                             slab[0].size * slab.dtype.itemsize)
     assert f"detpu/sparse_apply_w128/detpu/scatter_{form}" in text
     others = [f for f in opt.SCATTER_FORMS if f != form]

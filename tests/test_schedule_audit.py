@@ -319,8 +319,7 @@ def test_schedule_declaration_check_against_compiled_graph():
 # --------------------------------------------- the real compiled step
 
 
-@pytest.fixture(scope="module")
-def real_step_report():
+def _real_step_report():
     from tools._profcommon import build_case
 
     import jax
@@ -334,6 +333,17 @@ def real_step_report():
         mesh=mesh, lr_schedule=0.3, dense_params=dense_params,
         with_metrics=False, nan_guard=True, label="test/dense8")
     return de, rep
+
+
+@pytest.fixture(scope="module")
+def real_step_report():
+    """The unpipelined step with every slot on the scatter's stream: the
+    baseline the contracts were written against."""
+    from distributed_embeddings_tpu.parallel import optimizers as opt
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(opt, "sums_densely", lambda *a, **k: False)
+        return _real_step_report()
 
 
 def test_real_step_baseline_serialized_a2a_chain(real_step_report):
@@ -358,6 +368,24 @@ def test_real_step_baseline_serialized_a2a_chain(real_step_report):
     assert "lookup_" in path_phases
     assert PHASE_OUT_EXCHANGE in path_phases
     assert "grad_all_to_all" in path_phases
+
+
+def test_small_table_sums_read_ids_beside_the_exchanges():
+    """At this batch every table of the case is small, so the backward sums
+    each slot's cotangents into a dense block. Which block rows an id
+    touched is read off the received ids alone: a chain independent of the
+    activation and cotangent exchanges, which on these toy payloads
+    outweighs them (``tools/schedule_audit.py`` declares it for the case).
+    The id exchange, which everything follows, stays serialized."""
+    de, rep = _real_step_report()
+    plan, = de._plan_cache.values()
+    assert plan.dense_slots
+    a2a = {c.phase_leaf: c for c in rep.collectives if c.op == "all-to-all"}
+    assert a2a["id_all_to_all"].classification == "serialized"
+    for leaf in ("out_all_to_all", "grad_all_to_all"):
+        by = a2a[leaf].independent_by_phase
+        assert any(p.endswith("small_sum") and ns > 0
+                   for p, ns in by.items()), by
 
 
 def test_real_step_fake_overlap_schedule_fails(real_step_report):

@@ -82,15 +82,25 @@ def audit_case(name: str, world: int, batch: int, opt_name: str):
         # classify overlappable — the ROADMAP item 2 acceptance this
         # gate certifies
         contracts = sa.declared_overlap_contracts(de.schedule)
-    elif name == "streaming":
-        # the auditor's first real finding: the staged slot-map/sketch
-        # transitions branch off the received ids and are consumed only
-        # at commit — a genuine independent compute chain next to the
-        # activation/cotangent exchanges. The id exchange stays
+    elif name in ("streaming", "dense", "row_sliced"):
+        # Chains that branch off the received ids and meet the step again
+        # only at the apply or the commit: genuine independent compute
+        # next to the activation/cotangent exchanges, which on the audit
+        # shapes outweighs their toy payloads. The id exchange stays
         # serialized (everything downstream depends on it).
-        why = ("streaming admission staging (slot-map/sketch "
-               "transitions) is independent of this exchange — the "
-               "overlap candidate a pipelined step can exploit")
+        if name == "streaming":
+            # the auditor's first real finding: the staged slot-map/sketch
+            # transitions are consumed only at commit
+            why = ("streaming admission staging (slot-map/sketch "
+                   "transitions) is independent of this exchange — the "
+                   "overlap candidate a pipelined step can exploit")
+        else:
+            # every table of these cases is small at this batch, so the
+            # backward sums each slot's cotangents into a dense block
+            # (parallel/apply.py:small_table_sums); which block rows an id
+            # touched is read off the received ids alone
+            why = ("the small-table sums' touched-rows reduce reads only "
+                   "the received ids and is independent of this exchange")
         contracts = [
             sa.ScheduleContract("id_all_to_all", expect="serialized",
                                 on_critical_path=True,
