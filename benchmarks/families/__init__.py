@@ -24,6 +24,22 @@ both kinds
     reader ``roofline``, and ``FLOPS[name](config) -> FLOP a sample``, for
     the reader ``mfu``, by the name in the metric's file.
 
+    Counters: what the program counts with ``obs.counter_inc`` (a family's
+    adapter half may bump one of its own there, in the host wrapper of its
+    step or of its runtime) the runner reads through ``system.counters()``
+    as the window opens and closes, and every counter's rise is in
+    ``ctx["counters"]``; of a serving cell every number of ``rt.stats()`` is
+    in ``ctx["stats"]``. A metric file reads either by name:
+    ``{"reader": "value", "group": "counters" | "stats", "key": ...}``.
+    The configuration file's keys are the family's to read, but for those
+    that the harness reads itself: ``family`` (this look-up), ``chips`` (1 or
+    4, the ``chips`` of every cell that runs it), ``reduced`` (the keys cut
+    from the source's values, the list of the ``configs`` entry) and, where
+    that is not empty, ``published`` (the source's own value of each reduced
+    key) and ``deployment`` (over how many chips each layer is divided, and
+    how). ``check_config_entry`` of
+    ``tests/benchmark/test_benchmark_manifest.py`` holds every file to it.
+
 ``"kind": "train"``
     ``train_batches(config, traffic, seed)``: the host batches of a seed.
     ``stage(built, batch)``: one batch on the device, the tuple of

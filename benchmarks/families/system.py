@@ -12,8 +12,6 @@ import jax
 from distributed_embeddings_tpu.parallel import serving as serving_mod
 from distributed_embeddings_tpu.utils import obs, runtime
 
-compile_count = lambda: obs.counters().get("recompiles", 0)  # noqa: E731
-
 
 def ensure_compile_cache() -> str:
     """The persistent compile cache at the program's fixed path inside the
@@ -26,6 +24,9 @@ def ensure_compile_cache() -> str:
 
 
 install_compile_listener = obs.install_compile_listener
+# a snapshot of every process counter of the program (``obs.counter_inc``):
+# the runner takes one as the window opens and one as it closes
+counters = obs.counters
 Request = serving_mod.Request
 Served = serving_mod.Served
 

@@ -71,6 +71,14 @@ def _memory_peak(devices) -> int:
     return max((s or {}).get("peak_bytes_in_use", 0) for s in stats)
 
 
+def _window_counters(opened: dict) -> dict:
+    """What every counter of the program rose by since the snapshot
+    ``opened``: ``ctx["counters"]``, which a metric file reads by name
+    (``{"reader": "value", "group": "counters", "key": ...}``). A counter
+    that a family bumps in its own wrappers is among them."""
+    return {k: v - opened.get(k, 0) for k, v in system.counters().items()}
+
+
 def _metric_values(cell, trace: bool, e2e: dict, ctx: dict, rehearse: bool):
     out = {}
     if not trace:
@@ -143,17 +151,17 @@ def _train(cell, seed, seconds, capture, ctx, t_start, hooks):
     laps.lap("first three steps and their read-outs")
     laps.say()
     per_step = int(fam.samples_per_step(cfg, tr))
-    compiles0 = system.compile_count()
+    opened = system.counters()
     e2e = {"setup_s": time.perf_counter() - t_start}
     with _HostWatch() as host, capture or contextlib.nullcontext():
         sps, n, dt, state, losses, stalls = train.window(
             step, state, staged, per_step, seconds, train.CHECK_STEPS,
             span=capture.span if capture else None)
+    counters = _window_counters(opened)
+    counters["compiles_in_window"] = counters.get("recompiles", 0)
     e2e["samples_per_s"] = sps
     ctx["memory_peak_bytes"] = _memory_peak(jax.devices()[:cell.chips])
-    ctx.update(window_s=dt, steps=n, samples=n * per_step,
-               counters={"compiles_in_window":
-                         system.compile_count() - compiles0})
+    ctx.update(window_s=dt, steps=n, samples=n * per_step, counters=counters)
     if capture:
         ctx["work"] = fam.step_work(cfg, tr, batches)
     del state, staged
@@ -214,6 +222,7 @@ def _serve(cell, seed, seconds, capture, ctx, t_start, hooks):
     gc.freeze()
     laps.lap("collector")
     laps.say()
+    opened = system.counters()
     e2e = {"setup_s": time.perf_counter() - t_start}
     try:
         with _HostWatch() as host, capture or contextlib.nullcontext():
@@ -222,6 +231,8 @@ def _serve(cell, seed, seconds, capture, ctx, t_start, hooks):
                 span=capture.span if capture else contextlib.nullcontext)
     finally:
         gc.unfreeze()
+    counters = _window_counters(opened)
+    counters["serve_recompiles"] = rt.steady_recompiles()
     if "results" in hooks:
         hooks["results"](results)
     lat = serve.latencies_ms(schedule.due_s, t_sub, results)
@@ -239,7 +250,10 @@ def _serve(cell, seed, seconds, capture, ctx, t_start, hooks):
     ctx.update(
         window_s=max(t_last, float(schedule.due_s[-1])),
         samples=int(sizes[served].sum()), flushes=int(stats["flushes"]),
-        counters={"serve_recompiles": rt.steady_recompiles()},
+        counters=counters,
+        # every number of the runtime's own summary, under the runtime's name
+        stats={k: v for k, v in stats.items()
+               if isinstance(v, (int, float)) and not isinstance(v, bool)},
         ratios={"serve_pad_fraction": float(stats["pad_fraction"])},
         spans={
             "queue_wait_ms": [results[i].spans["queue_wait_ms"] for i in served],
