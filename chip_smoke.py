@@ -20,6 +20,12 @@ global batch 65536, bf16 compute, bf16 tables, ``SparseSGD`` +
    published snapshot without recompiling.
 5. **four chips** (``--chips 4``) — the same model and global batch over a
    ``("data",)`` mesh of four devices with all three exchanges running.
+6. **experts** — ``models.moe_lm``'s grouped product as the backend gives
+   it (the megablox kernel on the chip) over a share of the experts, against
+   one plain product a group: the rows past the held experts' must come out
+   zero, forward and in the gradient of ``lhs``, because the expert layer
+   multiplies them by a weight of 0 and a row left unwritten could hold
+   anything.
 
 Any failed check exits non-zero at once with the check's name. No phase is
 wrapped in try/except, nothing is retried, nothing falls back to the CPU.
@@ -91,6 +97,46 @@ def check(name, ok, detail=""):
 
 def note(msg):
     print(f"  obs  {msg}", flush=True)
+
+
+def experts_share():
+    """The grouped product of a chunk whose tail (rows of experts not held)
+    is not empty, an empty group among the held, against one plain product a
+    group: ``(worst gap over out, d lhs, d rhs as a share of the largest
+    value; largest |value| in the tail rows of out and of d lhs; all finite;
+    the largest |value| that lax.ragged_dot leaves in the tail of out)``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from distributed_embeddings_tpu.models import moe_lm
+    m, k, n, sizes = 2048, 256, 384, (700, 0, 500)
+    keys = jax.random.split(jax.random.key(SEED), 3)
+    lhs = jax.random.normal(keys[0], (m, k), jnp.bfloat16)
+    rhs = jax.random.normal(keys[1], (len(sizes), k, n), jnp.bfloat16)
+    cot = jax.random.normal(keys[2], (m, n), jnp.float32)  # the tail's too
+    held, group_sizes = sum(sizes), jnp.asarray(sizes, jnp.int32)
+
+    def plain(a, b):
+        starts = [sum(sizes[:i]) for i in range(len(sizes))]
+        return jnp.concatenate(
+            [jnp.dot(a[at:at + size], b[i], preferred_element_type=jnp.float32)
+             for i, (at, size) in enumerate(zip(starts, sizes))]
+            + [jnp.zeros((m - held, n), jnp.float32)])
+
+    def three(f):   # out, d lhs, d rhs, in float32
+        out = jax.jit(lambda a, b: (f(a, b), *jax.grad(
+            lambda a, b: jnp.sum(f(a, b) * cot), argnums=(0, 1))(a, b)))(
+                lhs, rhs)
+        return [x.astype(jnp.float32) for x in out]
+    got, want = three(lambda a, b: moe_lm._grouped(a, b, group_sizes)), \
+        three(plain)
+    ragged = jax.jit(lambda a, b: lax.ragged_dot(
+        a, b, group_sizes, preferred_element_type=jnp.float32))(lhs, rhs)
+    gap = max(float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w)))
+              for g, w in zip(got, want))
+    tail = max(float(jnp.max(jnp.abs(x[held:]))) for x in got[:2])
+    return (gap, tail, all(bool(jnp.isfinite(x).all()) for x in got),
+            float(jnp.max(jnp.abs(ragged[held:]))))
 
 
 def main(argv=None):
@@ -619,6 +665,17 @@ def main(argv=None):
         w4 = de4.get_weights(state4.emb_params)
         agreement("four.agree.after", de4, state4, w4, evals4, put4)
         no_stray_writes("four.agree.after", w0, w4, FOUR_CHIP_STEPS)
+
+    # -------------------------------------------------------------- experts
+    print("== experts", flush=True)
+    gap, tail, finite, stray = experts_share()
+    check("experts.tail_rows_zero", finite and tail == 0.0,
+          f"largest |value| past the held experts' rows {tail}, forward and "
+          f"d lhs; all finite {finite}")
+    check("experts.agrees_with_plain_products", gap <= 2.0 ** -7,
+          f"worst gap {gap:.2e} of the largest value over out, d lhs, d rhs")
+    note(f"lax.ragged_dot on this backend leaves up to {stray} in those rows "
+         f"(0 on the CPU; it is not the chip's form)")
 
     c = obs.counters()
     print(f"programs built {c.get('recompiles', 0)}, of which loaded from the "

@@ -425,7 +425,7 @@ def _pipelined_local_step(de, loss_fn, dense_tx, emb_optimizer,
 def _hybrid_local_step(de, loss_fn, dense_tx, emb_optimizer, lr_schedule,
                        state, cat_inputs, batch, with_metrics=False,
                        nan_guard=False, telemetry_cfg=None, telem=None,
-                       streaming_cfg=None, sstate=None):
+                       streaming_cfg=None, sstate=None, has_aux=False):
     """One per-device hybrid step (shared by :func:`make_hybrid_train_step`
     and :func:`make_hybrid_train_loop`): forward, one backward producing dp
     gradients (pmean-averaged) and mp cotangents (manual sparse path), both
@@ -466,6 +466,12 @@ def _hybrid_local_step(de, loss_fn, dense_tx, emb_optimizer, lr_schedule,
     trajectory. The updated streaming state returns as the step's LAST
     element (after the telemetry state when both ride).
 
+    ``has_aux=True`` (static): ``loss_fn`` returns ``(loss, aux)``, ``aux``
+    a dict of per-device arrays with a leading dimension (counts the model
+    takes on the way, say). It leaves the step as its third element, merged
+    into the metrics dict under ``with_metrics``; nothing of the update
+    reads it. The serialized step only.
+
     A ``de.schedule`` with ``microbatches > 1`` (a
     :func:`~.schedule.pipelined_schedule`) routes to
     :func:`_pipelined_local_step` — the K-microbatch latency-hiding
@@ -475,6 +481,10 @@ def _hybrid_local_step(de, loss_fn, dense_tx, emb_optimizer, lr_schedule,
     """
     K = _microbatch_count(de)
     if K > 1:
+        if has_aux:
+            raise NotImplementedError(
+                "has_aux: the pipelined step has no way out for a loss's "
+                "auxiliary outputs; use a serialized schedule")
         return _pipelined_local_step(
             de, loss_fn, dense_tx, emb_optimizer, lr_schedule, state,
             cat_inputs, batch, K, with_metrics=with_metrics,
@@ -497,7 +507,14 @@ def _hybrid_local_step(de, loss_fn, dense_tx, emb_optimizer, lr_schedule,
 
     with obs.scope("dense_forward_backward"):
         loss, (dense_grads, out_grads) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1))(state.dense_params, outs, batch)
+            loss_fn, argnums=(0, 1), has_aux=has_aux)(
+                state.dense_params, outs, batch)
+    loss_aux = {}
+    if has_aux:
+        loss, loss_aux = loss
+        # what the loss derived from its shard varies over the mesh already
+        loss_aux = {k: v if world == 1 or de.axis_name in jax.typeof(v).vma
+                    else de._vary(v) for k, v in loss_aux.items()}
     if world > 1:
         loss = lax.pmean(loss, de.axis_name)
         dense_grads = jax.tree.map(
@@ -552,12 +569,12 @@ def _hybrid_local_step(de, loss_fn, dense_tx, emb_optimizer, lr_schedule,
     if new_sstate is not None:
         aux_out += (new_sstate,)
     if not with_metrics:
-        return (loss, new_state) + aux_out
+        return (loss, new_state) + ((loss_aux,) if has_aux else ()) + aux_out
     metrics = de.step_metrics(
         res, out_dtype=out_grads[0].dtype if out_grads else None)
     metrics = _finish_metrics(de, metrics, out_grads, dense_grads, loss,
                               ok, state, sstats, lr)
-    return (loss, new_state, metrics) + aux_out
+    return (loss, new_state, dict(metrics, **loss_aux)) + aux_out
 
 
 class HybridTrainState(NamedTuple):
@@ -601,16 +618,23 @@ def make_hybrid_train_step(de: DistributedEmbedding,
                            with_metrics: Optional[bool] = None,
                            nan_guard: Optional[bool] = None,
                            telemetry=None,
-                           dynamic=None):
+                           dynamic=None,
+                           has_aux: bool = False):
     """Build ``step(state, cat_inputs, batch) -> (loss, state)``.
 
     Args:
       de: the distributed embedding layer.
       loss_fn: ``loss_fn(dense_params, emb_outputs, batch) -> scalar`` local
-        mean loss over the per-device batch shard.
+        mean loss over the per-device batch shard; with ``has_aux``,
+        ``(scalar, aux)``. The dense stack may be anything that takes the
+        embedding activations: DLRM's MLPs, or a language model's layers over
+        its token table's rows (:mod:`~..models.moe_lm`).
       dense_tx: optax transform for the dense (data-parallel) parameters.
-      emb_optimizer: sparse slab optimizer (:class:`~.optimizers.SparseSGD` /
-        :class:`~.optimizers.SparseAdagrad`).
+      emb_optimizer: sparse slab optimizer (:class:`~.optimizers.SparseSGD`,
+        :class:`~.optimizers.SparseAdagrad`,
+        :class:`~.optimizers.SparseMomentum` or
+        :class:`~.optimizers.SparseAdam`; the last two carry row-wise
+        state, updated lazily for the rows a step touches).
       mesh: required when ``de.world_size > 1``.
       lr_schedule: embedding-optimizer learning rate — a constant or a
         ``step -> lr`` callable (the dense side can use optax schedules
@@ -657,6 +681,14 @@ def make_hybrid_train_step(de: DistributedEmbedding,
     ``with_metrics`` the :data:`~..utils.obs.STREAMING_METRIC_KEYS`
     entries join the metrics dict.
 
+    ``has_aux``: ``loss_fn`` returns ``(loss, aux)``, ``aux`` a dict of
+    per-device arrays with a leading dimension (a model's own counts: the
+    pairs an expert layer routed, say). The step then returns ``(loss,
+    state, aux)``, or under ``with_metrics`` the metrics dict with ``aux``'s
+    entries merged in; on a mesh every entry stacks per rank like a metric.
+    The update reads nothing of it, and a step built without it is the
+    program it was. Not with a pipelined schedule.
+
     The returned step takes data-parallel shards: each categorical input
     ``[local_batch, hotness]`` and ``batch`` any pytree of per-device arrays
     the loss consumes (already sharded by the caller).
@@ -686,7 +718,8 @@ def make_hybrid_train_step(de: DistributedEmbedding,
                                  with_metrics=with_metrics,
                                  nan_guard=nan_guard,
                                  telemetry_cfg=tel_cfg, telem=telem,
-                                 streaming_cfg=dyn_cfg, sstate=sstate)
+                                 streaming_cfg=dyn_cfg, sstate=sstate,
+                                 has_aux=has_aux)
         if not n_aux:
             return out
         head, aux_out = out[:-n_aux], list(out[-n_aux:])
@@ -711,8 +744,13 @@ def make_hybrid_train_step(de: DistributedEmbedding,
         dense_params=P(), dense_opt_state=P(), step=P())
     mspecs = _metric_specs(
         ax, obs.STREAMING_METRIC_KEYS if dyn_cfg is not None else ())
-    out_specs = ((P(), state_specs, mspecs) if with_metrics
-                 else (P(), state_specs))
+    if has_aux:
+        # the loss's own entries are not known before the trace: one spec
+        # for the whole dict, every entry per rank as the metrics are
+        out_specs = (P(), state_specs, P(ax))
+    else:
+        out_specs = ((P(), state_specs, mspecs) if with_metrics
+                     else (P(), state_specs))
     in_specs = (state_specs, P(ax), P(ax)) + (P(ax),) * n_aux
     out_specs = out_specs + (P(ax),) * n_aux
 
