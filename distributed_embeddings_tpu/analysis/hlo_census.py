@@ -198,12 +198,15 @@ def dedup_zero_contracts(reason: str) -> List[PassBudget]:
 def apply_contracts() -> List[PassBudget]:
     """The sparse apply's budget. A small table's cotangents are summed as
     ``onehot(ids)^T @ cotangents`` (``parallel/apply.py:small_table_sums``):
-    the ``small_sum`` scope holds no sort, scatter, cumsum or gather, or the
-    sums are back on a row path. And the sweep's forms are ONE scatter a
-    width slab, whatever joined its stream."""
+    the ``small_sum`` scope of the dense slots and the ``ragged_sum`` scope
+    of the ragged ones (whose ``take`` of the cotangents stays outside it)
+    hold no sort, scatter, cumsum or gather, or the sums are back on a row
+    path. And the sweep's forms are ONE scatter a width slab, whatever
+    joined its stream."""
     why = ("small tables are summed on the MXU: a row operation there is "
            "the scatter's stream again")
-    out = [PassBudget("small_sum", k, max_passes=0, reason=why)
+    out = [PassBudget(scope, k, max_passes=0, reason=why)
+           for scope in ("small_sum", "ragged_sum")
            for k in ("sort", "scatter", "cumsum", "gather")]
     out += [PassBudget("scatter_" + form, "scatter", max_passes=1,
                        per_path=True, reason="ONE scatter a width slab")

@@ -112,7 +112,17 @@ def sparse_apply_gradients(de, params, opt_state, residuals, out_grads,
                                optimizer, lr, scale, enable=enable)
 
 
-def small_table_sums(g, ids4, grads, live, roff, sent):
+def block_runs(block):
+    """``(block rows, first slot, end slot)`` of each run of equal block
+    rows of a small-table group: the slots that share one batched matmul."""
+    k0 = 0
+    for v, run in itertools.groupby(block):
+        k1 = k0 + len(list(run))
+        yield v, k0, k1
+        k0 = k1
+
+
+def small_table_sums(g, ids4, grads, live, roff, sent, slot_major=False):
     """The stream of a small-table group (``GroupSpec.block``): per slot ONE
     dense block, rows ``roff .. roff + V - 1`` holding ``onehot(ids)^T @
     cotangents``, in place of a row an id. Slots of equal ``V`` share one
@@ -121,36 +131,46 @@ def small_table_sums(g, ids4, grads, live, roff, sent):
     buffer, and the sums accumulate in float32 and round once: what a
     dedup of the slot's rows would have made of them.
 
-    ``ids4 [world, n, b, hot]`` are table-local ids, ``grads [world, b, n,
-    w]`` the slots' cotangent rows (``mean`` already divided), ``live [n]``
-    this rank's table rows a slot (0: dead slot). A block row past ``live``
-    or that no id of the step touched goes to ``sent``: out-of-range ids and
-    dead slots train nothing, and a lazy optimizer leaves an untouched row
-    and its state alone. Returns ``(ids [sum V], vals [sum V, w])``."""
-    world, n, b, hot = ids4.shape
-    w = grads.shape[-1]
-    precision = (lax.Precision.HIGHEST if grads.dtype == jnp.float32
+    ``ids4 [world, n, s, hot]`` are table-local ids and ``grads`` the
+    cotangent rows they address (``mean`` already divided): for a dense
+    group the slots' rows of the exchange row, ``[world, s, n, w]`` over the
+    ``s = b`` samples; ``slot_major``, for a ragged group, the rows its
+    ``take`` expanded, one array a run of :func:`block_runs`, ``[world,
+    slots, s, w]`` over the ``s`` positions of the capacity, each its own
+    column of the one-hot (``hot`` 1; a position that holds no id of the
+    batch carries an id that matches no row). ``live [n]`` are this rank's
+    table rows a slot (0: dead slot). A block row past ``live`` or that no
+    id of the step touched goes to ``sent``: out-of-range ids and dead slots
+    train nothing, and a lazy optimizer leaves an untouched row and its
+    state alone. Returns ``(ids [sum V], vals [sum V, w])``."""
+    world, n, s, hot = ids4.shape
+    dtype = (grads[0] if slot_major else grads).dtype
+    w = g.width
+    precision = (lax.Precision.HIGHEST if dtype == jnp.float32
                  else None)  # 0/1 times float32 stays float32
     ids_l, vals_l = [], []
-    k0 = 0
-    for v, run in itertools.groupby(g.block):
-        k1 = k0 + len(list(run))
-        # [slots, 1, world * b, hot] against the block's row numbers
+    for i, (v, k0, k1) in enumerate(block_runs(g.block)):
+        # [slots, 1, world * s, hot] against the block's row numbers
         loc = ids4[:, k0:k1].transpose(1, 0, 2, 3).reshape(
-            k1 - k0, 1, world * b, hot)
+            k1 - k0, 1, world * s, hot)
         row = lax.broadcasted_iota(loc.dtype, (1, v, 1, 1), 1)
         eq = loc == row
-        sums = lax.dot_general(
-            jnp.sum(eq, axis=3, dtype=grads.dtype),   # [slots, v, world * b]
-            grads[:, :, k0:k1].reshape(world * b, k1 - k0, w),
-            (((2,), (0,)), ((0,), (1,))), precision=precision,
-            preferred_element_type=jnp.float32)       # [slots, v, w]
+        onehot = jnp.sum(eq, axis=3, dtype=dtype)  # [slots, v, world * s]
+        if slot_major:  # contract the sources and the positions in place
+            lhs, rhs = onehot.reshape(k1 - k0, v, world, s), grads[i]
+            dims = (((2, 3), (0, 2)), ((0,), (1,)))
+        else:
+            lhs = onehot
+            rhs = grads[:, :, k0:k1].reshape(world * s, k1 - k0, w)
+            dims = (((2,), (0,)), ((0,), (1,)))
+        sums = lax.dot_general(lhs, rhs, dims, precision=precision,
+                               preferred_element_type=jnp.float32
+                               )  # [slots, v, w]
         at = row.reshape(1, v)
         keep = jnp.any(eq, axis=(2, 3)) & (at < live[k0:k1, None])
         ids_l.append(jnp.where(keep, at + roff[k0:k1, None], sent
                                ).reshape(-1))
-        vals_l.append(sums.astype(grads.dtype).reshape(-1, w))
-        k0 = k1
+        vals_l.append(sums.astype(dtype).reshape(-1, w))
     return jnp.concatenate(ids_l), jnp.concatenate(vals_l)
 
 
@@ -244,10 +264,12 @@ def cotangent_width_streams(de, residuals, out_grads, fallback_dtype=None,
             ids4 = region.reshape(world, g.n, b, g.hot)
             if rbase is not None:  # row-sliced slots: range-local ids
                 ids4 = ids4 - rbase[None, :, None, None]
+        # a small-table group's sums run under the scope the width's scatter
+        # will run in, so that a profile counts them to the apply, and under
+        # a name of their own there
         if g.block:
             live = rows if valid is None else jnp.where(valid > 0, rows, 0)
-            # under the scope the width's scatter will run in, so that a
-            # profile counts the sums to the apply and names them
+        if g.block and g.kind == "d":
             with obs.scope(f"sparse_apply_{_wkey(g.width)}"), \
                     obs.scope("small_sum"):
                 ids, vals = small_table_sums(g, ids4, gb, live, roff, sent)
@@ -271,43 +293,71 @@ def cotangent_width_streams(de, residuals, out_grads, fallback_dtype=None,
                 gb[:, :, :, None, :],
                 (world, b, g.n, g.hot, g.width))
         else:
-            gsl = gsl.transpose(0, 2, 1, 3)  # ragged sidx layout is
-            # (source, slot, row): one small copy, the take absorbs it
             values, _, seg, _, counts = lookup_mod.ragged_decode(
                 de, g, b, region, rows, roff, valid,
                 need_counts=any_mean, rbase=rbase)
             if rbase is not None:  # row-sliced slots: range-local ids
                 values = values - rbase[None, :, None]
-            sidx = lookup_mod.ragged_scatter_idx(g, b, world, seg)
-            gpad = jnp.concatenate(
-                [gsl, de._vary(jnp.zeros((world, g.n, 1, g.width),
-                                         gsl.dtype))],
-                axis=2)  # [world, n, b+1, w]
-            vals = jnp.take(gpad.reshape(-1, g.width), sidx.reshape(-1),
-                            axis=0).reshape(world, g.n, g.hot, g.width)
             if g.kind == "rw":
                 # d(w_i * x_i)/dx_i: the weight multiplies the per-id
                 # cotangent (the reference backward reuses the forward
                 # kernel with the same weights input, .cu:539-627)
                 wts = lookup_mod.region_weights(de, g, b, region)
-                vals = vals * wts[..., None].astype(vals.dtype)
+            # What a position takes, it takes by its segment. A dead
+            # position (seg == b) names the entry after its segments',
+            # clipped into the buffer: its id goes to the sentinel below (in
+            # a block it matches no row), so what it takes is never read.
+            # Hence no entry is padded on for it, and the takes are spared
+            # their fill: over the rows that is a select a matmul reading
+            # them cannot absorb as the stream's ``* scale`` does.
+            s_ix = jnp.arange(world, dtype=seg.dtype)[:, None, None]
             if any_mean:
-                cpad = jnp.concatenate(
-                    [counts, jnp.ones((world, g.n, 1), counts.dtype)],
-                    axis=2)
-                cval = jnp.take(cpad.reshape(-1), sidx.reshape(-1)
-                                ).reshape(world, g.n, g.hot)
-                div = vals / cval[..., None].astype(vals.dtype)
-                if all_mean:
-                    vals = div
-                else:
-                    mean = de._plan_row(plan.mean[gi], my)
-                    vals = jnp.where(mean[None, :, None, None] > 0,
-                                     div, vals)
+                f_ix = jnp.arange(g.n, dtype=seg.dtype)[None, :, None]
+                cval = jnp.take(
+                    counts.reshape(-1),
+                    ((s_ix * g.n + f_ix) * b + seg).reshape(-1), mode="clip"
+                ).reshape(world, g.n, g.hot)
+                mean = (None if all_mean
+                        else de._plan_row(plan.mean[gi], my))
+
+            def rows_of(k0, k1):
+                """The update row of every position of slots ``k0 .. k1``,
+                ``[world, slots, capacity, w]``: its segment's cotangent row
+                straight out of the exchange row's ``[world, b, n, w]``
+                layout (no transposed copy of it), times its weight, over
+                its ``mean`` divisor."""
+                f_ix = jnp.arange(k1 - k0, dtype=seg.dtype)[None, :, None]
+                out = jnp.take(
+                    gsl[:, :, k0:k1].reshape(-1, g.width),
+                    ((s_ix * b + seg[:, k0:k1]) * (k1 - k0) + f_ix
+                     ).reshape(-1), axis=0, mode="clip"
+                ).reshape(world, k1 - k0, g.hot, g.width)
+                if g.kind == "rw":
+                    out = out * wts[:, k0:k1, :, None].astype(out.dtype)
+                if any_mean:
+                    div = out / cval[:, k0:k1, :, None].astype(out.dtype)
+                    out = (div if all_mean else jnp.where(
+                        mean[None, k0:k1, None, None] > 0, div, out))
+                return out
+
             ok = (seg < b) & (values >= 0) & (values < rows[None, :, None])
             if valid is not None:
                 ok = ok & (valid[None, :, None] > 0)
-            ids = jnp.where(ok, values + roff[None, :, None], sent)
+            if g.block:
+                # The rows of one run of equal block rows at a time: what a
+                # gather costs goes by whether its source stays near the
+                # core, and a run's slice of the exchange row is likelier to
+                # than the group's. A position that is not ok matches no
+                # block row.
+                runs = [rows_of(k0, k1) for _, k0, k1 in block_runs(g.block)]
+                with obs.scope(f"sparse_apply_{_wkey(g.width)}"), \
+                        obs.scope("ragged_sum"):
+                    ids, vals = small_table_sums(
+                        g, jnp.where(ok, values, -1)[..., None], runs, live,
+                        roff, sent, slot_major=True)
+            else:
+                vals = rows_of(0, g.n)
+                ids = jnp.where(ok, values + roff[None, :, None], sent)
         per_width.setdefault(_wkey(g.width), []).append(
             (ids, vals, g.width))
 

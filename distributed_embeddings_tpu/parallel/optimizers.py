@@ -101,7 +101,10 @@ SCATTER_FORMS = ("sort_fused", "unsorted", "dedup_rows")
 # small table's ids cost what a huge one's do; :func:`sums_densely` takes such
 # a slot off the stream for a block of ``onehot(ids)^T @ cotangents`` at
 # ``_ONEHOT_ELEM_NS`` an element of the one-hot (PERF.md section 6, PR 33: the
-# one-hot cell's sweep 58.4 -> 43.1 ms for 1.70 M -> 0.68 M rows, the sums 3.1).
+# one-hot cell's sweep 58.4 -> 43.1 ms for 1.70 M -> 0.68 M rows, the sums 3.1;
+# PR 37, ragged slots, a row a position: the multi-hot cell's 146.1 -> 77.3 ms
+# for 6.8 M -> 2.64 M rows, 72.28 + 5.03 read where the line gives 72.7 + 5.0,
+# the sums 12.3).
 _SWEEP_NS = (15.2, 4.04e6 / 2 ** 30)
 _RMW_ROW_NS = 75.0
 _SCATTER_NS = {
@@ -124,7 +127,11 @@ _RMW_CHUNK = 8192
 # MXU and its part of the touched-rows reduce): ``small_sum`` read 3.086 ms a
 # step for 21 120 block rows x 65 536 samples in the one-hot cell (PERF.md
 # section 6, PR 33). A block of 6 800 rows then costs what its 65 536 ids
-# cost the sweep.
+# cost the sweep. Ragged slots have the class too, a column of the one-hot a
+# position of the capacity: ``ragged_sum`` read 12.29 ms for the same 21 120
+# block rows x 262 144 positions in the multi-hot cell, 2.22 ps an element
+# (PR 37), so one constant serves both; a block of 7 450 rows costs what
+# 262 144 positions cost the sweep.
 _ONEHOT_ELEM_NS = 2.23e-3
 # a block holds whole tiles of the matmul's output rows
 _BLOCK_TILE = 128
@@ -158,12 +165,14 @@ def padded_slots_ns(slots: int, samples: int) -> float:
 
 
 def sums_densely(table_rows: int, ids: int, hot: int = 1) -> bool:
-    """Whether a dense slot that sends ``ids`` ids a step (``hot`` a sample)
-    into a table of ``table_rows`` rows should leave the scatter's stream:
-    the backward then sums its cotangents as ``onehot(ids)^T @ cotangents``
-    into one block of the table's rows, and the stream gets the block's rows
-    in place of a row an id. It should where that costs less than the ids
-    cost the cheapest sweep."""
+    """Whether a slot that sends ``ids`` ids a step into a table of
+    ``table_rows`` rows should leave the scatter's stream: the backward then
+    sums its cotangents as ``onehot(ids)^T @ cotangents`` into one block of
+    the table's rows, and the stream gets the block's rows in place of a row
+    an id. It should where that costs less than the ids cost the cheapest
+    sweep. A dense slot sends ``hot`` ids a sample, which meet in one column
+    of a count matrix; a ragged slot sends the positions of its capacity,
+    every source's and dead ones too, each a column of its own (``hot`` 1)."""
     return (hot <= _COUNT_EXACT
             and small_sum_ns([block_rows(table_rows)], ids)
             < scatter_ns("sort_fused", ids, 0))

@@ -19,12 +19,13 @@ id-exchange block and the output-exchange row are laid out as a sequence of
   ``w`` output columns;
 * ``n`` is the max slot count over ranks — ranks with fewer tables of that
   shape pad with dead slots (zero ids in, never-read columns out);
-* a dense group is one of two **size classes**: the slots of tables small
-  enough that the backward sums their cotangents as ``onehot(ids)^T @
-  cotangents`` (``optimizers.sums_densely``: the cost rule) meet in a group
-  of their own, each rank's slots largest first, and its ``GroupSpec.block``
-  holds every slot's block rows. The forward, the exchanges and the serve
-  program treat it as any dense group.
+* a group, dense or ragged, is one of two **size classes**: the slots of
+  tables small enough that the backward sums their cotangents as
+  ``onehot(ids)^T @ cotangents`` (``optimizers.sums_densely``: the cost rule,
+  over the ids a dense slot sends a step or the positions of a ragged slot's
+  capacity) meet in a group of their own, each rank's slots largest first,
+  and its ``GroupSpec.block`` holds every slot's block rows. The forward,
+  the exchanges and the serve program treat it as any group of its kind.
 
 What *differs* per rank — which table a slot reads (row count, slab row
 offset), its combiner, whether the slot is live — is carried in small
@@ -68,14 +69,17 @@ class GroupSpec:
     #: in whole tiles); empty where the slots ride the scatter's stream
     block: Tuple[int, ...] = ()
 
-    def stream_rows(self, world: int, b: int) -> int:
-        """Update rows the group puts into its width's stream a step: a
-        block's rows, else one row an id (ragged: a row a capacity slot)."""
-        if self.block:
-            return sum(self.block)
+    def id_rows(self, world: int, b: int) -> int:
+        """Update rows the group's slots send a step at one row an id
+        (ragged: a row a position of the capacity, dead or not)."""
         per_source = (b * self.n * self.hot if self.kind == "d"
                       else self.n * self.hot)
         return world * per_source
+
+    def stream_rows(self, world: int, b: int) -> int:
+        """Update rows the group puts into its width's stream a step: a
+        block's rows, else :meth:`id_rows`."""
+        return sum(self.block) if self.block else self.id_rows(world, b)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,8 +160,8 @@ class ExchangePlan:
     @property
     def dense_rows(self) -> int:
         """Stream rows a step those slots would have sent (one an id)."""
-        return sum(self.world * self.b * g.hot * len(g.block)
-                   for g in self.groups)
+        return sum(g.id_rows(self.world, self.b)
+                   for g in self.groups if g.block)
 
 
 def _n_slots(insts) -> int:
@@ -228,31 +232,38 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
                         f"Input {i} is Ragged but table "
                         f"{strategy.input_table_map[i]} has no combiner; "
                         "ragged features require combiner='sum' or 'mean'")
-                key = (kind, w, param)  # "r" | "rw" (per-id weights ride
-                # the block as bitcast floats, so weighted features group
-                # separately — their slots are one capacity longer)
+                # "r" | "rw" (per-id weights ride the block as bitcast
+                # floats, so weighted features group separately — their
+                # slots are one capacity longer); the size class over the
+                # positions a slot sends a step, dead ones too: each is one
+                # column of a 0/1 one-hot whatever the hotness
+                key = (kind, w, param, int(optimizers.sums_densely(
+                    rows, world * param)))
                 entries = [(rows, roff, 1.0,
                             1.0 if comb == "mean" else 0.0, rbase, rsl)]
             key_insts.setdefault(key, [[] for _ in range(world)]
                                  )[r].append((pos, i, entries))
             pos += 1
 
-    # The small class of a (width, hotness): each rank's slots largest table
-    # first, so that a slot's block (the largest table any rank has there)
-    # is tight. Two classes pad each to its fullest rank, so the class
-    # stands only where the step's work is then less than with one group.
-    for k in [k for k in key_insts if k[0] == "d" and k[3]]:
+    # The small class of a (kind, width, hotness or capacity): each rank's
+    # slots largest table first, so that a slot's block (the largest table
+    # any rank has there) is tight. Two classes pad each to its fullest rank,
+    # so the class stands only where the step's work is then less than with
+    # one group. A padded dense slot costs the forward a row a sample, a
+    # padded ragged slot a row a position.
+    for k in [k for k in key_insts if k[3]]:
         small = [sorted(insts, key=lambda inst: -inst[2][0][0])
                  for insts in key_insts[k]]
         large = key_insts.get(k[:3] + (0,), [[] for _ in range(world)])
         n_large = max(_n_slots(insts) for insts in large)
         n_one = max(_n_slots(x) + _n_slots(y) for x, y in zip(large, small))
         n_small = max(_n_slots(insts) for insts in small)
-        ids = world * b * k[2]
+        ids = world * (b * k[2] if k[0] == "d" else k[2])
+        samples = world * b if k[0] == "d" else ids
         a_row = optimizers.scatter_ns("sort_fused", ids, 0)
         if (optimizers.small_sum_ns(_blocks(small), ids) + a_row * n_large
                 + optimizers.padded_slots_ns(n_large + n_small - n_one,
-                                             world * b)
+                                             samples)
                 < a_row * n_one):
             key_insts[k] = small
         else:  # one group, in worker order
@@ -268,7 +279,6 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
     goff = col = 0
     for gi, k in enumerate(keys):
         kind, w, hp = k[:3]
-        small = kind == "d" and k[3]
         slots: List[list] = []
         for r, insts in enumerate(key_insts[k]):
             slots.append([])
@@ -288,7 +298,7 @@ def build_plan(strategy, row_offsets_list: Sequence[Sequence[int]],
                 rows_a[r, kk], roff_a[r, kk] = tr, to
                 val_a[r, kk], mn_a[r, kk] = tv, tm
                 rb_a[r, kk], rs_a[r, kk] = trb, trs
-        block = _blocks(key_insts[k]) if small else ()
+        block = _blocks(key_insts[k]) if k[3] else ()
         groups.append(GroupSpec(kind, w, hp, n, blen, goff, col, block))
         goff += n * blen
         col += n * w

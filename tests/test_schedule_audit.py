@@ -319,19 +319,19 @@ def test_schedule_declaration_check_against_compiled_graph():
 # --------------------------------------------- the real compiled step
 
 
-def _real_step_report():
+def _real_step_report(case="dense"):
     from tools._profcommon import build_case
 
     import jax
     from jax.sharding import Mesh
 
     de, cats, batch_tree, dense_params, loss_fn = build_case(
-        "dense", 8, 256)
+        case, 8, 256)
     mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
     rep = sa.audit_train_step(
         de, loss_fn, optax.sgd(0.5), SparseAdagrad(), cats, batch_tree,
         mesh=mesh, lr_schedule=0.3, dense_params=dense_params,
-        with_metrics=False, nan_guard=True, label="test/dense8")
+        with_metrics=False, nan_guard=True, label=f"test/{case}8")
     return de, rep
 
 
@@ -370,21 +370,24 @@ def test_real_step_baseline_serialized_a2a_chain(real_step_report):
     assert "grad_all_to_all" in path_phases
 
 
-def test_small_table_sums_read_ids_beside_the_exchanges():
-    """At this batch every table of the case is small, so the backward sums
-    each slot's cotangents into a dense block. Which block rows an id
-    touched is read off the received ids alone: a chain independent of the
-    activation and cotangent exchanges, which on these toy payloads
-    outweighs them (``tools/schedule_audit.py`` declares it for the case).
-    The id exchange, which everything follows, stays serialized."""
-    de, rep = _real_step_report()
+@pytest.mark.parametrize("case,scope", [("dense", "small_sum"),
+                                        ("ragged", "ragged_sum")])
+def test_small_table_sums_read_ids_beside_the_exchanges(case, scope):
+    """At this batch (the ragged case: at its capacity) every table of the
+    case is small, so the backward sums each slot's cotangents into a dense
+    block. Which block rows an id touched is read off the received ids
+    alone: a chain independent of the activation and cotangent exchanges,
+    which on these toy payloads outweighs them (``tools/schedule_audit.py``
+    declares it for both cases). The id exchange, which everything follows,
+    stays serialized."""
+    de, rep = _real_step_report(case)
     plan, = de._plan_cache.values()
     assert plan.dense_slots
     a2a = {c.phase_leaf: c for c in rep.collectives if c.op == "all-to-all"}
     assert a2a["id_all_to_all"].classification == "serialized"
     for leaf in ("out_all_to_all", "grad_all_to_all"):
         by = a2a[leaf].independent_by_phase
-        assert any(p.endswith("small_sum") and ns > 0
+        assert any(p.endswith(scope) and ns > 0
                    for p, ns in by.items()), by
 
 

@@ -10,6 +10,7 @@ on cotangents whose per-row sums are exact in any order (small integers,
 power-of-two scales), for every sparse optimizer and every state component.
 """
 
+import hashlib
 import json
 import os
 
@@ -126,6 +127,25 @@ def _assert_same(a, b):
         np.testing.assert_array_equal(x, y)
 
 
+def _assert_only_rows_under_12_moved(got, fresh, plan):
+    """Of every width-128 block slot's table, in the slab and in every state
+    component of its shape: rows 12 and up are ``fresh``'s bits, and some
+    row under 12 is not."""
+    slab = got[0]["w128"]
+    moved = False
+    for gi, g in enumerate(plan.groups):
+        if not (g.block and g.width == 128):
+            continue
+        for rows, roff in zip(plan.rows[gi][0], plan.roff[gi][0]):
+            for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(fresh)):
+                if x.shape != slab.shape:
+                    continue
+                np.testing.assert_array_equal(x[roff + 12:roff + rows],
+                                              y[roff + 12:roff + rows])
+                moved |= bool((x[roff:roff + 12] != y[roff:roff + 12]).any())
+    assert moved
+
+
 @pytest.mark.parametrize("world", [1, WORLD])
 @pytest.mark.parametrize("name", list(OPTIMIZERS))
 def test_blocks_train_what_the_stream_trains(name, world, monkeypatch):
@@ -176,18 +196,7 @@ def test_untouched_rows_and_their_state_stay(name):
     optimizer = OPTIMIZERS[name]()
     got, plan, _ = _train(1, optimizer, _batches(np.random.default_rng(5)))
     fresh, _, _ = _train(1, optimizer, [])
-    slab = got[0]["w128"]
-    moved = False
-    for g in (g for g in plan.groups if g.block and g.width == 128):
-        gi = plan.groups.index(g)
-        for rows, roff in zip(plan.rows[gi][0], plan.roff[gi][0]):
-            for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(fresh)):
-                if x.shape != slab.shape:
-                    continue
-                np.testing.assert_array_equal(x[roff + 12:roff + rows],
-                                              y[roff + 12:roff + rows])
-                moved |= bool((x[roff:roff + 12] != y[roff:roff + 12]).any())
-    assert moved
+    _assert_only_rows_under_12_moved(got, fresh, plan)
 
 
 @pytest.mark.parametrize("world", [1, WORLD])
@@ -204,18 +213,18 @@ def test_a_skipped_step_changes_nothing(world):
 # ------------------------------------------------------------------ the plan
 
 
-def _plan_of(name, b):
+def _plan_of(name, b, enc=("d", 1), combiner=None):
     with open(os.path.join(ROOT, "benchmarks", "configs", name + ".json")) as f:
         cfg = json.load(f)
     plan = cfg.get("plan", {})
     de = DistributedEmbedding(
         [{"input_dim": s, "output_dim": cfg["embedding_dim"],
-          "combiner": None} for s in cfg["table_sizes"]],
+          "combiner": combiner} for s in cfg["table_sizes"]],
         world_size=cfg["chips"],
         **({"strategy": plan["strategy"]} if "strategy" in plan else {}),
         **({"column_slice_threshold": int(plan["column_slice_threshold"])}
            if plan.get("column_slice_threshold") else {}))
-    return cfg, de, de._get_plan([("d", 1)] * len(cfg["table_sizes"]), b)
+    return cfg, de, de._get_plan([enc] * len(cfg["table_sizes"]), b)
 
 
 def test_rule_on_the_benchmarks_tables():
@@ -242,6 +251,62 @@ def test_rule_on_the_benchmarks_tables():
     # more ids make no table of 12 000 rows and up small
     assert not opt.sums_densely(11939, 2 ** 30)
     assert not opt.sums_densely(64, 2 ** 20, hot=257)   # bf16 counts to 256
+
+
+MULTIHOT_CAPACITY = 262144   # benchmarks/traffic/train_multihot_b16384.json
+
+
+def test_rule_on_the_benchmarks_multihot_signature():
+    """The multi-hot cell: every feature ragged in a capacity of 262 144. A
+    slot sends the sweep a row a POSITION, so the rule is the one-hot cell's
+    at four times the ids: the same 16 tables (a block of 7 450 rows would
+    cost what its positions cost; 5 683 and 12 517 are the nearest), 21 120
+    block rows, and the width's stream is short enough for ``sort_fused``."""
+    cfg, _, plan = _plan_of("dlrm-kaggle", 16384,
+                            ("r", MULTIHOT_CAPACITY), "sum")
+    small = sorted((s for s in cfg["table_sizes"]
+                    if opt.sums_densely(s, MULTIHOT_CAPACITY)), reverse=True)
+    assert small == sorted((s for s in cfg["table_sizes"] if s <= 5683),
+                           reverse=True) and len(small) == 16
+    assert opt.sums_densely(7400, MULTIHOT_CAPACITY)
+    assert not opt.sums_densely(7500, MULTIHOT_CAPACITY)
+    large, blocks = plan.groups
+    assert (large.kind, large.n, large.block) == ("r", 10, ())
+    assert (blocks.kind, blocks.n) == ("r", 16)
+    assert blocks.block == tuple(opt.block_rows(s) for s in small)
+    assert sum(blocks.block) == 21_120
+    assert (plan.dense_slots, plan.dense_rows) == (16, 16 * MULTIHOT_CAPACITY)
+    stream = plan.stream_rows()[128]
+    assert stream == 10 * MULTIHOT_CAPACITY + 21_120 == 2_642_560
+    slab_bytes = sum(cfg["table_sizes"]) * 128 * 2
+    assert opt.scatter_form(stream, slab_bytes) == "sort_fused"
+    assert opt.scatter_form(26 * MULTIHOT_CAPACITY,
+                            slab_bytes) == "unsorted"
+
+
+def _digest(plan):
+    h = hashlib.sha256(repr((plan.b, plan.groups, plan.instances, plan.l_max,
+                             plan.s_max)).encode())
+    for arrays in (plan.rows, plan.roff, plan.valid, plan.mean, plan.rbase,
+                   plan.rsliced):
+        for a in arrays:
+            h.update(a.tobytes() + str(a.dtype).encode())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name,b,enc,combiner,digest", [
+    ("dlrm-kaggle", 65536, ("d", 1), None, "dd39f94af4ea0673"),
+    ("dlrm-kaggle", 4096, ("d", 1), None, "230be8307d516250"),
+    ("dlrm-kaggle", 256, ("d", 1), None, "bdbcb9f518e02ac2"),
+    ("dlrm-criteo1tb", 16384, ("d", 1), None, "ca85017b1ebc4b5a"),
+    ("dlrm-kaggle", 512, ("d", 3), "sum", "b85cb57830ed1493"),
+])
+def test_dense_signatures_plan_as_before_the_ragged_class(name, b, enc,
+                                                          combiner, digest):
+    """The ragged size class moved no dense signature's plan: groups,
+    instances, offsets and every plan tensor hash to what the tree before it
+    gave (``fc65086``; the digests were taken there)."""
+    assert _digest(_plan_of(name, b, enc, combiner)[2]) == digest
 
 
 def test_size_class_is_rank_uniform():
@@ -290,6 +355,40 @@ def test_a_class_that_only_pads_is_not_made():
     assert plan.stream_rows() == {128: B}
 
 
+@pytest.mark.parametrize("kind", ["r", "rw"])
+def test_a_ragged_class_that_only_pads_is_not_made(kind):
+    """As above for ragged slots, whose padding is priced by the capacity: a
+    dead ragged slot is gathered and swept a row a position like a live one."""
+    tables = [(40, 128, "sum", 1), (9000, 128, "sum", 1)] * 4
+    de = _embedding(WORLD, tables)
+    cap = 4 * B // WORLD
+    assert opt.sums_densely(40, WORLD * cap)
+    plan = de._get_plan([(kind, cap)] * len(tables), B // WORLD)
+    assert plan.dense_slots == plan.dense_rows == 0 and len(plan.groups) == 1
+    assert plan.stream_rows() == {128: WORLD * cap}
+    g, = plan.groups
+    assert (g.kind, g.hot, g.n, g.block) == (kind, cap, 1, ())
+
+
+def test_plan_audit_counts_ragged_blocks():
+    """The multi-hot cell's report: the 16 ragged slots and the rows a step
+    they no longer send, and the scatter priced over the shorter stream."""
+    cfg, de, plan = _plan_of("dlrm-kaggle", 16384,
+                             ("r", MULTIHOT_CAPACITY), "sum")
+    rep = audit_plan(de, 16384, param_dtype="bfloat16",
+                     encodings=[("r", MULTIHOT_CAPACITY)] * 26)
+    slab, = rep.slabs
+    assert slab.stream_rows == 2_642_560
+    assert (rep.dense_slots, rep.dense_rows) == (16, 16 * MULTIHOT_CAPACITY)
+    assert slab.scatter_form == "sort_fused"
+    assert slab.scatter_ms == pytest.approx(opt.scatter_ns(
+        "sort_fused", 2_642_560, slab.rank_bytes) / 1e6)
+    assert "16 small-table slot(s)" in rep.markdown()
+    assert [g["kind"] for g in rep.to_json()["groups"]] == ["r", "r"]
+    assert rep.to_json()["groups"][1]["block_rows"] == list(
+        plan.groups[1].block)
+
+
 def test_plan_audit_prices_the_shorter_stream():
     _, de, plan = _plan_of("dlrm-kaggle", 65536)
     rep = audit_plan(de, 65536, param_dtype="bfloat16")
@@ -334,3 +433,43 @@ def test_kaggle_step_scatters_the_shorter_stream(monkeypatch):
     assert rep.passes("*scatter_sort_fused", "scatter") == 1
     assert rep.passes("*small_sum", "fusion") + rep.phases[
         "sparse_apply/sparse_apply_w128/small_sum"].instructions > 0
+
+
+def test_multihot_step_scatters_the_shorter_stream(monkeypatch):
+    """The multi-hot cell's step at its real shapes, abstract: ONE scatter
+    into the width-128 slab, of 2 642 560 rows where it was 6 815 744, in the
+    form ``sort_fused``; the ragged sums hold no row operation (the ``take``
+    that expands the cotangents is the stream path's and lies outside)."""
+    from distributed_embeddings_tpu import Ragged
+
+    cfg, de, plan = _plan_of("dlrm-kaggle", 16384,
+                             ("r", MULTIHOT_CAPACITY), "sum")
+    calls = []
+    real = opt._sorted_scatter_add
+
+    def spy(slab, ids, vals):
+        calls.append((slab.shape[0], ids.shape[0], vals.shape))
+        return real(slab, ids, vals)
+
+    monkeypatch.setattr(opt, "_sorted_scatter_add", spy)
+    n = len(cfg["table_sizes"])
+    tx = optax.sgd(0.1)
+    state = jax.eval_shape(
+        lambda k: init_hybrid_state(de, SparseSGD(), {"w": jnp.ones((1,))},
+                                    tx, k, dtype=jnp.bfloat16),
+        jax.random.key(0))
+    step = make_hybrid_train_step(de, _loss, tx, SparseSGD(),
+                                  lr_schedule=0.5, with_metrics=False)
+    rag = Ragged(values=jax.ShapeDtypeStruct((MULTIHOT_CAPACITY,), jnp.int32),
+                 row_splits=jax.ShapeDtypeStruct((16385,), jnp.int32))
+    args = (state, [rag] * n,
+            jax.ShapeDtypeStruct((16384, 128 * n), jnp.float32))
+    rep = census_step_fn(step, args, contracts=apply_contracts())
+    assert rep.ok, rep.violations
+    assert calls == [(sum(cfg["table_sizes"]), 2_642_560, (2_642_560, 128))]
+    assert rep.passes("*scatter_sort_fused", "scatter") == 1
+    assert not rep.passes("*scatter_unsorted", "scatter")
+    sums = rep.phases["sparse_apply/sparse_apply_w128/ragged_sum"]
+    assert sums.fusions + sums.instructions > 0
+    assert not any(sums.counts.get(k) for k in ("sort", "scatter", "cumsum",
+                                                "gather"))

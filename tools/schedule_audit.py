@@ -82,7 +82,7 @@ def audit_case(name: str, world: int, batch: int, opt_name: str):
         # classify overlappable — the ROADMAP item 2 acceptance this
         # gate certifies
         contracts = sa.declared_overlap_contracts(de.schedule)
-    elif name in ("streaming", "dense", "row_sliced"):
+    elif name in ("streaming", "dense", "ragged", "row_sliced"):
         # Chains that branch off the received ids and meet the step again
         # only at the apply or the commit: genuine independent compute
         # next to the activation/cotangent exchanges, which on the audit
@@ -95,8 +95,9 @@ def audit_case(name: str, world: int, batch: int, opt_name: str):
                    "transitions) is independent of this exchange — the "
                    "overlap candidate a pipelined step can exploit")
         else:
-            # every table of these cases is small at this batch, so the
-            # backward sums each slot's cotangents into a dense block
+            # every table of these cases is small at this batch (the
+            # ragged case's at its capacity), so the backward sums each
+            # slot's cotangents into a dense block
             # (parallel/apply.py:small_table_sums); which block rows an id
             # touched is read off the received ids alone
             why = ("the small-table sums' touched-rows reduce reads only "
