@@ -245,7 +245,10 @@ def _grouped(lhs, rhs, sizes):
         return lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=_F32)
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     m, kdim, n = lhs.shape[0], lhs.shape[1], rhs.shape[2]
-    tiling = (min(512, m), min(1024, kdim), min(1024, n))
+    tm = min(512, m)
+    while tm > 8 and m % tm:    # rows a tile: a divisor of the rows
+        tm -= 8
+    tiling = (tm, min(1024, kdim), min(1024, n))
     # The rows past the held experts' ride as one more group, with no weights
     # here: the kernel's own form for a shard of the experts. This leans on
     # ``gmm`` zeroing the rows of groups beyond ``rhs.shape[0]`` (its
@@ -345,10 +348,13 @@ def moe_dispatch(idx, p, cfg: MoELMConfig):
             bounds[1:] - bounds[:-1])
 
 
-def moe_experts(g, idx, p, layer: dict, cfg: MoELMConfig):
+def moe_experts(g, idx, p, layer: dict, cfg: MoELMConfig, act=jax.nn.relu):
     """The held experts' part of the layer's sum for ``g [T, H]`` (normed),
     with the counts of :data:`COUNT_KEYS`: the pairs held, the pairs dropped
-    (held less computed: 0), the fullest expert's pairs, the chunks run."""
+    (held less computed: 0), the fullest expert's pairs, the chunks run.
+    An expert is ``down(act(gate(g)) * up(g))``: ReGLU by default, SwiGLU
+    with ``act=jax.nn.silu``. ``cfg`` is any configuration with
+    ``experts_held``, ``router_outputs``, ``num_held`` and ``moe_chunk``."""
     with obs.scope("moe_route"):
         token_of, weight, pos, starts, sizes = moe_dispatch(idx, p, cfg)
         held = jnp.sum(sizes)
@@ -370,8 +376,7 @@ def moe_experts(g, idx, p, layer: dict, cfg: MoELMConfig):
         with obs.scope("moe_route"):
             xs = _dispatch(gb, tok, pos, at)
         with obs.scope("moe_experts"):
-            a = jax.nn.relu(_grouped(xs, w_gate, here)) \
-                * _grouped(xs, w_up, here)
+            a = act(_grouped(xs, w_gate, here)) * _grouped(xs, w_up, here)
             out = _grouped(a.astype(_BF16), w_down, here)
         with obs.scope("moe_route"):
             part = _combine(out * w[:, None], tok, pos, at)

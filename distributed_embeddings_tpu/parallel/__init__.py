@@ -17,6 +17,7 @@ from .grads import (
     split_mp_dp,
 )
 from .optimizers import SparseAdagrad, SparseAdam, SparseMomentum, SparseSGD
+from .lm_serving import LMServeState, SessionConfig, SessionRuntime
 from .sparse_optax import (
     SparseRows,
     apply_sparse_updates,

@@ -233,6 +233,12 @@ class Request:
     # spans the process boundary: the worker's runtime adopts it in
     # _normalize and its stage spans re-parent under the upstream trace
     trace: Optional[Dict[str, Any]] = None
+    # a language model's turn (``parallel/lm_serving.py``): the session it
+    # continues, the tokens to generate (its ``n``) and the generated
+    # positions whose logits come back; ``cats[0]`` holds the prompt's ids
+    session: Optional[int] = None
+    max_new_tokens: int = 0
+    logits_at: Sequence[int] = ()
 
 
 @dataclasses.dataclass
@@ -265,6 +271,9 @@ class Served(ServeResult):
     # member; the five spans sum to ``latency_ms`` by construction
     # (queue wait is this request's own, the rest are its flush's)
     spans: Optional[Dict[str, float]] = None
+    # a language model's turn: the generated ids (``predictions`` then holds
+    # the logits of the positions ``Request.logits_at`` named)
+    tokens: Any = None
 
 
 @dataclasses.dataclass
