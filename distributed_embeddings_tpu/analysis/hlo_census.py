@@ -214,19 +214,30 @@ def apply_contracts() -> List[PassBudget]:
     return out
 
 
+def lookup_contracts() -> List[PassBudget]:
+    """The lookup's budget. A small-table ragged group's bags are summed
+    from within-tile prefixes of rows made on the MXU, read at the splits
+    (``parallel/lookup.py:block_bag_sums``): the ``segment_prefix`` scope
+    holds no scatter and no sort, or the combine is back on the row path."""
+    why = ("small-table bags are prefix differences: a scatter or a sort "
+           "there is the row path again")
+    return [PassBudget("segment_prefix", k, max_passes=0, reason=why)
+            for k in ("scatter", "sort")]
+
+
 def default_contracts(emb_optimizer=None) -> List[PassBudget]:
     """Config-independent contracts for a hybrid train step census.
 
-    That is :func:`apply_contracts` and the dedup budget: when the sparse
-    optimizer declares ``needs_dedup=False`` (and ``DETPU_SGD_DEDUP`` does
-    not force the pass back in), the compiled dedup phase must be empty.
-    Shape-dependent
+    That is :func:`apply_contracts`, :func:`lookup_contracts` and the dedup
+    budget: when the sparse optimizer declares ``needs_dedup=False`` (and
+    ``DETPU_SGD_DEDUP`` does not force the pass back in), the compiled dedup
+    phase must be empty. Shape-dependent
     budgets (gathers per lookup group, pinned dedup counts for stateful
     optimizers) belong to the caller — ``tools/hlo_audit.py`` pins them
     for the reference configurations."""
     from ..parallel.optimizers import sgd_dedup_forced
 
-    out = apply_contracts()
+    out = apply_contracts() + lookup_contracts()
     if emb_optimizer is not None and not getattr(
             emb_optimizer, "needs_dedup", True) and not sgd_dedup_forced():
         out += dedup_zero_contracts(

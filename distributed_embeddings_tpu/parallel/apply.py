@@ -18,7 +18,6 @@ the monolith's methods produced.
 
 from __future__ import annotations
 
-import itertools
 from typing import Dict, List, Optional
 
 import jax
@@ -29,7 +28,7 @@ from ..utils import obs
 from ..ops import packed_slab as ps
 from . import exchange as exchange_mod
 from . import lookup as lookup_mod
-from .lookup import _wkey
+from .lookup import _wkey, block_runs
 
 
 def apply_width_streams(de, params, opt_state,
@@ -110,16 +109,6 @@ def sparse_apply_gradients(de, params, opt_state, residuals, out_grads,
                                         fallback_dtype=fallback)
     return apply_width_streams(de, params, opt_state, per_width,
                                optimizer, lr, scale, enable=enable)
-
-
-def block_runs(block):
-    """``(block rows, first slot, end slot)`` of each run of equal block
-    rows of a small-table group: the slots that share one batched matmul."""
-    k0 = 0
-    for v, run in itertools.groupby(block):
-        k1 = k0 + len(list(run))
-        yield v, k0, k1
-        k0 = k1
 
 
 def small_table_sums(g, ids4, grads, live, roff, sent, slot_major=False):

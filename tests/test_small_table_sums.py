@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import Mesh
 
 from distributed_embeddings_tpu.analysis.hlo_census import (
-    apply_contracts, census_step_fn)
+    apply_contracts, census_step_fn, lookup_contracts)
 from distributed_embeddings_tpu.analysis.plan_audit import audit_plan
 from distributed_embeddings_tpu.parallel import (
     DistributedEmbedding, SparseAdagrad, SparseAdam, SparseMomentum,
@@ -439,7 +439,9 @@ def test_multihot_step_scatters_the_shorter_stream(monkeypatch):
     """The multi-hot cell's step at its real shapes, abstract: ONE scatter
     into the width-128 slab, of 2 642 560 rows where it was 6 815 744, in the
     form ``sort_fused``; the ragged sums hold no row operation (the ``take``
-    that expands the cotangents is the stream path's and lies outside)."""
+    that expands the cotangents is the stream path's and lies outside), and
+    the forward's bags of the same 16 slots are prefix differences: the
+    ``segment_prefix`` scope is there and holds no scatter and no sort."""
     from distributed_embeddings_tpu import Ragged
 
     cfg, de, plan = _plan_of("dlrm-kaggle", 16384,
@@ -464,7 +466,8 @@ def test_multihot_step_scatters_the_shorter_stream(monkeypatch):
                  row_splits=jax.ShapeDtypeStruct((16385,), jnp.int32))
     args = (state, [rag] * n,
             jax.ShapeDtypeStruct((16384, 128 * n), jnp.float32))
-    rep = census_step_fn(step, args, contracts=apply_contracts())
+    rep = census_step_fn(step, args,
+                         contracts=apply_contracts() + lookup_contracts())
     assert rep.ok, rep.violations
     assert calls == [(sum(cfg["table_sizes"]), 2_642_560, (2_642_560, 128))]
     assert rep.passes("*scatter_sort_fused", "scatter") == 1
@@ -473,3 +476,7 @@ def test_multihot_step_scatters_the_shorter_stream(monkeypatch):
     assert sums.fusions + sums.instructions > 0
     assert not any(sums.counts.get(k) for k in ("sort", "scatter", "cumsum",
                                                 "gather"))
+    bags = rep.phases["embedding_forward/lookup_w128_r/segment_prefix"]
+    assert bags.fusions > 0 and bags.counts.get("gather")
+    assert rep.passes("*segment_prefix", "scatter") == 0
+    assert rep.passes("*segment_prefix", "sort") == 0
