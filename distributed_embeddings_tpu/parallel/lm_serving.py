@@ -49,13 +49,24 @@ as ``ServingRuntime``'s flush holds its transfer and its fetch),
 ``serve/step`` around each step's dispatch (args ``decode`` and
 ``prefill_tokens``), ``serve/prefill`` around a chunk's, ``serve/h2d``
 around the transfer of a step's inputs, ``serve/fetch`` around a step's
-read-back, ``serve/prefill_document``.
-Counters (:func:`~..utils.obs.counter_inc`): ``lm_steps``,
-``lm_decode_tokens``, ``lm_prefill_tokens``, ``lm_decode_context_tokens``
-(each decoding slot's context, summed over steps),
-``lm_prefill_context_tokens`` and ``lm_prefill_key_pairs`` (a chunk's
-session rows and query-key pairs), ``lm_cache_tokens_peak`` and the model's
-``COUNT_KEYS``.
+read-back: the wait for the step to finish, then the copy.
+Counters (:func:`~..utils.obs.counter_inc`): ``lm_decode_tokens``,
+``lm_decode_context_tokens`` (each decoding slot's context, summed over
+steps), ``lm_prefill_context_tokens`` and ``lm_prefill_key_pairs`` (a
+chunk's session rows and query-key pairs) and the model's ``COUNT_KEYS``.
+
+**The steps' timeline**, always kept, on the runtime's clock. A step
+records ``t_sent``, the clock once its program call returned; its read-back
+asks first whether the step is done, then waits for it and records
+``t_done``. A read-back that found its step done counts in
+``readback_ready``: the host came late, and the device may have idled
+since. A step whose read-back waited, whose predecessor's read-back waited
+too, and that was sent before the predecessor's ``t_done`` ran on the device
+right after it: its device time is ``t_done`` less the predecessor's, summed
+by kind (``decode_step_ms``, ``chunk_step_ms`` in :meth:`stats`: a step
+that held a prompt chunk is a chunk step). A ready read-back forms no pair
+with either neighbour; a :meth:`poll` that only reads back follows the same
+rule.
 """
 
 from __future__ import annotations
@@ -125,6 +136,8 @@ class _Step:
     logits: Any
     counts: Any
     produced: List          # (turn, generated index) this step produces
+    chunk: bool             # whether it held a prompt chunk
+    t_sent: float           # the clock once its program call returned
 
 
 class SessionRuntime:
@@ -165,11 +178,16 @@ class SessionRuntime:
         self._cache_peak = 0
         self._lat: List[float] = []
         self._ttft: List[float] = []
+        self._tpot: List[float] = []
         self._counts = {"served": 0, "shed": 0, "deadline_missed": 0,
                         "expired": 0, "flushes": 0,
                         "served_samples": 0, "chunk_steps": 0,
                         "decode_steps": 0, "decode_slots": 0,
-                        "prefill_tokens": 0, "cache_full": 0}
+                        "prefill_tokens": 0, "cache_full": 0,
+                        "readbacks": 0, "readback_ready": 0,
+                        "decode_step_pairs": 0, "chunk_step_pairs": 0}
+        self._pair_s = {"decode": 0.0, "chunk": 0.0}
+        self._prev_done: Optional[float] = None   # None: the chain broke
 
     # --------------------------------------------------------- the state
 
@@ -239,19 +257,18 @@ class SessionRuntime:
     def prefill_document(self, tokens: np.ndarray) -> Document:
         """A document's latent cache from scratch (decompressed attention),
         for :meth:`open_session`."""
-        with obs.span("serve/prefill_document", tokens=len(tokens)):
-            t = int(len(tokens))
-            params = self._state.dense_params
-            x = self._lookup_fn(self._state.emb_params,
-                                jnp.asarray(tokens, jnp.int32))
-            cs = self._rope[:t]
-            caches = []
-            for l, layer in enumerate(params["layers"]):
-                x, c, pe = self._prefill_fn(l < self.cfg.num_dense_layers)(
-                    x, layer, cs)
-                caches.append((c, pe))
-            del x
-            return Document(caches=caches, length=t)
+        t = int(len(tokens))
+        params = self._state.dense_params
+        x = self._lookup_fn(self._state.emb_params,
+                            jnp.asarray(tokens, jnp.int32))
+        cs = self._rope[:t]
+        caches = []
+        for l, layer in enumerate(params["layers"]):
+            x, c, pe = self._prefill_fn(l < self.cfg.num_dense_layers)(
+                x, layer, cs)
+            caches.append((c, pe))
+        del x
+        return Document(caches=caches, length=t)
 
     def _new_caches(self):
         return [tuple(jnp.zeros(sh, jnp.bfloat16) for sh in pair)
@@ -421,6 +438,7 @@ class SessionRuntime:
                 self._caches, self._tok, logits, counts = self._program(
                     chunk)(self._state, self._caches, self._tok, dev,
                            self._rope)
+        t_sent = self._clock()
         for s in decoding:
             self._len[s] += 1
             self._running[s].gen += 1
@@ -429,7 +447,6 @@ class SessionRuntime:
             self._len[sid] += meta[2]
             self._counts["chunk_steps"] += 1
             self._counts["prefill_tokens"] += meta[2]
-            obs.counter_inc("lm_prefill_tokens", meta[2])
             # what the chunk's attention reads and computes: its session's
             # rows up to its last query, and a query-key pair a key each of
             # its queries sees
@@ -446,18 +463,35 @@ class SessionRuntime:
             obs.counter_inc("lm_decode_tokens", len(decoding))
             obs.counter_inc("lm_decode_context_tokens", ctx_tokens)
         self._counts["flushes"] += 1
-        obs.counter_inc("lm_steps")
-        held = int(self._len.sum())
-        if held > self._cache_peak:
-            obs.counter_inc("lm_cache_tokens_peak", held - self._cache_peak)
-            self._cache_peak = held
+        self._cache_peak = max(self._cache_peak, int(self._len.sum()))
         # a turn whose every step is out frees its session for the next turn
         for s, turn in enumerate(self._running):
             if turn is not None and turn.gen >= g_of(turn):
                 self._running[s] = None
         self._inflight.append(_Step(tok=self._tok, logits=logits,
-                                    counts=counts, produced=produced))
+                                    counts=counts, produced=produced,
+                                    chunk=ft is not None, t_sent=t_sent))
         return True
+
+    @staticmethod
+    def _await(st: _Step) -> bool:
+        """Whether step ``st`` was done when asked; returns once it is."""
+        ready = st.tok.is_ready()
+        jax.block_until_ready(st.tok)
+        return ready
+
+    def _time_step(self, st: _Step, ready: bool, t_done: float) -> None:
+        """Count a read-back, and a step's device time where it pairs with
+        its predecessor's (the module's docstring has the rule)."""
+        c = self._counts
+        c["readbacks"] += 1
+        prev, self._prev_done = self._prev_done, None if ready else t_done
+        if ready:
+            c["readback_ready"] += 1
+        elif prev is not None and st.t_sent < prev:
+            kind = "chunk" if st.chunk else "decode"
+            c[kind + "_step_pairs"] += 1
+            self._pair_s[kind] += t_done - prev
 
     def _retire(self, out: List[ServeResult]) -> None:
         """Read the oldest step in flight back and answer the turns it
@@ -465,8 +499,11 @@ class SessionRuntime:
         st = self._inflight.popleft()
         want = any(j in turn.req.logits_at for turn, j in st.produced)
         with obs.span("serve/fetch"):
+            ready = self._await(st)
+            t_done = self._clock()
             tok, counts = jax.device_get((st.tok, st.counts))  # host-ok: the runtime's read-back, one step behind the device
             logits = np.asarray(st.logits) if want else None
+        self._time_step(st, ready, t_done)
         for k, v in zip(self.model.COUNT_KEYS, np.asarray(counts)):
             obs.counter_inc(k, int(v))
         t = self._clock()
@@ -493,6 +530,9 @@ class SessionRuntime:
                  "decode_ms": (t_done - turn.t_first) * 1e3,
                  "reply_ms": (t1 - t_done) * 1e3}
         self._lat.append(lat)
+        if r.max_new_tokens > 1:
+            # the wait for each token after the first
+            self._tpot.append(spans["decode_ms"] / (r.max_new_tokens - 1))
         missed = t1 > r.deadline
         self._counts["served"] += 1
         self._counts["served_samples"] += r.n
@@ -528,6 +568,10 @@ class SessionRuntime:
         slots = c["flushes"] * self.config.sessions
         pct = (lambda q: float(np.percentile(lat, q))) if lat is not None \
             else (lambda q: None)
+        p50 = lambda xs: float(np.percentile(xs, 50)) if xs else None  # noqa: E731
+        step_ms = {k: (1e3 * s / c[k + "_step_pairs"]
+                       if c[k + "_step_pairs"] else None)
+                   for k, s in self._pair_s.items()}
         return {
             **c,
             "steps": c["flushes"],
@@ -537,8 +581,13 @@ class SessionRuntime:
                                   if c["decode_steps"] else 0.0),
             "latency_p50_ms": pct(50), "latency_p95_ms": pct(95),
             "latency_p99_ms": pct(99),
-            "ttft_p50_ms": (float(np.percentile(self._ttft, 50))
-                            if self._ttft else None),
+            "ttft_p50_ms": p50(self._ttft),
+            "tpot_p50_ms": p50(self._tpot),
+            "readback_ready_share": (100.0 * c["readback_ready"]
+                                     / c["readbacks"]
+                                     if c["readbacks"] else None),
+            "decode_step_ms": step_ms["decode"],
+            "chunk_step_ms": step_ms["chunk"],
             "cache_tokens_peak": self._cache_peak,
             "warmup_compiles": self.warmup_compiles,
             "steady_state_recompiles": self.steady_recompiles(),

@@ -223,6 +223,130 @@ def test_a_flush_span_holds_each_steps_transfer_and_read_back(built,
     assert flushes == rt.stats()["flushes"] + 1
 
 
+class Device:
+    """Stands in for ``SessionRuntime._await``: a device that runs the
+    runtime's steps in order from when each was sent, ``chunk_ms`` a step
+    that holds a prompt chunk and ``decode_ms`` another, on the runtime's
+    clock. Asked about a step, a tenth of a millisecond after the poll sent
+    its own, it says whether the step is done and moves the clock to the
+    step's end where it is not."""
+
+    def __init__(self, clock, decode_ms, chunk_ms):
+        self.clock, self.decode_ms, self.chunk_ms = clock, decode_ms, chunk_ms
+        self.free = 0.0
+        self.log = []               # (chunk, ready) a read-back
+
+    def __call__(self, st):
+        self.clock.t += 1e-4
+        start = max(st.t_sent, self.free)
+        self.free = start + (self.chunk_ms if st.chunk else self.decode_ms) / 1e3
+        ready = self.clock.t >= self.free
+        self.clock.t = max(self.clock.t, self.free)
+        self.log.append((st.chunk, ready))
+        return ready
+
+
+def _timed_runtime(built, monkeypatch, decode_ms=3.0, chunk_ms=8.0):
+    rt = _runtime(built)
+    dev = Device(rt._clock, decode_ms, chunk_ms)
+    monkeypatch.setattr(rt, "_await", dev)
+    return rt, dev
+
+
+STEP_KEYS = ("readbacks", "readback_ready", "readback_ready_share",
+             "decode_step_ms", "chunk_step_ms", "decode_step_pairs",
+             "chunk_step_pairs", "tpot_p50_ms")
+
+
+def test_read_backs_that_wait_in_a_row_time_each_step_on_the_device(
+        built, monkeypatch):
+    """A host that polls every millisecond beside a device that takes 3 ms a
+    decode step and 8 a chunk step waits at every read-back: each step after
+    the first of a turn pairs with the one before, so the counters read the
+    device's own step times, each kind in its own sum. A turn's prompt of 12
+    is two chunk steps, the second of which yields the first token, then 7
+    decode steps; the last is read back by a poll that sends nothing."""
+    rt, dev = _timed_runtime(built, monkeypatch)
+    (r,) = _serve(rt, [_turn(0, 1)]).values()
+    assert isinstance(r, Served)
+    assert dev.log == [(True, False)] * 2 + [(False, False)] * 7
+    st = rt.stats()
+    assert st["readbacks"] == 9 and st["readback_ready"] == 0
+    assert st["readback_ready_share"] == 0.0
+    # the first chunk step has no predecessor that waited: no pair
+    assert st["chunk_step_pairs"] == 1 and st["decode_step_pairs"] == 7
+    assert st["chunk_step_ms"] == pytest.approx(8.0, abs=1e-9)
+    assert st["decode_step_ms"] == pytest.approx(3.0, abs=1e-9)
+    # a second turn after an idle gap starts a new chain
+    (r2,) = _serve(rt, [_turn(1, 2)]).values()
+    st = rt.stats()
+    assert st["readbacks"] == 18
+    assert st["chunk_step_pairs"] == 2 and st["decode_step_pairs"] == 14
+    assert st["chunk_step_ms"] == pytest.approx(8.0, abs=1e-9)
+    assert st["decode_step_ms"] == pytest.approx(3.0, abs=1e-9)
+
+
+def test_a_ready_read_back_counts_and_breaks_the_chain_on_both_sides(
+        built, monkeypatch):
+    """The host stalls 50 ms once, in the middle of the decode steps: the
+    read-back after the stall finds its step done (``readback_ready``), and
+    neither it nor the step after it pairs, since the device may have idled
+    between them; the steps further on pair again."""
+    rt, dev = _timed_runtime(built, monkeypatch)
+    assert rt.submit(_turn(2, 3)) is None
+    got = []
+    for k in range(40):
+        if k == 5:
+            rt._clock.t += 0.05
+        got += rt.poll()
+        rt._clock.t += 1e-3
+        if got:
+            break
+    assert len(got) == 1 and isinstance(got[0], Served)
+    assert [ready for _, ready in dev.log].count(True) == 1
+    st = rt.stats()
+    assert st["readbacks"] == 9 and st["readback_ready"] == 1
+    assert st["readback_ready_share"] == pytest.approx(100.0 / 9)
+    assert st["chunk_step_pairs"] == 1 and st["decode_step_pairs"] == 5
+    assert st["decode_step_ms"] == pytest.approx(3.0, abs=1e-9)
+
+
+def test_a_host_slower_than_the_device_finds_every_step_done(built,
+                                                             monkeypatch):
+    """A device of half a millisecond a step beside a poll a millisecond:
+    every read-back is ready, no step pairs, and the step times read
+    ``None``, not 0, as they do on a runtime that has served nothing."""
+    rt, dev = _timed_runtime(built, monkeypatch, decode_ms=0.5, chunk_ms=0.5)
+    fresh = rt.stats()
+    for key in STEP_KEYS:
+        assert key in fresh, key
+    assert fresh["readbacks"] == 0 and fresh["readback_ready_share"] is None
+    assert fresh["decode_step_ms"] is None and fresh["chunk_step_ms"] is None
+    assert fresh["tpot_p50_ms"] is None
+    _serve(rt, [_turn(3, 4)])
+    st = rt.stats()
+    assert st["readbacks"] == 9 and st["readback_ready_share"] == 100.0
+    assert st["decode_step_pairs"] == st["chunk_step_pairs"] == 0
+    assert st["decode_step_ms"] is None and st["chunk_step_ms"] is None
+
+
+def test_tpot_is_the_median_wait_for_each_token_after_the_first(
+        built, monkeypatch):
+    """``tpot_p50_ms`` is the median over served turns of ``decode_ms /
+    (max_new_tokens - 1)``; a turn of one token has no such wait and is left
+    out."""
+    rt, _ = _timed_runtime(built, monkeypatch)
+    one = Request(cats=[_prompt(9)], session=3, max_new_tokens=1,
+                  logits_at=(0,))
+    got = _serve(rt, [_turn(0, 5), _turn(1, 6), _turn(2, 7), one])
+    served = [r for r in got.values() if isinstance(r, Served)]
+    assert len(served) == 4
+    want = [r.spans["decode_ms"] / (G - 1) for r in served
+            if len(r.tokens) == G]
+    assert len(want) == 3
+    assert rt.stats()["tpot_p50_ms"] == pytest.approx(float(np.median(want)))
+
+
 def test_a_scope_read_per_step_that_held_a_chunk():
     """``stat_scope_ms`` divides a scope's device time by a count of the
     runtime's ``stats()``: on the recorded three-step trace, the apply's
